@@ -71,7 +71,7 @@ def test_r21d_tables_agree():
 
 R21D_KNOWN = {
     # torchvision r2plus1d_18: block-level midplanes — (inplanes, planes) once
-    # per block, shared by conv1 AND conv2 (ADVICE.md round-1 high finding)
+    # per block, shared by conv1 AND conv2 (a round-1 review finding)
     "layer2.0.conv1.0.0.weight": (230, 64, 1, 3, 3),
     "layer2.0.conv2.0.0.weight": (230, 128, 1, 3, 3),
     "layer3.0.conv2.0.0.weight": (460, 256, 1, 3, 3),

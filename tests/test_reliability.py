@@ -57,9 +57,19 @@ def test_classify_taxonomy_and_unknown():
     assert classify(ValueError("a")) == ("ValueError", False)
 
 
-def test_classify_xla_runtime_error_is_device_fault():
-    exc = type("XlaRuntimeError", (RuntimeError,), {})("DEADLINE_EXCEEDED")
-    assert classify(exc) == ("DeviceError", True)
+def test_classify_jax_runtime_error_is_device_fault():
+    """What a failing jitted call really raises on the installed JAX (here: an
+    allocation no host can satisfy) classifies as a transient device fault —
+    and a look-alike by name only does not."""
+    import jax
+    import jax.numpy as jnp
+
+    with pytest.raises(jax.errors.JaxRuntimeError) as info:
+        jax.jit(lambda: jnp.zeros((1 << 42,), jnp.float32))().block_until_ready()
+    assert "RESOURCE_EXHAUSTED" in str(info.value)
+    assert classify(info.value) == ("DeviceError", True)
+    fake = type("JaxRuntimeError", (RuntimeError,), {})("DEADLINE_EXCEEDED")
+    assert classify(fake) == ("JaxRuntimeError", False)
 
 
 def test_traceback_digest_groups_by_site_not_message():

@@ -25,6 +25,12 @@ death, driving the CLI surface as an operator would:
 3. an ENOSPC drill (``wal_append:raise``) proves degrade-never-crash on a
    live daemon: submits keep completing, ``healthz`` flags ``durable: false``.
 
+A CPU tool, and it stays one: every child is pinned to ``JAX_PLATFORMS=cpu``
+and this parent never imports jax — a chip belongs to one process at a time,
+so a parent that held it would starve its children, and a child killed
+mid-step could leave it locked. The chip's own check is ``chip_smoke.py``
+(one process).
+
 Runs on CPU with deterministic random weights::
 
     JAX_PLATFORMS=cpu VFT_ALLOW_RANDOM_WEIGHTS=1 python tools/chaos_smoke.py

@@ -1,6 +1,6 @@
 """Measure the reference computation in torch on this host → BASELINE.json "measured".
 
-The reference publishes no benchmark numbers (BASELINE.md), so this script anchors
+The reference publishes no benchmark numbers, so this script anchors
 ``vs_baseline`` by timing the torch equivalents of the reference's hot paths
 (architectures mirrored 1:1 from the reference source in tools/torch_mirrors.py):
 

@@ -39,6 +39,12 @@ driving the REAL CLI surface as an operator would — no test harness imports:
    the decode/transfer stage split — the operator meter showing the decode
    pool shed the per-frame PIL work.
 
+A CPU tool, and it stays one: every child is pinned to ``JAX_PLATFORMS=cpu``
+and this parent never imports jax — a chip belongs to one process at a time,
+so a parent that held it would starve its children, and a child killed
+mid-step could leave it locked. The chip's own check is ``chip_smoke.py``
+(one process).
+
 Runs on CPU with deterministic random weights::
 
     JAX_PLATFORMS=cpu VFT_ALLOW_RANDOM_WEIGHTS=1 python tools/service_smoke.py

@@ -27,6 +27,7 @@ from ..core import Finding, Rule, SourceFile, register
 # load-bearing for slow-only CI. Each file carries a matching annotation.
 DEFAULT_TIER: Dict[str, str] = {
     "test_bench_record": "bench record/merge logic drives jitted extractors",
+    "test_chip_bringup": "subprocesses that import jax + TPU cross-lowerings",
     "test_decode_pool": "real-sleep concurrency tests on the decode pool",
     "test_device_preproc": "device-preproc parity over real-model compiles",
     "test_fault_injection": "e2e extraction under injected faults (compiles)",

@@ -33,7 +33,6 @@ ALLOWED: Dict[str, int] = {
     "video_features_tpu/parallel/pipeline.py": 3,  # distributed-client probe + worker re-raise + segment planner (falls back to sequential scheduling)
     "video_features_tpu/reliability/retry.py": 2,  # classified re-raise + attempts attr
     "video_features_tpu/reliability/watchdog.py": 1,  # hands the exception to the waiter
-    "video_features_tpu/run.py": 1,                # best-effort JAX_PLATFORMS shim
     "video_features_tpu/serve/daemon.py": 7,       # per-video isolation point (serving loop) + lazy model-construction arm + cache-hit write arm + best-effort rejection/result records (the daemon must outlive a full notify disk) + profile start/stop arms (an on-demand jax.profiler session failing must report over the socket, not kill the API thread)
     "video_features_tpu/serve/ingest.py": 1,       # one bad socket client must not kill the API thread
     "video_features_tpu/serve/wal.py": 1,          # writer-thread wrapper: a dead writer would hang every submitter blocked on its ack event — degrade loudly and keep acking

@@ -4,8 +4,8 @@ The reference's VGGish frontend resamples arbitrary-rate wavs to 16 kHz with
 ``resampy.resample`` (``/root/reference/models/vggish/vggish_src/vggish_input.py:84``),
 i.e. Smith's band-limited interpolation with a Kaiser-windowed sinc prototype
 ("kaiser_best"). Round 1 substituted scipy's polyphase resampler, which is a
-different filter — features on non-16 kHz inputs diverged from the reference
-(ADVICE.md r1). This module re-implements the published algorithm (J. O. Smith,
+different filter — features on non-16 kHz inputs diverged from the reference.
+This module re-implements the published algorithm (J. O. Smith,
 "Digital audio resampling", and the resampy 0.2 kernel the reference pins) so
 that path agrees too:
 

@@ -98,7 +98,6 @@ EXECUTION_FIELDS = (
     "pwc_corr",                # impl choice, parity pinned (test_pallas_corr)
     "pwc_warp",                # impl choice, parity pinned (tests/test_pwc)
     "flow_pair_chunk",         # lax.map chunking, parity pinned
-    "compilation_cache",       # XLA cache location
     "precompile",              # compile scheduling
     "async_writer",            # write scheduling, same bytes
     "profile_dir",             # observability

@@ -239,17 +239,13 @@ def build_parser() -> argparse.ArgumentParser:
                         help="reprocess exactly the videos in the failure manifest "
                              "(<output>/<feature_type>/.failed_manifest.jsonl) "
                              "instead of --video_paths/--file_with_video_paths")
-    parser.add_argument("--compilation_cache", default=None,
-                        help="persistent XLA compilation cache directory: "
-                             "compiles longer than ~1s are cached so reruns "
-                             "and restarts skip straight to execution "
-                             "(docs/performance.md)")
     parser.add_argument("--precompile", action="store_true", default=False,
                         help="flow models: warm the device program for each "
                              "video's (bucketed) geometry in a background "
                              "thread while the host decodes, overlapping "
                              "mixed-resolution recompiles with decode "
-                             "(combine with --shape_bucket/--compilation_cache)")
+                             "(combine with --shape_bucket; the persistent "
+                             "compile cache keeps the results across runs)")
     parser.add_argument("--sync_writer", dest="async_writer",
                         action="store_false", default=True,
                         help="disable the async output writer and serialize "

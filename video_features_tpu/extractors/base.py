@@ -36,7 +36,6 @@ from ..io.video import open_video, open_video_segment, plan_segments, probe_vide
 from ..io import ffmpeg as ffmpeg_io
 from ..parallel import MeshRunner
 from ..parallel.pipeline import DecodePrefetcher, HostStagingRing
-from ..parallel.mesh import enable_compilation_cache
 from ..reliability import (
     CircuitBreakerTripped,
     DeviceError,
@@ -103,10 +102,6 @@ class Extractor(abc.ABC):
         cfg.validate()
         self.cfg = cfg
         self.feature_type = cfg.feature_type
-        # persistent compilation cache (--compilation_cache): applied before
-        # the mesh (and so before any compile) — see docs/performance.md
-        if cfg.compilation_cache:
-            enable_compilation_cache(cfg.compilation_cache)
         # per-feature-type subdirs, as the reference joins them (extract_i3d.py:77-78)
         self.output_dir = feature_output_dir(cfg.output_path, cfg.feature_type)
         self.tmp_dir = os.path.join(cfg.tmp_path, cfg.feature_type)

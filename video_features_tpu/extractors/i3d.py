@@ -37,6 +37,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..models.i3d import I3D, i3d_preprocess_flow, i3d_preprocess_rgb
 from ..models.pwc import pwc_forward_frames, pwc_forward_frames_sharded, pwc_init_params
@@ -46,7 +47,7 @@ from ..models.raft import (
     raft_init_params,
 )
 from ..ops.image import device_edge_resize_hwc, pil_edge_resize
-from ..parallel import prefetch_to_device
+from ..parallel import DATA_AXIS, prefetch_to_device
 from ..utils.labels import show_predictions_on_dataset
 from ..weights.convert_torch import convert_i3d, convert_pwc, convert_raft
 from ..weights.store import resolve_params
@@ -215,23 +216,39 @@ class ExtractI3D(Extractor):
                 corr_impl=self.cfg.raft_corr, dtype=flow_dtype,
                 n_devices=self.runner.num_devices)
         else:
-            total = n * (sp1 - 1)
+            # each device flows its own clips: under shard_map the flow net
+            # sees local arrays, which is what lets its Mosaic kernels lower
+            # at all on a mesh ("Mosaic kernels cannot be automatically
+            # partitioned") and keeps the chunked decode from walking every
+            # clip on every device
+            n_dev = self.runner.num_devices
+            total = (n // n_dev) * (sp1 - 1)
             if self.cfg.flow_pair_chunk is not None:
                 chunk = self.cfg.flow_pair_chunk or None  # 0 → never chunk
             else:
                 # auto: the per-pair decoder working set scales with the
                 # /64 flow grid (PWC's internal geometry, models/pwc.py
-                # _grid64); 64 pairs at 256×384 exceeds HBM while 64 at
-                # 256² fits (BASELINE.md round-3 note)
+                # _grid64); 64 pairs at 256×384 in one piece was recorded as
+                # exceeding HBM on an earlier installation. Chunked, the step
+                # compiles for a v5e with 1.3 GiB of temporaries and runs
+                # (chip_smoke.py, PR 21); unchunked: not measured here
                 from ..models.pwc import _grid64
 
                 h64, w64 = _grid64(h, w)
                 chunk = 16 if total * h64 * w64 > 5_000_000 else None
-            flow = pwc_forward_frames(self.flow_params, frames,
-                                      corr_impl=self.cfg.pwc_corr,
-                                      dtype=flow_dtype,
-                                      pair_chunk=chunk,
-                                      warp_impl=self.cfg.pwc_warp)
+
+            def flow_net(fr):
+                return pwc_forward_frames(self.flow_params, fr,
+                                          corr_impl=self.cfg.pwc_corr,
+                                          dtype=flow_dtype,
+                                          pair_chunk=chunk,
+                                          warp_impl=self.cfg.pwc_warp)
+
+            if n_dev > 1:
+                flow_net = jax.shard_map(flow_net, mesh=self.runner.mesh,
+                                         in_specs=P(DATA_AXIS),
+                                         out_specs=P(DATA_AXIS))
+            flow = flow_net(frames)
         # flow: (N, S, Hp, Wp, 2)
         x = i3d_preprocess_flow(_center_crop_nhwc(flow, self.crop_size),
                                 dtype=self.dtype)

@@ -185,8 +185,8 @@ def pwc_forward_frames(params: Dict, frames: jnp.ndarray,
     chunk = min(pair_chunk, total) if pair_chunk else 0
     if chunk > 0 and chunk < total:
         # bound peak decoder memory: the DenseNet decoder activations scale
-        # with the pair batch (a 64-pair 65-frame I3D stack at 256×341 blows
-        # HBM in one piece — BASELINE.md round-3 note); the shared per-frame
+        # with the pair batch (a 64-pair 65-frame I3D stack at 256×341 was
+        # recorded as blowing HBM in one piece); the shared per-frame
         # pyramid above is computed ONCE either way, only the coarse-to-fine
         # decode runs chunk-by-chunk under lax.map (sequential on device).
         # Non-divisible totals zero-pad the pair axis up to a chunk multiple
@@ -241,7 +241,7 @@ def pwc_forward_frames_sharded(params: Dict, frames: jnp.ndarray,
     from ..ops.halo import boundary_from_next, frame_axis_mesh
 
     b, h, w, _ = frames.shape
-    shard_map, axis, n_dev = frame_axis_mesh(mesh, b)
+    axis, n_dev = frame_axis_mesh(mesh, b)
     h64, w64 = _grid64(h, w)
 
     def local(p, fr, fl):  # per-shard: (k, H, W, 3) main + (1, H, W, 3) last
@@ -256,7 +256,7 @@ def pwc_forward_frames_sharded(params: Dict, frames: jnp.ndarray,
             for lvl, lvl_l in zip(pyr, pyr_l))
         return _decode(p, pyr, pyr2, h, w, h64, w64, corr_impl, warp_impl)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P(), P(axis), P()), out_specs=P(axis))
     return fn(params, frames, frame_last)
 

@@ -557,7 +557,7 @@ def raft_forward_frames_sharded(params: Dict, frames: jnp.ndarray,
     from ..ops.halo import boundary_from_next, frame_axis_mesh
 
     b, h, w, _ = frames.shape
-    shard_map, axis, n_dev = frame_axis_mesh(mesh, b)
+    axis, n_dev = frame_axis_mesh(mesh, b)
     corr_impl = resolve_corr_impl(corr_impl, b, h, w, dtype, n_dev)
     if corr_impl not in ("volume", "volume_gather", "on_demand", "on_demand_matmul"):
         raise ValueError(
@@ -574,7 +574,7 @@ def raft_forward_frames_sharded(params: Dict, frames: jnp.ndarray,
         cnet = _encoder(p["cnet"], x, "batch")  # sources only: no halo needed
         return _refine_flow(p, f_loc, f2, cnet, iters, None, corr_impl, dtype)
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P(), P(axis), P()), out_specs=P(axis))
     return fn(params, frames, frame_last)
 
@@ -621,7 +621,12 @@ def _refine_flow(params: Dict, f1: jnp.ndarray, f2: jnp.ndarray, cnet: jnp.ndarr
         return (net, coords1 + delta.astype(jnp.float32)), None
 
     if taps is None:
-        (net, coords1), _ = lax.scan(body, (net, coords0), None, length=iters)
+        # under shard_map a scan carry must enter with the mesh variance it
+        # leaves with: the coordinate grid is built replicated, while every
+        # update derives from the shard's own features
+        vma = tuple(jax.typeof(net).vma)
+        coords1 = lax.pcast(coords0, vma, to="varying") if vma else coords0
+        (net, coords1), _ = lax.scan(body, (net, coords1), None, length=iters)
     else:
         coords1 = coords0
         for it in range(iters):

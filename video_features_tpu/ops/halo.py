@@ -25,21 +25,16 @@ from jax import lax
 
 def frame_axis_mesh(mesh, n_pairs: int):
     """Shared scaffolding for a frame-axis sharded forward:
-    ``(shard_map, axis_name, n_dev)`` for ``mesh``, after validating that the
-    pair count divides the mesh. Both sharded flow forwards (and any future
-    frame-sharded model) go through here so the shard_map import fallback
-    and the divisibility contract have one home.
+    ``(axis_name, n_dev)`` for ``mesh``, after validating that the pair count
+    divides the mesh. Both sharded flow forwards (and any future
+    frame-sharded model) go through here so the divisibility contract has
+    one home.
     """
-    try:  # moved out of experimental in newer JAX
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
     n_dev = int(mesh.devices.size)
     if n_pairs % n_dev:
         raise ValueError(
             f"pair count {n_pairs} must be divisible by the mesh size {n_dev}")
-    return shard_map, mesh.axis_names[0], n_dev
+    return mesh.axis_names[0], n_dev
 
 
 def recv_from_next(x: jnp.ndarray, axis_name: str, n_dev: int) -> jnp.ndarray:

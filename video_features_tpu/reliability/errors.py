@@ -28,6 +28,8 @@ import hashlib
 import traceback
 from typing import Tuple
 
+import jax
+
 
 class ExtractionError(Exception):
     """Base of the taxonomy; ``transient`` is a class-level retry tag."""
@@ -89,14 +91,15 @@ class CircuitBreakerTripped(Exception):
 def classify(exc: BaseException) -> Tuple[str, bool]:
     """(error_class, transient) for any exception the barrier can see.
 
-    Taxonomy members carry their own tags. XLA runtime errors (matched by type
-    name — jaxlib's class lives at an unstable import path) are device faults
-    and therefore transient. Everything else is an unknown permanent error:
-    retrying an exception we cannot classify just repeats the work.
+    Taxonomy members carry their own tags. ``jax.errors.JaxRuntimeError`` —
+    what a failing compile or execution raises (HBM or VMEM exhaustion, a
+    refused Mosaic kernel, a lost device) — is a device fault and therefore
+    transient. Everything else is an unknown permanent error: retrying an
+    exception we cannot classify just repeats the work.
     """
     if isinstance(exc, ExtractionError):
         return exc.error_class, exc.transient
-    if type(exc).__name__ == "XlaRuntimeError":
+    if isinstance(exc, jax.errors.JaxRuntimeError):
         return DeviceError.__name__, DeviceError.transient
     return type(exc).__name__, False
 

@@ -82,6 +82,7 @@ from ..io.output import (
     write_request_result,
 )
 from ..obs import MetricsRegistry
+from ..parallel.mesh import describe_devices, enable_compilation_cache
 from ..reliability import (
     DeviceError,
     TenantBreaker,
@@ -1273,6 +1274,7 @@ def serve(cfg) -> int:
     if api is not None:
         api.start()
         print(f"[serve] socket API at {sock_path}")
+    print(f"[serve] {describe_devices(extractor.runner.mesh)}")
     print(f"[serve] watching {cfg.spool_dir} "
           f"(results → {service.notify_dir}); SIGTERM drains, SIGHUP "
           "reloads")
@@ -1288,9 +1290,8 @@ def main(argv=None) -> int:
     """``python -m video_features_tpu.serve`` — the batch CLI surface with
     ``--serve`` implied."""
     from ..cli import parse_args
-    from ..run import _honor_jax_platforms_env
 
-    _honor_jax_platforms_env()
+    enable_compilation_cache()
     cfg = parse_args(list(argv) if argv is not None else None)
     if not cfg.serve:
         cfg = cfg.replace(serve=True)
