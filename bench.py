@@ -1527,7 +1527,7 @@ def main() -> None:
                 if ex._decode_pool is not None:
                     ex._decode_pool.shutdown()
                     ex._decode_pool = None
-                ex.clock = None
+                ex.clock = StageClock()  # never None: the accumulators are always on
             entry = {
                 "videos_per_sec": round(len(videos) / wall, 4),
                 "unit": unit_key or f"{feat_key} rows",

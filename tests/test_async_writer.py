@@ -231,3 +231,79 @@ def test_writer_close_drains_queued_jobs(tmp_path):
     assert len(load_done_set(str(tmp_path))) == 3
     with pytest.raises(OutputError, match="closed"):
         w.submit({"feat": np.zeros(1)}, "late.mp4", str(tmp_path))
+
+
+def test_writer_counts_backlog_videos_and_bytes(tmp_path):
+    """The writer's three counters, kept where the work happens."""
+    w = AsyncOutputWriter(depth=2)
+    assert (w.backlog_max, w.videos_written, w.write_bytes) == (0, 0, 0)
+    handles = [w.submit({"feat": np.zeros(8, np.float32),
+                         "fps": np.array(25.0)}, f"c{i}.mp4", str(tmp_path))
+               for i in range(3)]
+    for h in handles:
+        assert h.wait(timeout=60)
+    w.close()
+    assert w.counters() == {"writer_backlog_max": w.backlog_max,
+                            "videos_written": 3,
+                            "write_bytes": 3 * (8 * 4 + 8)}
+    # sampled at every submit, the job being submitted included: at least 1,
+    # at most the queue's depth plus the job in hand plus the one submitted
+    assert 1 <= w.backlog_max <= 4
+
+
+def test_writer_backlog_counts_queue_plus_job_in_hand(tmp_path, monkeypatch):
+    import threading
+
+    gate = threading.Event()
+    real = AsyncOutputWriter._run_one
+
+    def slow(*job):
+        gate.wait(30)
+        return real(*job)
+
+    monkeypatch.setattr(AsyncOutputWriter, "_run_one", staticmethod(slow))
+    w = AsyncOutputWriter(depth=2)
+    handles = [w.submit({"feat": np.zeros(2, np.float32)}, f"b{i}.mp4",
+                        str(tmp_path)) for i in range(3)]
+    assert w.backlog_max == 3  # one in hand, two queued
+    gate.set()
+    for h in handles:
+        assert h.wait(timeout=60)
+    w.close()
+    assert w.counters()["videos_written"] == 3
+
+
+def test_writer_job_runs_inside_a_write_span_with_bytes_and_retries(
+        tmp_path, monkeypatch):
+    import threading
+
+    from video_features_tpu.utils.metrics import SpanRecorder, span
+
+    monkeypatch.setenv("VFT_FAULTS", "save:raise_transient::1")
+    rec = SpanRecorder()
+
+    def the_span(name, **kw):
+        return span(name, recorder=rec, **kw)
+
+    w = AsyncOutputWriter(depth=2, span=the_span,
+                          retry=RetryPolicy(attempts=3, base_delay=0.01))
+    h = w.submit({"feat": np.arange(5, dtype=np.float32)}, "ws.mp4",
+                 str(tmp_path))
+    assert h.wait(timeout=60)
+    w.close()
+    (record,) = rec.export()["records"]
+    assert record["name"] == "write" and record["thread"] == "output-writer"
+    assert record["thread"] != threading.current_thread().name
+    assert record["ids"] == {"video": "ws.mp4", "bytes": 20, "retries": 1}
+    assert w.counters()["videos_written"] == 1 and w.write_bytes == 20
+
+
+def test_failed_write_is_not_counted_as_written(tmp_path, monkeypatch):
+    monkeypatch.setenv("VFT_FAULTS", "save:raise_permanent")
+    w = AsyncOutputWriter(depth=2)
+    h = w.submit({"feat": np.zeros(3, np.float32)}, "bad.mp4", str(tmp_path))
+    with pytest.raises(Exception):
+        h.wait(timeout=60)
+    w.close()
+    assert w.counters() == {"writer_backlog_max": 1, "videos_written": 0,
+                            "write_bytes": 0}

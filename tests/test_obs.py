@@ -591,3 +591,134 @@ def test_daemon_journals_cache_hits(tmp_path, corpus):
     assert len(by["cache_hit"]) == 2
     # cache-hit videos still close their lifecycle chain
     assert len(by["video_done"]) == 4
+
+
+# ---- the one span call across its sinks ------------------------------------
+
+
+def test_recording_off_keeps_no_record_and_stage_seconds_stay(tmp_path, corpus,
+                                                              monkeypatch):
+    """Without the switch (VFT_METRICS / --profile_dir / --telemetry_dir) no
+    record is kept; the accumulators are on all the same, in both loops."""
+    monkeypatch.delenv("VFT_METRICS", raising=False)
+    for pack in (False, True):
+        ex = ToyPacked(_cfg(tmp_path, f"off_{pack}", pack_corpus=pack))
+        assert ex.run(corpus) == len(corpus)
+        assert ex._recorder is None and ex.clock is not None
+        stats = ex._pack_stats
+        assert "spans" not in stats
+        assert stats["stage_seconds"]["decode"] > 0
+        assert stats["stage_seconds"]["device_wait"] > 0
+        assert stats["videos_written"] == len(corpus)
+        assert stats["writer_backlog_max"] >= 1
+    assert "dispatched_slots" in stats  # the packed run's counters besides
+
+
+def test_recording_on_with_telemetry_dir_alone(tmp_path, corpus, monkeypatch):
+    monkeypatch.delenv("VFT_METRICS", raising=False)
+    ex = ToyPacked(_cfg(tmp_path, "tel_only", pack_corpus=True,
+                        telemetry_dir=str(tmp_path / "tel_only" / "tel")))
+    assert ex.run(corpus) == len(corpus)
+    spans = ex._pack_stats["spans"]
+    names = {r["name"] for r in spans["records"]}
+    assert {"run", "extract", "stage", "launch", "device", "finalize",
+            "write"} <= names
+    # a second run starts a fresh list
+    n_first = len(spans["records"])
+    assert ex.run(corpus[:1]) == 1
+    assert len(ex._pack_stats["spans"]["records"]) < n_first
+
+
+def test_one_device_span_feeds_clock_journal_histogram_and_record(tmp_path,
+                                                                  corpus):
+    """`_fetch_batch` has ONE timer: the 'device' span's exit gives the same
+    duration to the stage clock, the histogram and the record, and the
+    journal's pair brackets it."""
+    ex = ToyPacked(_cfg(tmp_path, "one_timer", pack_corpus=True,
+                        telemetry_dir=str(tmp_path / "one_timer" / "tel")))
+    assert ex.run(corpus) == len(corpus)
+    stats = ex._pack_stats
+    device = [r for r in stats["spans"]["records"] if r["name"] == "device"]
+    assert device and all("page" in r["ids"] and "bucket" in r["ids"]
+                          for r in device)
+    hist = [h for h in ex._metrics.snapshot()["histograms"]
+            if h["name"] == "device_batch_seconds"]
+    assert len(hist) == 1 and hist[0]["count"] == len(device)
+    clock_s = stats["stage_seconds"]["device_wait"]
+    # clock and histogram are fed the very same float per span
+    assert hist[0]["sum"] == pytest.approx(clock_s, abs=1e-4 * len(device))
+    record_s = sum(r["end"] - r["start"] for r in device) / 1e9
+    assert record_s == pytest.approx(clock_s, abs=0.02 * len(device))
+    events, _ = load_journal(ex._journal.path)
+    by = _events_by_name(events)
+    assert len(by["device_start"]) == len(by["device_end"]) == len(device)
+    ends = {e["span"]: e for e in by["device_end"]}
+    journal_s = sum(ends[s["span"]]["ts"] - s["ts"] for s in by["device_start"])
+    assert journal_s == pytest.approx(clock_s, abs=0.02 * len(device))
+    assert all(e["page"] == s_["page"] for e, s_ in
+               zip(by["device_end"], by["device_start"]))
+    # the instant dispatch event kept its fields and gained the page
+    assert {"bucket", "real_slots", "batch_slots", "paged", "inflight",
+            "page"} <= set(by["dispatch"][0])
+    assert sorted(e["page"] for e in by["dispatch"]) == \
+        sorted(r["ids"]["page"] for r in device)
+
+
+def test_profiler_session_shows_the_programs_annotations(tmp_path, corpus):
+    """With the host tracer on, any profiler session shows the program's
+    spans as TraceAnnotations, their ids as stats — taken from outside, the
+    program not knowing (no --profile_dir)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    ex = ToyPacked(_cfg(tmp_path, "annot", pack_corpus=True))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    trace_dir = str(tmp_path / "annot_trace")
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        assert ex.run(corpus[:2]) == 2
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in ("run", "extract", "stage", "launch", "device",
+                               "finalize", "write", "write_reap"):
+                    seen.setdefault(ev.name, []).append(
+                        {str(k): str(v) for k, v in ev.stats})
+    assert {"run", "extract", "stage", "launch", "device", "finalize",
+            "write"} <= set(seen)
+    assert any(s.get("page") == "0" for s in seen["launch"])
+    assert {s.get("video") for s in seen["extract"]} == set(corpus[:2])
+
+
+def test_i3d_flow_step_lowers_with_pwc_scopes(tmp_path, monkeypatch):
+    """The device side: the I3D flow step's lowered text, with debug
+    information, names the step, the tower and PWC-Net's stages — whatever
+    fusion the compiler makes of them later, the operations carry
+    `pwc/resize_in` in their metadata."""
+    import jax
+
+    from video_features_tpu.extractors.i3d import ExtractI3D
+
+    monkeypatch.setenv("VFT_ALLOW_RANDOM_WEIGHTS", "1")
+    ex = ExtractI3D(ExtractionConfig(
+        feature_type="i3d", stack_size=16, step_size=16, flow_type="pwc",
+        i3d_pre_crop_size=96, i3d_crop_size=64, num_devices=1,
+        output_path=str(tmp_path)))
+    stacks = jax.ShapeDtypeStruct((ex.clips_per_batch, 17, 96, 96, 3), np.uint8)
+    text = ex._flow_step.lower(ex.i3d_params["flow"], stacks).as_text(
+        debug_info=True)
+    # mesh.py's scope of the step function's own name, then the tower's
+    assert "flow_forward/i3d/flow/pwc/resize_in" in text
+    for scope in ("pwc/pyramid", "pwc/corr6", "pwc/warp5", "pwc/corr2",
+                  "pwc/decoder4", "pwc/refiner", "pwc/resize_out",
+                  "i3d/flow/I3D/i3d/stem"):
+        assert scope in text, scope
+    assert "jit__flow_forward" in text  # the step keeps its name

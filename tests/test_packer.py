@@ -299,7 +299,10 @@ def test_unsupported_model_falls_back_with_notice(tmp_path, corpus, capsys):
     ex = NoPack(_cfg(tmp_path, "nb", pack_corpus=True))
     assert ex.run(corpus[:2]) == 2
     assert "--pack_corpus ignored" in capsys.readouterr().out
-    assert ex._pack_stats is None  # the per-video loop ran
+    # the per-video loop ran: no packing counters, but its stage seconds
+    # (the accumulators are on in both loops)
+    assert "dispatched_slots" not in ex._pack_stats
+    assert "decode" in ex._pack_stats["stage_seconds"]
     assert len(load_done_set(ex.output_dir)) == 2
 
 

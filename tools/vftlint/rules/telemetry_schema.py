@@ -59,6 +59,10 @@ _DAEMON_MOD = "video_features_tpu/serve/daemon.py"
 
 _BACKTICK = re.compile(r"`([^`]+)`")
 
+# options of the one span call (``Extractor._span`` → ``utils.metrics.span``):
+# which clock stage the seconds go to and the payload bytes — not journal fields
+_SPAN_OPTIONS = frozenset({"stage", "nbytes"})
+
 
 def _receiver_is_journal(func: ast.AST) -> bool:
     if not isinstance(func, ast.Attribute):
@@ -256,7 +260,7 @@ class TelemetrySchemaRule(Rule):
                             seen.add(key)
                             own = frozenset(
                                 kw.arg for kw in call.keywords
-                                if kw.arg is not None)
+                                if kw.arg is not None) - _SPAN_OPTIONS
                             self._wrappers.setdefault(fn.name, []).append(
                                 _Wrapper(rel, fn.name,
                                          params.index(arg.id) - self_offset,
@@ -331,8 +335,9 @@ class TelemetrySchemaRule(Rule):
                     "literal (or declare a forwarding wrapper) so the "
                     f"{_OBS_DOC} catalogue stays checkable"))
                 return
-            fields = frozenset(kw.arg for kw in call.keywords
-                               if kw.arg is not None) | injected
+            fields = (frozenset(kw.arg for kw in call.keywords
+                                if kw.arg is not None) - _SPAN_OPTIONS
+                      | injected)
             sites.append(_Site(rel, call.lineno, events, kind, fields, src))
 
         StringFlow(on_call).scan_block(fn.body)

@@ -154,7 +154,7 @@ class ExtractionConfig:
     # "onehot" (MXU selector matmuls, ops/warp.bilinear_sample_onehot —
     # covers the levels the Mosaic compile cliff bars from the fused
     # kernel). "auto" (default) defers to VFT_WARP_IMPL, unset -> gather,
-    # pending the TPU decision sweep (tools/profile_warp_corr.py --forward).
+    # pending a decision measured on the chip (ROADMAP S4).
     pwc_warp: str = "auto"
     # I3D flow sandwich: decode the PWC pairs in sub-batches of this size
     # under lax.map to bound peak decoder memory (the 64-pair stack at the

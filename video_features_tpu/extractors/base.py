@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import abc
 import contextlib
+import functools
 import os
 import sys
 import threading
@@ -29,6 +30,7 @@ from ..io.output import (
     AsyncOutputWriter,
     WriteHandle,
     feature_output_dir,
+    feats_nbytes,
     load_done_set,
     write_outputs,
 )
@@ -52,10 +54,13 @@ from ..reliability import (
 from ..obs import MetricsRegistry, SpanJournal
 from ..obs.journal import JOURNAL_NAME
 from ..utils.metrics import (
+    BLOCKED_RECORD_SECONDS,
+    SpanRecorder,
     StageClock,
     decode_starvation_warning,
     maybe_profiler,
     metrics_enabled,
+    span,
 )
 
 
@@ -112,8 +117,13 @@ class Extractor(abc.ABC):
         # extractor's runner (one mesh for all co-resident models).
         self.runner = (_CONSTRUCTION_SHARING.get("runner")
                        or MeshRunner(cfg.num_devices, cfg.matmul_precision))
-        # per-video stage clock; active only when metrics are enabled (run())
-        self.clock: Optional[StageClock] = None
+        # stage clock: always on, never None. The per-video loop opens one
+        # per video, the packed loop one per run, the daemon one for its
+        # lifetime. The span records (and the printed report) are behind the
+        # one switch — VFT_METRICS=1, --profile_dir, --telemetry_dir — and
+        # open with the run resources
+        self.clock = StageClock()
+        self._recorder: Optional[SpanRecorder] = None
         # telemetry (docs/observability.md): the span/event journal
         # (--telemetry_dir) and the metrics registry. Opened by
         # _open_telemetry (run resources); a co-loaded serving model shares
@@ -164,6 +174,7 @@ class Extractor(abc.ABC):
         # per-run accounting shared by the per-video and packed loops
         self._ok = 0
         self._failures = 0
+        self._inline_writes = {"videos_written": 0, "write_bytes": 0}
         # --pack_corpus occupancy of the last packed run (bench/run.py report):
         # {"real_slots", "dispatched_slots", "occupancy", "video_clips"}
         self._pack_stats: Optional[Dict] = None
@@ -325,7 +336,7 @@ class Extractor(abc.ABC):
         return open_video_segment(plan, index, transform=self._host_transform,
                                   seek=self.cfg.segment_seek)
 
-    # --- observability hooks (no-ops unless metrics are enabled) ---
+    # --- observability hooks ---
 
     def _open_telemetry(self) -> None:
         """Open the span journal (``--telemetry_dir``) and the metrics
@@ -343,6 +354,9 @@ class Extractor(abc.ABC):
         if self._cache is not None:
             # the store reports quarantines/evictions into the same journal
             self._cache.journal = self._journal
+        if self._recorder is None and metrics_enabled(self.cfg.profile_dir,
+                                                      self.cfg.telemetry_dir):
+            self._recorder = SpanRecorder()
 
     def _emit(self, event: str, **fields) -> None:
         """Append one journal event (no-op without --telemetry_dir); the
@@ -350,11 +364,19 @@ class Extractor(abc.ABC):
         if self._journal is not None:
             self._journal.emit(event, model=self.feature_type, **fields)
 
-    def _span(self, name: str, **fields):
-        """Journal span context (``<name>_start``/``<name>_end`` pair)."""
-        if self._journal is None:
-            return contextlib.nullcontext()
-        return self._journal.span(name, model=self.feature_type, **fields)
+    def _span(self, name: str, stage: Optional[str] = None, nbytes: int = 0,
+              **ids):
+        """THE span call: the one place a layer boundary is marked and timed
+        (:func:`..utils.metrics.span`). One entry/exit adds its seconds (and
+        ``nbytes``) to the stage clock under ``stage``, enters a
+        ``jax.profiler.TraceAnnotation``, emits the journal's
+        ``<name>_start``/``<name>_end`` pair (``--telemetry_dir``) and, with
+        recording on, keeps one record. Yields a handle: ``.ids`` takes what
+        is only known inside the span, ``.seconds`` is its duration after.
+        The decode pool, the packer and the output writer are handed this
+        bound method; they start no timer of their own."""
+        return span(name, self.clock, self._recorder, self._journal,
+                    stage, nbytes, **ids)
 
     def _mark_succeeded(self, path: str) -> None:
         """Shared per-video success accounting: the run counter, the
@@ -369,49 +391,50 @@ class Extractor(abc.ABC):
             self._metrics.inc("videos_ok_total", model=self.feature_type)
 
     def _timed_frames(self, frames_iter):
-        """Attribute host time blocked on decode/transform to the 'decode'
-        stage, and account decoded payload bytes (the ingest-throughput
-        counter the stage report derives decode MB/s from)."""
-        if self.clock is None:
-            return frames_iter
+        """The ``pull`` span: host time blocked in ``next()`` on the frame
+        stream. Every pull adds to the 'decode' stage, with the decoded
+        payload bytes (the ingest-throughput counter the stage report derives
+        decode MB/s from); only a pull that blocked a millisecond or longer
+        leaves a record (:data:`..utils.metrics.BLOCKED_RECORD_SECONDS`), so
+        nothing but the ``perf_counter`` pair runs per frame. The record
+        takes its ``video`` from the ``extract`` span it lies in."""
+        on_blocked = None
+        if self._recorder is not None:
+            on_blocked = functools.partial(self._recorder.add, "pull")
         return self.clock.timed_iter(frames_iter, "decode",
-                                     bytes_of=lambda item: item[0].nbytes)
+                                     bytes_of=lambda item: item[0].nbytes,
+                                     on_blocked=on_blocked)
 
-    def _wait(self, device_out) -> np.ndarray:
-        """Gather a device result, attributing blocked time to 'device_wait'."""
-        if self.clock is None:
-            return np.asarray(device_out)
-        with self.clock.stage("device_wait"):
+    def _wait(self, device_out, **ids) -> np.ndarray:
+        """The ``device`` span: gather a device result, the blocked time on
+        the 'device_wait' stage."""
+        with self._span("device", stage="device_wait", **ids):
             return np.asarray(device_out)
 
     def _transfer_wait(self, seconds: float) -> None:
         """Staging-ring backpressure (blocked until a pending host→device
-        copy finished) is transfer time — attribute it to that stage."""
-        if self.clock is not None:
-            self.clock.add_seconds("transfer", seconds)
+        copy finished) is transfer time: the ring measured it for its own
+        counter, so it is added to the 'transfer' stage and recorded as a
+        ``put`` span after the fact."""
+        self.clock.add_seconds("transfer", seconds)
+        if (self._recorder is not None
+                and seconds >= BLOCKED_RECORD_SECONDS):
+            self._recorder.add("put", seconds, ring_wait=1)
 
     def _put(self, arr):
-        """Transfer a host batch onto the mesh (sharded along axis 0),
-        attributing host dispatch time and the staged payload bytes to the
-        'transfer' stage — the host→device MB/s counter the run report and
-        the serve stats op derive from."""
-        if self.clock is None:
+        """The ``put`` span: transfer a host batch onto the mesh (sharded
+        along axis 0). Host dispatch time and the staged payload bytes land
+        on the 'transfer' stage — the host→device MB/s counter the run report
+        and the serve stats op derive from."""
+        with self._span("put", stage="transfer", nbytes=int(arr.nbytes)):
             return self.runner.put(arr)
-        with self.clock.stage("transfer"):
-            dev = self.runner.put(arr)
-        self.clock.add_bytes("transfer", int(arr.nbytes))
-        return dev
 
     def _put_replicated(self, arr):
         """Replicated transfer with the same 'transfer' attribution. Bytes
         count the HOST payload once (the replication fan-out across devices
         rides the interconnect, not the host staging path)."""
-        if self.clock is None:
+        with self._span("put", stage="transfer", nbytes=int(arr.nbytes)):
             return self.runner.put_replicated(arr)
-        with self.clock.stage("transfer"):
-            dev = self.runner.put_replicated(arr)
-        self.clock.add_bytes("transfer", int(arr.nbytes))
-        return dev
 
     def _stage_rows(self, rows: Sequence[np.ndarray],
                     batch_size: Optional[int] = None) -> np.ndarray:
@@ -421,7 +444,8 @@ class Extractor(abc.ABC):
         buffer's device value back through ``self._staging.commit`` (the
         prefetcher's ``commit`` hook does this) so the buffer is not
         rewritten while its transfer is pending."""
-        return self._staging.stage(rows, batch_size)
+        with self._span("stage"):
+            return self._staging.stage(rows, batch_size)
 
     def _throttle(self, outputs: Sequence) -> None:
         """Bound in-flight device work when per-batch results stay on device.
@@ -451,7 +475,8 @@ class Extractor(abc.ABC):
         """
         paths = list(video_paths) if video_paths is not None else self.video_list()
         done = load_done_set(self.output_dir) if self.cfg.resume else set()
-        with_metrics = metrics_enabled(self.cfg.profile_dir)
+        with_metrics = metrics_enabled(self.cfg.profile_dir,
+                                       self.cfg.telemetry_dir)
         pack = None
         if self.cfg.pack_corpus:
             pack = self.pack_spec()
@@ -460,6 +485,9 @@ class Extractor(abc.ABC):
                       "packing path under this config (--show_pred debug "
                       "runs and the single-clip frame-sharded flow sandwich "
                       "use the per-video loop)")
+        # a fresh record list per run, opened with the run resources where
+        # the switch is on (the daemon keeps one for its lifetime)
+        self._recorder = None
         self._open_run_resources()
         try:
             if pack is not None:
@@ -492,7 +520,7 @@ class Extractor(abc.ABC):
         self._decode_workers = workers
         if workers > 1 and self.uses_frame_stream:
             self._decode_pool = DecodePrefetcher(self._open_inline, workers,
-                                                 journal=self._journal)
+                                                 span=self._span)
             self._decode_pool.set_segmenter(self._plan_inline,
                                             self._open_segment_inline)
         elif workers > 1:
@@ -508,10 +536,12 @@ class Extractor(abc.ABC):
             self._writer = AsyncOutputWriter(
                 depth=2,
                 retry=RetryPolicy(attempts=self.cfg.retries + 1,
-                                  base_delay=self.cfg.retry_backoff))
+                                  base_delay=self.cfg.retry_backoff),
+                span=self._span)
         self._succeeded = []  # pruned from the failure manifest at exit
         self._ok = 0
         self._failures = 0
+        self._inline_writes = {"videos_written": 0, "write_bytes": 0}
 
     def _close_run_resources(self) -> None:
         """Unwind-safe teardown (run()'s ``finally`` and the daemon's)."""
@@ -595,13 +625,27 @@ class Extractor(abc.ABC):
             # outstanding write before starting the next attempt — so a
             # PREDECESSOR's slow write stalls the loop in reap_writes
             # (outside any watchdog), never this video's timeout budget.
-            return self._writer.submit(feats_dict, path, self.output_dir,
-                                       self.cfg.on_extraction,
-                                       cancelled=cancelled)
+            with self._span("write_reap", video=path):
+                return self._writer.submit(feats_dict, path, self.output_dir,
+                                           self.cfg.on_extraction,
+                                           cancelled=cancelled)
         # inline mode: the same shared write contract, on this thread
-        write_outputs(feats_dict, path, self.output_dir,
-                      self.cfg.on_extraction, cancelled=cancelled)
+        nbytes = feats_nbytes(feats_dict)
+        with self._span("write", video=path, bytes=nbytes):
+            write_outputs(feats_dict, path, self.output_dir,
+                          self.cfg.on_extraction, cancelled=cancelled)
+        self._inline_writes["videos_written"] += 1
+        self._inline_writes["write_bytes"] += nbytes
         return None
+
+    def _write_counters(self) -> Dict[str, int]:
+        """The writer's three counters, kept where the work happens (the
+        async writer's own, or the inline path's): deepest backlog (queue
+        plus the job in hand, sampled at every submit; 0 inline), videos
+        written and their payload bytes."""
+        if self._writer is not None:
+            return self._writer.counters()
+        return {"writer_backlog_max": 0, **self._inline_writes}
 
     # --- feature cache (--cache_dir, docs/caching.md) -------------------------
 
@@ -632,11 +676,7 @@ class Extractor(abc.ABC):
         lookup time lands on the 'cache' stage of the report."""
         if self._cache is None:
             return None
-        if self.clock is not None:
-            with self.clock.stage("cache"):
-                key = self._cache_key_for(path)
-                feats = self._cache.get(key) if key is not None else None
-        else:
+        with self._span("cache", stage="cache", video=path):
             key = self._cache_key_for(path)
             feats = self._cache.get(key) if key is not None else None
         if feats is not None:
@@ -798,7 +838,11 @@ class Extractor(abc.ABC):
         while len(pending_writes) > limit:
             wpath, handle = pending_writes[0]
             try:
-                handle.wait()
+                if handle.done():
+                    handle.wait()
+                else:  # blocks on a predecessor's write: worth a span
+                    with self._span("write_reap", video=wpath):
+                        handle.wait()
             except KeyboardInterrupt:
                 raise
             except Exception as e:  # noqa: BLE001 — fault-barrier: the write-side arm of the per-video isolation point
@@ -827,8 +871,9 @@ class Extractor(abc.ABC):
         pending_writes = self._pending_writes
         pending_writes.clear()
         t_run = time.perf_counter()
+        stage_seconds: Dict[str, float] = {}  # summed over the per-video clocks
 
-        with maybe_profiler(self.cfg.profile_dir):
+        with maybe_profiler(self.cfg.profile_dir), self._span("run"):
             for n, path in enumerate(paths, start=1):
                 if os.path.abspath(path) in done:
                     self._ok += 1
@@ -836,9 +881,9 @@ class Extractor(abc.ABC):
                     if progress:
                         progress(n, len(paths))
                     continue
-                self.clock = (StageClock(registry=self._metrics,
-                                         labels={"model": self.feature_type})
-                              if with_metrics else None)
+                # one clock per video: the per-video report's
+                self.clock = StageClock(registry=self._metrics,
+                                        labels={"model": self.feature_type})
                 t0 = time.perf_counter()
                 # consult the cache BEFORE decode: a hit dispatches nothing —
                 # no decode stream, no device step (_cache_fetch never raises;
@@ -859,10 +904,11 @@ class Extractor(abc.ABC):
                         self._publish_cache_hit(path, feats)
                         handle = None  # accounted inside the helper
                     else:
-                        with self._span("extract", video=path):
+                        with self._span("extract", model=self.feature_type,
+                                        video=path):
                             handle = self._attempt_with_retries(path)
                         extracted += 1
-                    if self.clock is not None:
+                    if with_metrics:
                         print(self.clock.report(path, time.perf_counter() - t0))
                     if handle is not None:
                         pending_writes.append((path, handle))
@@ -873,7 +919,9 @@ class Extractor(abc.ABC):
                 except Exception as e:  # noqa: BLE001 — fault-barrier: the per-video isolation point
                     self._fail(path, e)
                 finally:
-                    self.clock = None
+                    for stage, seconds in dict(self.clock.seconds).items():
+                        stage_seconds[stage] = (stage_seconds.get(stage, 0.0)
+                                                + seconds)
                     if self._decode_pool is not None:
                         # cancel this video's decode stream whether it was fully
                         # drained or abandoned by a compute error — an orphaned
@@ -888,9 +936,12 @@ class Extractor(abc.ABC):
                 if progress:
                     progress(n, len(paths))
             self._reap_writes(0)  # tail videos' writes resolve before run() returns
+        self._pack_stats = self._run_stats(stage_seconds)
         if with_metrics and (extracted or
                              (self._cache is not None and self._cache.hits)):
             dt = time.perf_counter() - t_run
+            if self._recorder is not None:
+                print(self._recorder.report())
             hits = f", {self._cache.hits} cache hit(s)" if self._cache else ""
             print(f"extracted {extracted}/{len(paths)} videos "
                   f"({resumed} resumed{hits}) in {dt:.2f}s "
@@ -926,15 +977,14 @@ class Extractor(abc.ABC):
             # corpus-level planning (e.g. the flow extractors' shape-bucket
             # clustering over container probes) before any decode starts
             spec.prepare(todo)
-        self.clock = (StageClock(registry=self._metrics,
-                                 labels={"model": self.feature_type})
-                      if with_metrics else None)  # corpus-level
+        self.clock = StageClock(registry=self._metrics,
+                                labels={"model": self.feature_type})  # corpus-level
         session = PackedSession(self, spec)
         packer = session.packer
         self._pending_writes.clear()
         t_run = time.perf_counter()
 
-        with maybe_profiler(self.cfg.profile_dir):
+        with maybe_profiler(self.cfg.profile_dir), self._span("run"):
             for n, path in enumerate(paths, start=1):
                 if os.path.abspath(path) in done:
                     self._ok += 1
@@ -983,35 +1033,44 @@ class Extractor(abc.ABC):
             # observed in-flight ring — the bench's batches-in-flight proof
             "pages_dispatched": packer.pages_dispatched,
             "max_in_flight": packer.max_in_flight,
+            # per-stage wall seconds for the whole corpus, the writer's
+            # counters and — with recording on — the span records
+            **self._run_stats(dict(self.clock.seconds)),
         }
-        if self.clock is not None:
-            # per-stage wall seconds for the whole corpus (metrics runs only)
-            # — the bench's device_preproc scenario reads the decode stage
-            # from here to show decode-pool relief with the flag on
-            self._pack_stats["stage_seconds"] = {
-                k: round(v, 4) for k, v in self.clock.seconds.items()}
         if with_metrics:
             dt = time.perf_counter() - t_run
-            if self.clock is not None:
-                # the stage report carries pack_occupancy; run.py prints the
-                # canonical standalone occupancy line (once) after the run
-                print(self.clock.report(
-                    f"packed corpus ({extracted} videos)", dt))
-                # ROADMAP item 4: pin the decode-starvation signal — padding
-                # burned while the run sat blocked on decode means the decode
-                # pool, not the mesh, is the ceiling
-                starved = decode_starvation_warning(
-                    occupancy=packer.occupancy,
-                    decode_seconds=self.clock.seconds.get("decode", 0.0),
-                    wall=dt, stale_flushes=packer.stale_flushes,
-                    transfer_seconds=self.clock.seconds.get("transfer", 0.0))
-                if starved:
-                    print(starved, file=sys.stderr)
+            # the stage report carries pack_occupancy; run.py prints the
+            # canonical standalone occupancy line (once) after the run
+            print(self.clock.report(
+                f"packed corpus ({extracted} videos)", dt))
+            if self._recorder is not None:
+                print(self._recorder.report())
+            # ROADMAP item 4: pin the decode-starvation signal — padding
+            # burned while the run sat blocked on decode means the decode
+            # pool, not the mesh, is the ceiling
+            starved = decode_starvation_warning(
+                occupancy=packer.occupancy,
+                decode_seconds=self.clock.seconds.get("decode", 0.0),
+                wall=dt, stale_flushes=packer.stale_flushes,
+                transfer_seconds=self.clock.seconds.get("transfer", 0.0))
+            if starved:
+                print(starved, file=sys.stderr)
             hits = f", {self._cache.hits} cache hit(s)" if self._cache else ""
             print(f"extracted {extracted}/{len(paths)} videos "
                   f"({resumed} resumed{hits}) in {dt:.2f}s")
-        self.clock = None
         return self._ok
+
+    def _run_stats(self, stage_seconds: Dict[str, float]) -> Dict:
+        """What every run leaves in ``_pack_stats`` whatever its loop: the
+        stage clock's seconds, the writer's counters and — with recording on
+        — ``spans``: ``{"clock": "time_ns", "records", "self_seconds",
+        "dropped"}`` (:meth:`..utils.metrics.SpanRecorder.export`)."""
+        stats = {"stage_seconds": {k: round(v, 4)
+                                   for k, v in stage_seconds.items()},
+                 **self._write_counters()}
+        if self._recorder is not None:
+            stats["spans"] = self._recorder.export()
+        return stats
 
 
 class PackedSession:
@@ -1050,7 +1109,7 @@ class PackedSession:
         self.spec = spec
         self.model = model
         if packer is None:
-            packer = CorpusPacker(spec, wait=ex._wait, clock=ex.clock,
+            packer = CorpusPacker(spec, clock=ex.clock, span=ex._span,
                                   flush_age=ex.cfg.pack_flush_age,
                                   staging=ex._staging, journal=ex._journal,
                                   metrics=ex._metrics)
@@ -1088,7 +1147,7 @@ class PackedSession:
             if ex._decode_pool is not None:
                 ex._decode_pool.release(path)
 
-        with ex._span("extract", video=path):
+        with ex._span("extract", model=ex.feature_type, video=path):
             retry_call(
                 lambda: self._drain_stream(path),
                 RetryPolicy(attempts=retries + 1,
@@ -1133,9 +1192,10 @@ class PackedSession:
         ex = self.ex
         for asm in self.packer.pop_completed(model=self.model):
             try:
-                feats = self.spec.finalize(
-                    asm.video, asm.stacked(self.spec.empty_row_shape),
-                    asm.info)
+                with ex._span("finalize", video=asm.video):
+                    feats = self.spec.finalize(
+                        asm.video, asm.stacked(self.spec.empty_row_shape),
+                        asm.info)
                 handle = ex._submit_outputs(asm.video, feats)
             except KeyboardInterrupt:
                 raise
@@ -1297,7 +1357,7 @@ class MultiModelSessions:
                 max_geometries=(HostStagingRing.DEFAULT_MAX_GEOMETRIES
                                 * len(self.models)))
         self.packer = CorpusPacker(
-            wait=primary._wait, clock=primary.clock,
+            clock=primary.clock, span=primary._span,
             flush_age=primary.cfg.pack_flush_age, staging=primary._staging,
             journal=primary._journal, metrics=primary._metrics)
         self._extractors: Dict[str, Extractor] = {
@@ -1354,6 +1414,7 @@ class MultiModelSessions:
                                   metrics=primary._metrics):
             ex = self._factory(model)
         ex.clock = primary.clock
+        ex._recorder = primary._recorder
         ex._writer = primary._writer
         ex._decode_pool = (self._shared_pool()
                            if ex.uses_frame_stream else None)
@@ -1392,7 +1453,7 @@ class MultiModelSessions:
         if self._pool is None and self.primary._decode_workers > 1:
             self._pool = DecodePrefetcher(self._open_routed,
                                           self.primary._decode_workers,
-                                          journal=self.primary._journal)
+                                          span=self.primary._span)
             self._pool.set_segmenter(self._plan_routed,
                                      self._open_segment_routed)
         return self._pool

@@ -118,7 +118,8 @@ class ExtractI3D(Extractor):
 
         # VFT_I3D_S2D=1 opts into the space-to-depth stem lowering; measured
         # SLOWER on v5e (the fold relayout costs more than the small-channel
-        # stem conv, which XLA already runs at ~20 TF/s — tools/profile_i3d.py)
+        # stem conv, which XLA already runs at ~20 TF/s — an earlier
+        # installation's stage profile; not measured on this one)
         s2d = os.environ.get("VFT_I3D_S2D") == "1"
         self.i3d = {s: I3D(modality=s, s2d_stem=s2d, dtype=self.dtype)
                     for s in self.streams}
@@ -164,6 +165,7 @@ class ExtractI3D(Extractor):
 
     # --- jitted stack steps -------------------------------------------------
 
+    @jax.named_scope("i3d/rgb")
     def _rgb_forward(self, params, stacks_u8):  # (N, S+1, H, W, 3) uint8
         # pure per-row stream body — jitted whole by `_rgb_step`, composed
         # (un-jitted) into the paged program by `pack_spec`
@@ -186,6 +188,7 @@ class ExtractI3D(Extractor):
     def _rgb_step(self):
         return self.runner.jit(self._rgb_forward)
 
+    @jax.named_scope("i3d/flow")
     def _flow_forward(self, params, stacks_u8):  # (N, S+1, H, W, 3) uint8
         # pure per-row stream body (flow net + I3D flow stream) — jitted
         # whole by `_flow_step`, composed into the paged program by
@@ -397,6 +400,7 @@ class ExtractI3D(Extractor):
                                              self.i3d_params,
                                              self.clips_per_batch))
 
+    @jax.named_scope("i3d/page")
     def _composite_forward(self, params, stacks_u8):
         # paged composite: every configured stream's un-jitted body over one
         # page, compiled as ONE program by jit_paged — same (N, n_streams,
@@ -468,7 +472,7 @@ class ExtractI3D(Extractor):
                 host_batches(),
                 sharding=sharding,
                 depth=self.cfg.prefetch_depth,
-                clock=self.clock,
+                span=self._span,
                 # commit is a no-op for the frame-sharded mode's view tuples
                 # (their backing ring buffer is guarded per put through the
                 # prefetcher only in standard mode)

@@ -186,7 +186,7 @@ class ExtractResNet50(Extractor):
                 batches(),
                 sharding=self.runner.batch_sharding,
                 depth=self.cfg.prefetch_depth,
-                clock=self.clock,
+                span=self._span,
                 commit=self._staging.commit,
             )
         ):

@@ -29,6 +29,7 @@ import numpy as np
 from ..reliability import OutputError, VideoTimeoutError, fault_point
 from ..reliability.retry import RetryPolicy, retry_call
 from ..reliability.manifest import read_jsonl
+from ..utils.metrics import span as bare_span
 
 MANIFEST_NAME = ".done_manifest.jsonl"
 
@@ -206,15 +207,26 @@ class FeatureAssembly:
         self._rows.clear()
 
 
+def feats_nbytes(feats_dict: Mapping[str, np.ndarray]) -> int:
+    """Payload bytes of one video's feature dict (the ``write`` span's
+    ``bytes`` and the ``write_bytes`` counter)."""
+    return sum(int(getattr(v, "nbytes", 0)) for v in feats_dict.values())
+
+
 class WriteHandle:
     """Completion token for one video's asynchronous output write."""
 
-    __slots__ = ("video", "_done", "_error")
+    __slots__ = ("video", "nbytes", "_done", "_error")
 
-    def __init__(self, video: str):
+    def __init__(self, video: str, nbytes: int = 0):
         self.video = video
+        self.nbytes = nbytes  # payload bytes of the job (the write counters)
         self._done = threading.Event()
         self._error: Optional[BaseException] = None
+
+    def ok(self) -> bool:
+        """The write completed without an error."""
+        return self._done.is_set() and self._error is None
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         """Block until the write completed; re-raises its classified error.
@@ -253,9 +265,22 @@ class AsyncOutputWriter:
       host memory.
     """
 
-    def __init__(self, depth: int = 2, retry: Optional[RetryPolicy] = None):
+    def __init__(self, depth: int = 2, retry: Optional[RetryPolicy] = None,
+                 span=bare_span):
         self._q: queue.Queue = queue.Queue(maxsize=max(depth, 1))
         self._retry = retry
+        # the extractor's one span call (``Extractor._span``): each job runs
+        # inside a ``write`` span on this thread
+        self._span = span
+        # counters, handed out in the run's ``_pack_stats`` (all kept on
+        # the submitting thread, from the handles: the writer thread stores
+        # nothing but a handle's outcome). ``_live`` holds the handles not yet
+        # counted; the unfinished among them are the backlog — the queue plus
+        # the job in hand — sampled at every submit
+        self._live: list = []
+        self.backlog_max = 0
+        self.videos_written = 0
+        self.write_bytes = 0
         self._closed = False
         self._thread = threading.Thread(target=self._drain, daemon=True,
                                         name="output-writer")
@@ -277,10 +302,27 @@ class AsyncOutputWriter:
             raise OutputError("output writer is closed")
         if not self._thread.is_alive():
             raise OutputError("output writer thread died")
-        handle = WriteHandle(video_path)
+        handle = WriteHandle(video_path, feats_nbytes(feats_dict))
+        self.counters()
+        self._live.append(handle)
+        self.backlog_max = max(self.backlog_max, len(self._live))
         self._q.put((handle, feats_dict, video_path, output_path, on_extraction,
                      cancelled))
         return handle
+
+    def counters(self) -> Dict[str, int]:
+        """``writer_backlog_max``, ``videos_written``, ``write_bytes``: counts
+        the handles that finished since the last call (the submitting
+        thread's to call)."""
+        finished = [h for h in self._live if h.done()]
+        self._live = [h for h in self._live if not h.done()]
+        for h in finished:
+            if h.ok():
+                self.videos_written += 1
+                self.write_bytes += h.nbytes
+        return {"writer_backlog_max": self.backlog_max,
+                "videos_written": self.videos_written,
+                "write_bytes": self.write_bytes}
 
     _run_one = staticmethod(write_outputs)  # one write-contract implementation
 
@@ -290,15 +332,22 @@ class AsyncOutputWriter:
             if item is None:
                 return
             handle, *job = item
+            retries = []
             try:
-                if self._retry is not None:
-                    # OutputError is transient (disk/NFS pressure clears);
-                    # retrying here re-runs idempotent steps only — atomic
-                    # saves overwrite, duplicate done records collapse into
-                    # the load_done_set set
-                    retry_call(lambda: self._run_one(*job), self._retry)  # noqa: B023
-                else:
-                    self._run_one(*job)
+                with self._span("write", video=handle.video,
+                                bytes=handle.nbytes) as sp:
+                    try:
+                        if self._retry is not None:
+                            # OutputError is transient (disk/NFS pressure
+                            # clears); retrying here re-runs idempotent steps
+                            # only — atomic saves overwrite, duplicate done
+                            # records collapse into the load_done_set set
+                            retry_call(lambda: self._run_one(*job), self._retry,  # noqa: B023
+                                       on_retry=lambda *a: retries.append(a))  # noqa: B023
+                        else:
+                            self._run_one(*job)
+                    finally:
+                        sp.ids.update(retries=len(retries))
             except Exception as e:  # noqa: BLE001 — fault-barrier: stored on the handle, re-raised classified at the run loop's per-video write reap
                 handle._error = e  # thread-shared-state: set before the _done Event; wait() reads after it
             finally:

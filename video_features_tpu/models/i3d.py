@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import Any, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from .layers import (
@@ -141,16 +142,26 @@ class I3D(nn.Module):
                 f"{self.modality} I3D expects {expected_c} input channels, got {x.shape[-1]}"
             )
         x = x.astype(self.dtype)
-        for op, name, *spec in I3D_STEM:
-            if op == "conv":
-                feats, kernel, stride = spec
-                s2d = self.s2d_stem and name == "conv3d_1a_7x7"
-                x = Unit3D(feats, kernel, stride, s2d=s2d, dtype=self.dtype, name=name)(x)
-            elif op == "pool":
-                kernel, stride = spec
-                x = max_pool_tf_same(x, kernel, stride)
-            else:
-                x = Mixed(spec[0], dtype=self.dtype, name=name)(x)
+        def walk(x, ops):
+            for op, name, *spec in ops:
+                if op == "conv":
+                    feats, kernel, stride = spec
+                    s2d = self.s2d_stem and name == "conv3d_1a_7x7"
+                    x = Unit3D(feats, kernel, stride, s2d=s2d, dtype=self.dtype,
+                               name=name)(x)
+                elif op == "pool":
+                    kernel, stride = spec
+                    x = max_pool_tf_same(x, kernel, stride)
+                else:
+                    x = Mixed(spec[0], dtype=self.dtype, name=name)(x)
+            return x
+
+        # device scope ``i3d/stem``: everything before the first inception
+        # block (flax names each module's operations by its own name besides)
+        n_stem = next(i for i, spec in enumerate(I3D_STEM) if spec[0] == "mixed")
+        with jax.named_scope("i3d/stem"):
+            x = walk(x, I3D_STEM[:n_stem])
+        x = walk(x, I3D_STEM[n_stem:])
 
         # (B, T', 7, 7, 1024) → AvgPool3d((2,7,7), stride 1) → (B, T'-1, 1, 1, 1024).
         # The reference kernel (2,7,7) assumes the 224-crop geometry where the final

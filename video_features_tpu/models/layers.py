@@ -56,7 +56,8 @@ def tf_same_pads(kernel: Sequence[int], stride: Sequence[int]) -> Tuple[Tuple[in
 class S2DStemConv(nn.Module):
     """Stride-2³ 7³ stem conv computed space-to-depth: the MXU formulation.
 
-    Measured result (tools/profile_i3d.py, v5e, 4×64×224² fp32): SLOWER than
+    Measured result (an earlier installation's stage profile, v5e,
+    4×64×224² fp32; not measured on this one): SLOWER than
     the direct conv — 37 ms vs 10.5 ms — because the fold's input relayout
     costs more than the stem conv, which XLA already runs at ~20 TF/s despite
     cin=3. Kept as a tested opt-in (``VFT_I3D_S2D=1`` /

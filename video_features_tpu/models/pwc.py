@@ -53,6 +53,14 @@ LEVEL_NAMES = {2: "moduleTwo", 3: "moduleThr", 4: "moduleFou", 5: "moduleFiv", 6
 from ..ops.pallas_corr import corr81_xla as correlation_81  # noqa: E402, F401
 
 
+# Device scopes (``jax.named_scope``): a profiler trace names every operation
+# of the net by its stage — ``pwc/resize_in``, ``pwc/pyramid``,
+# ``pwc/warp<level>``, ``pwc/corr<level>``, ``pwc/decoder<level>``,
+# ``pwc/refiner``, ``pwc/resize_out`` — whatever fusion the compiler makes of
+# it (docs/observability.md "reading a device trace").
+
+
+@jax.named_scope("pwc/pyramid")
 def _pyramid(p: Dict, x: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
     """6-level feature pyramid (pwc_net.py:44-110); 3 convs per level."""
     names = ("moduleOne", "moduleTwo", "moduleThr", "moduleFou", "moduleFiv", "moduleSix")
@@ -70,23 +78,27 @@ def _decoder(p: Dict, level: int, f1: jnp.ndarray, f2: jnp.ndarray, prev,
              corr_impl: str = "xla", warp_impl: str = "auto"):
     """One coarse-to-fine stage (pwc_net.py:152-187)."""
     if prev is None:
-        volume = leaky_relu(corr81(f1, f2, corr_impl))
+        with jax.named_scope(f"pwc/corr{level}"):
+            volume = leaky_relu(corr81(f1, f2, corr_impl))
         feat = volume
     else:
-        flow = conv2d_transpose(p["moduleUpflow"], prev["flow"])
-        upfeat = conv2d_transpose(p["moduleUpfeat"], prev["feat"])
+        with jax.named_scope(f"pwc/decoder{level}"):
+            flow = conv2d_transpose(p["moduleUpflow"], prev["flow"])
+            upfeat = conv2d_transpose(p["moduleUpfeat"], prev["feat"])
         # fused warp+correlate (ops/pallas_corr.warp_corr81): under pallas/auto
         # the warped f2 never exists in HBM — warp gathers were the PWC floor
         volume = leaky_relu(warp_corr81(f1, f2, flow * DEC_BACKWARD[level],
-                                        corr_impl, warp_impl))
+                                        corr_impl, warp_impl, level=str(level)))
         feat = jnp.concatenate([volume, f1, flow, upfeat], axis=-1)
 
-    for name in ("moduleOne", "moduleTwo", "moduleThr", "moduleFou", "moduleFiv"):
-        feat = jnp.concatenate([leaky_relu(conv2d(p[name]["0"], feat, 1, 1)), feat], axis=-1)
-    flow = conv2d(p["moduleSix"]["0"], feat, 1, 1)
+    with jax.named_scope(f"pwc/decoder{level}"):
+        for name in ("moduleOne", "moduleTwo", "moduleThr", "moduleFou", "moduleFiv"):
+            feat = jnp.concatenate([leaky_relu(conv2d(p[name]["0"], feat, 1, 1)), feat], axis=-1)
+        flow = conv2d(p["moduleSix"]["0"], feat, 1, 1)
     return {"flow": flow, "feat": feat}
 
 
+@jax.named_scope("pwc/refiner")
 def _refiner(p: Dict, feat: jnp.ndarray) -> jnp.ndarray:
     """Dilated context network (pwc_net.py:189-210)."""
     dilations = (1, 2, 4, 8, 16, 1)
@@ -100,7 +112,8 @@ def _preprocess(image: jnp.ndarray, h64: int, w64: int) -> jnp.ndarray:
     """RGB [0,255] → BGR /255 (pwc_net.py:230) resized to the /64 grid."""
     x = image[..., ::-1].astype(jnp.float32) / 255.0
     if (h64, w64) != image.shape[-3:-1]:
-        x = resize_bilinear_torch(x, h64, w64)
+        with jax.named_scope("pwc/resize_in"):
+            x = resize_bilinear_torch(x, h64, w64)
     return x
 
 
@@ -114,9 +127,10 @@ def _decode(params: Dict, pyr1, pyr2, h: int, w: int, h64: int, w64: int,
                        warp_impl)
 
     flow = est["flow"] + _refiner(params["moduleRefiner"]["moduleMain"], est["feat"])
-    flow = 20.0 * resize_bilinear_torch(flow.astype(jnp.float32), h, w)
-    scale = jnp.asarray([w / w64, h / h64], jnp.float32)
-    return flow * scale
+    with jax.named_scope("pwc/resize_out"):
+        flow = 20.0 * resize_bilinear_torch(flow.astype(jnp.float32), h, w)
+        scale = jnp.asarray([w / w64, h / h64], jnp.float32)
+        return flow * scale
 
 
 def _grid64(h: int, w: int) -> Tuple[int, int]:
@@ -157,7 +171,8 @@ def pwc_forward_frames(params: Dict, frames: jnp.ndarray,
     → (N, F−1, H, W, 2) — pairs never cross clip boundaries.
 
     TPU-first formulation of the reference's pair loop: the feature pyramid —
-    PWC's dominant stage (small-channel convs at 128²/64², tools/profile_pwc.py)
+    PWC's dominant stage by an earlier stage profile (small-channel convs at
+    128²/64²; on the v5e the resize gathers are, PERF.md §5)
     — is computed ONCE per frame (clips flattened into the conv batch axis) and
     pairs are formed by slicing the shared per-frame features, instead of
     re-encoding ``frames[:-1]`` and ``frames[1:]`` separately (which encodes

@@ -56,7 +56,7 @@ def resolve_corr_impl(corr_impl: str, n_pairs: int, h: int, w: int,
     1080p TPU sweep justifies it — its FLOPs scale with frame area, the
     gather's with the fixed window; see the big-frame comment below). In fp32
     the paths agree to reduction-order ulps (~3e-3 px
-    through 20 iterations, tools/profile_on_demand.py); under
+    through 20 iterations, a CPU comparison of an earlier round); under
     ``dtype=bfloat16`` the volume path stores a bf16 pyramid while the remat
     rounds the einsum inputs — the same one-bf16-rounding drift class,
     bounded in tests/test_flow_bf16.py.
@@ -89,7 +89,7 @@ def resolve_corr_impl(corr_impl: str, n_pairs: int, h: int, w: int,
     # the fixed 10×10 window, so the 3.2-3.6× win measured at 64×64 on CPU
     # can invert by ~300× more remat work at 1080p — exactly the regime auto
     # selects this path. Flip back to matmul only on a committed 1080p TPU
-    # measurement from tools/profile_on_demand.py
+    # measurement (ROADMAP S5)
     # (VFT_RAFT_ON_DEMAND_IMPL=matmul opts in per run meanwhile).
     choice = os.environ.get("VFT_RAFT_ON_DEMAND_IMPL", "gather")
     if choice not in ("gather", "matmul"):
@@ -219,7 +219,8 @@ def _lookup(pyramid, coords: jnp.ndarray, impl: str = "matmul") -> jnp.ndarray:
       gather unit. Out-of-bounds taps fall out as all-zero one-hot rows, which
       IS the reference's zero-padding semantics (grid_sample
       padding_mode='zeros', per corner tap). Measured on TPU v5e at batch
-      16 × 256² (tools/profile_raft.py): 20 lookups 1370 ms → 63 ms; full
+      16 × 256² (an earlier installation's stage profile; not measured on
+      this one): 20 lookups 1370 ms → 63 ms; full
       20-iteration forward 1551 ms → 100 ms (15.5×).
     - ``gather``: one ``take_along_axis`` patch gather per level (the exact
       arithmetic reference path; also the faster lowering on CPU).
@@ -250,8 +251,8 @@ def _lookup(pyramid, coords: jnp.ndarray, impl: str = "matmul") -> jnp.ndarray:
             # selection has no accumulation error at ANY precision, only the
             # value rounding the bf16 volume already paid, and the MXU runs
             # single-pass instead of the 6-pass fp32 sequence (the lookup is
-            # 70% of the fp32 step: 77.7 of 111 ms at b16·256²,
-            # tools/profile_raft.py).
+            # 70% of the fp32 step: 77.7 of 111 ms at b16·256², an earlier
+            # installation's stage profile).
             prec = (lax.Precision.HIGHEST if corr.dtype == jnp.float32
                     else lax.Precision.DEFAULT)
             rows = jnp.einsum("npi,nij->npj", sy.astype(corr.dtype),

@@ -146,6 +146,7 @@ def corr81_pallas(f1: jnp.ndarray, f2: jnp.ndarray, interpret: bool = False) -> 
         out_specs=pl.BlockSpec((1, h, w, CORR_CHANNELS), lambda i: (i, 0, 0, 0)),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="pwc_corr81_single",
     )(f1, f2p)
 
 
@@ -213,6 +214,7 @@ def corr81_pallas_tiled(f1: jnp.ndarray, f2: jnp.ndarray,
                                lambda i, j, k: (i, j, k, 0)),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="pwc_corr81_tiled",
     )(f1p, f2p)
     return out[:, :h, :w, :]
 
@@ -394,6 +396,7 @@ def warp_corr81_pallas(f1: jnp.ndarray, f2: jnp.ndarray, flow: jnp.ndarray,
                                lambda i, j, k: (i, j, k, 0)),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="pwc_warp_corr81_fused",
     )(f1p, f2, flowp)
     return out[:, :h, :w, :]
 
@@ -445,7 +448,8 @@ def _fused_enabled() -> bool:
 
 
 def warp_corr81(f1: jnp.ndarray, f2: jnp.ndarray, flow: jnp.ndarray,
-                impl: str = "xla", warp_impl: str = "auto") -> jnp.ndarray:
+                impl: str = "xla", warp_impl: str = "auto",
+                level: str = "") -> jnp.ndarray:
     """Backward-warp ``f2`` by ``flow`` (already level-scaled) and correlate.
 
     ``impl`` — ``xla``: the two-stage composition (warp → fused-XLA volume).
@@ -458,17 +462,25 @@ def warp_corr81(f1: jnp.ndarray, f2: jnp.ndarray, flow: jnp.ndarray,
     ``warp_impl`` — the composition's warp lowering: ``gather`` | ``onehot``
     (MXU selector matmuls, ops/warp.bilinear_sample_onehot) | ``auto``
     (VFT_WARP_IMPL, unset → gather).
+
+    ``level`` names the device scopes a profiler trace shows the work under:
+    ``pwc/warp<level>`` and ``pwc/corr<level>`` (the fused kernel is both, and
+    sits under the second).
     """
     from .warp import warp_backward
 
-    if impl == "pallas_interpret":
-        return warp_corr81_pallas(f1, f2, flow, interpret=True)
-    if impl in ("pallas", "auto") and _fused_enabled() \
-            and jax.default_backend() == "tpu" and f1.dtype in _KERNEL_DTYPES:
-        _, h, w, c = f1.shape
-        if _warp_corr_supported(h, w, c, jnp.dtype(f1.dtype).itemsize):
-            return warp_corr81_pallas(f1, f2, flow)
-    return corr81(f1, warp_backward(f2, flow, warp_impl), impl)
+    with jax.named_scope(f"pwc/corr{level}"):
+        if impl == "pallas_interpret":
+            return warp_corr81_pallas(f1, f2, flow, interpret=True)
+        if impl in ("pallas", "auto") and _fused_enabled() \
+                and jax.default_backend() == "tpu" and f1.dtype in _KERNEL_DTYPES:
+            _, h, w, c = f1.shape
+            if _warp_corr_supported(h, w, c, jnp.dtype(f1.dtype).itemsize):
+                return warp_corr81_pallas(f1, f2, flow)
+    with jax.named_scope(f"pwc/warp{level}"):
+        warped = warp_backward(f2, flow, warp_impl)
+    with jax.named_scope(f"pwc/corr{level}"):
+        return corr81(f1, warped, impl)
 
 
 def corr81_lowering(shape, f1_dtype, f2_dtype, impl: str) -> str:

@@ -21,6 +21,7 @@ embarrassingly-parallel split the reference documents via ``gen_file_list.py``.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
@@ -88,10 +89,17 @@ def sharded_apply(mesh: Mesh, fn: Callable, n_batch_args: int = 1,
     rather than batch-sharded — the encode-once flow steps pass the window's
     final frame this way (a (1, H, W, 3) array cannot shard over the mesh).
     """
-    if matmul_precision is not None:
-        inner = fn
+    # one device scope per jitted step, under the step function's own name:
+    # every model's operations carry a name in a profiler trace, whether or
+    # not the model sets scopes of its own (docs/observability.md)
+    inner = fn
+    scope = getattr(inner, "__name__", "step").lstrip("_")
 
-        def fn(*args):  # noqa: F811 — precision must be active at trace time
+    @functools.wraps(inner)  # the program keeps the step's name (jit_<name>)
+    def fn(*args):  # noqa: F811 — precision must be active at trace time
+        with jax.named_scope(scope):
+            if matmul_precision is None:
+                return inner(*args)
             with jax.default_matmul_precision(matmul_precision):
                 return inner(*args)
 

@@ -72,7 +72,6 @@ from __future__ import annotations
 
 import heapq
 import sys
-import time
 from collections import Counter, deque
 from dataclasses import dataclass
 from typing import (
@@ -91,6 +90,7 @@ import numpy as np
 
 from ..io.output import FeatureAssembly
 from ..reliability.faults import fault_point
+from ..utils.metrics import span as bare_span
 from .pages import TABLE_COLS, build_row_table
 
 
@@ -278,7 +278,7 @@ class CorpusPacker:
     def __init__(self, spec: Optional[PackSpec] = None,
                  wait: Callable[[Any], np.ndarray] = np.asarray,
                  clock=None, flush_age: int = 0, staging=None,
-                 journal=None, metrics=None):
+                 journal=None, metrics=None, span=bare_span):
         # model name -> PackSpec. Single-model callers (the batch loop, the
         # engine tests) pass one spec, registered under None; the multi-model
         # serving layer constructs spec-less and register_model()s each
@@ -291,11 +291,19 @@ class CorpusPacker:
         self._wait = wait
         self._clock = clock  # optional StageClock: packed_slots/packed_clips units
         self._flush_age = flush_age
+        # the extractor's one span call (``Extractor._span``; the bare
+        # ``utils.metrics.span`` without one). Per dispatched page: a 'stage'
+        # span around the page's assembly, a 'launch' span around the step
+        # call (the extractor's 'put' spans nest inside it) and a 'device'
+        # span around the fetch, all carrying ``page=<n>``, the running page
+        # number — the packer starts no timer of its own
+        self._span = span
+        self._page_seq = 0
         # telemetry (docs/observability.md): the span journal gets a
-        # 'dispatch' instant per dispatched batch, a 'device' span around
-        # each batch fetch, and 'stale_flush' instants; the metrics registry
-        # gets per-bucket occupancy gauges and the device_batch_seconds
-        # histogram. Both optional and emit-only — never block dispatch.
+        # 'dispatch' instant per dispatched batch and 'stale_flush' instants;
+        # the metrics registry gets per-bucket occupancy gauges and the
+        # device_batch_seconds histogram, fed by the 'device' span's own
+        # duration. Both optional and emit-only — never block dispatch.
         self._journal = journal
         self._metrics = metrics
         # optional HostStagingRing: the default (no-collate) batch assembly
@@ -501,17 +509,20 @@ class CorpusPacker:
         queue = self._pending[key]
         batch_size = self._batch_rows(spec)
         candidates = queue[:batch_size]
-        if spec.collate is not None:
-            batch, n_used, row_of = spec.collate(
-                [s.clip for s in candidates],
-                [(id(s.assembly), s.idx) for s in candidates])
-            slots = candidates[:n_used]
-            del queue[:n_used]  # in place: flush() iterates this same list
-        else:
-            slots = candidates
-            del queue[:batch_size]
-            batch = self._stage_batch([s.clip for s in slots], batch_size)
-            row_of = range(len(slots))
+        page = self._page_seq  # the running page number: this dispatch's id
+        self._page_seq += 1
+        with self._span("stage", page=page):
+            if spec.collate is not None:
+                batch, n_used, row_of = spec.collate(
+                    [s.clip for s in candidates],
+                    [(id(s.assembly), s.idx) for s in candidates])
+                slots = candidates[:n_used]
+                del queue[:n_used]  # in place: flush() iterates this same list
+            else:
+                slots = candidates
+                del queue[:batch_size]
+                batch = self._stage_batch([s.clip for s in slots], batch_size)
+                row_of = range(len(slots))
         # depth-k ring: resolve this bucket's OLDEST unfetched batch only
         # when the ring is full, so scatter of batch k overlaps the device
         # chewing on k+1..k+depth (bucketed depth is 1 — the original
@@ -525,12 +536,18 @@ class CorpusPacker:
         # every co-packed video of every admitted request
         fault_point("device", str(key))
         if paged:
-            table = self._stage_table(slots, batch_size)
-            out = spec.paged_step(batch, table)
-            fetchable = out[0]  # device rows; the donated table out is dropped
-        else:
-            out = spec.step(batch)
-            fetchable = out
+            with self._span("stage", page=page):
+                table = self._stage_table(slots, batch_size)
+        # host time of the step call: the extractor's puts (their own spans,
+        # inside this one), tracing, and a cache load or compile on a first
+        # shape
+        with self._span("launch", page=page):
+            if paged:
+                out = spec.paged_step(batch, table)
+                fetchable = out[0]  # device rows; the donated table out is dropped
+            else:
+                out = spec.step(batch)
+                fetchable = out
         self._rr_last = key[0]  # round-robin seed: the model just served
         if self._staging is not None:
             # no-op for batches the ring does not own (collate specs commit
@@ -539,7 +556,7 @@ class CorpusPacker:
             if paged:
                 self._staging.commit(table, out)
         self.staged_bytes += int(getattr(batch, "nbytes", 0))
-        ring.append((slots, row_of, fetchable))
+        ring.append((slots, row_of, fetchable, page))
         self.max_in_flight = max(self.max_in_flight, len(ring))
         # a bucket being served is not starving: age counts from its last
         # activity (dispatch here, slot arrival in add())
@@ -561,7 +578,7 @@ class CorpusPacker:
         if self._journal is not None:
             self._journal.emit("dispatch", bucket=self._bucket_name(key),
                                real_slots=len(slots), batch_slots=batch_size,
-                               paged=paged, inflight=len(ring))
+                               paged=paged, inflight=len(ring), page=page)
         if self._metrics is not None:
             occ = round(stats["real_slots"] / stats["dispatched_slots"], 4)
             self._metrics.set_gauge("bucket_occupancy", occ,
@@ -612,30 +629,25 @@ class CorpusPacker:
         ring = self._inflight.get(key)
         if not ring:
             return
-        slots, row_of, fetchable = ring.popleft()
-        host = self._fetch_batch(key, fetchable)
+        slots, row_of, fetchable, page = ring.popleft()
+        host = self._fetch_batch(key, fetchable, page)
         for i, slot in enumerate(slots):
             slot.assembly.put(slot.idx, host[row_of[i]])
 
-    def _fetch_batch(self, key: tuple, out) -> np.ndarray:
-        """Fetch one batch's device output through the extractor's
-        device_wait-accounted ``_wait``, with the blocked time journaled as
-        a per-batch 'device' span and observed into the
-        ``device_batch_seconds`` histogram (labeled by model — the
-        per-BATCH device distribution; per-video device attribution does
-        not exist under packing, where a batch mixes videos)."""
-        if self._journal is None and self._metrics is None:
-            return self._wait(out)
-        t0 = time.perf_counter()
-        if self._journal is not None:
-            with self._journal.span("device", bucket=self._bucket_name(key)):
-                host = self._wait(out)
-        else:
+    def _fetch_batch(self, key: tuple, out, page: int) -> np.ndarray:
+        """Fetch one batch's device output inside ONE 'device' span: its
+        duration is what the 'device_wait' stage, the journal's pair, the
+        span record and the ``device_batch_seconds`` histogram all get
+        (labeled by model — the per-BATCH device distribution; per-video
+        device attribution does not exist under packing, where a batch mixes
+        videos)."""
+        with self._span("device", stage="device_wait",
+                        bucket=self._bucket_name(key), page=page) as sp:
             host = self._wait(out)
         if self._metrics is not None:
             model = key[0] if key[0] is not None else "default"
-            self._metrics.observe("device_batch_seconds",
-                                  time.perf_counter() - t0, model=model)
+            self._metrics.observe("device_batch_seconds", sp.seconds,
+                                  model=model)
         return host
 
     def _flush_stale(self) -> None:

@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from ..reliability import fault_point
+from ..utils.metrics import span as bare_span
 
 
 def maybe_initialize_distributed() -> bool:
@@ -125,19 +126,21 @@ class DecodePrefetcher:
     _DONE = object()
 
     def __init__(self, open_fn: Callable, workers: int, max_buffered: int = 512,
-                 max_buffered_bytes: int = 512 << 20, journal=None):
+                 max_buffered_bytes: int = 512 << 20, span=bare_span):
         if workers < 1:
             raise ValueError("decode workers must be >= 1")
         self._open = open_fn
         self._max = max_buffered
         self._max_bytes = max_buffered_bytes
-        # optional ..obs.SpanJournal: each worker wraps its video in a
-        # 'decode' span (emit is a non-blocking queue put — thread-safe and
-        # never the decode path's problem). The span covers the worker's full
-        # occupancy of a decode slot: open + frame production, INCLUDING time
-        # blocked on a full buffer (consumer backpressure) — it answers "what
-        # was this decode slot doing", not "how fast is cv2".
-        self._journal = journal
+        # the extractor's one span call (``Extractor._span``; the bare
+        # ``utils.metrics.span`` without one): each worker wraps its video in
+        # a 'decode' span — a record, a trace annotation and the journal's
+        # pair (a non-blocking queue put, never the decode path's problem).
+        # The span covers the worker's full occupancy of a decode slot: open
+        # + frame production, INCLUDING time blocked on a full buffer
+        # (consumer backpressure) — it answers "what was this decode slot
+        # doing", not "how fast is cv2".
+        self._span = span
         self._slots: dict = {}  # scheduled, not yet consumed
         self._handed: dict = {}  # handed to a consumer via get(), not released
         self._stop = threading.Event()
@@ -384,69 +387,58 @@ class DecodePrefetcher:
 
         if not reserved:
             self._acquire_baseline()
-        # journal 'decode' span: full occupancy of this decode slot
-        sid = None
-        if self._journal is not None:
-            if segment is None:
-                sid = self._journal.begin("decode", video=path)
-            else:
-                sid = self._journal.begin("decode", video=path,
-                                          segment=segment, segments=segments)
         clean = False
         try:
-            try:
-                if stopped():
-                    return
-                # crash-injection seam: a worker dying HERE (not inside
-                # open_fn) must still surface a classified error at consume
-                # time instead of deadlocking the drain — tests prove it
-                fault_point("pool_worker", path)
-                meta, frames = produce()
-                slot["meta"] = meta  # thread-shared-state: published by the ready Event set below
-                slot["ready"].set()
-                for item in frames:
-                    nbytes = _item_bytes(item)
-                    # byte bound: wait for buffered-payload room (the frame
-                    # COUNT bound is the queue's maxsize below; the tighter
-                    # of the two governs). An empty buffer always admits one
-                    # item, so a single frame larger than the cap still flows.
-                    while not stopped():
-                        with slot["lock"]:
-                            fits = (slot["bytes"] == 0
-                                    or slot["bytes"] + nbytes <= slot["max_bytes"])
-                        if fits:
-                            break
-                        time.sleep(0.05)
+            # 'decode' span: full occupancy of this decode slot
+            with self._span("decode", video=path, segment=segment,
+                            segments=segments):
+                try:
                     if stopped():
                         return
+                    # crash-injection seam: a worker dying HERE (not inside
+                    # open_fn) must still surface a classified error at consume
+                    # time instead of deadlocking the drain — tests prove it
+                    fault_point("pool_worker", path)
+                    meta, frames = produce()
+                    slot["meta"] = meta  # thread-shared-state: published by the ready Event set below
+                    slot["ready"].set()
+                    for item in frames:
+                        nbytes = _item_bytes(item)
+                        # byte bound: wait for buffered-payload room (the frame
+                        # COUNT bound is the queue's maxsize below; the tighter
+                        # of the two governs). An empty buffer always admits one
+                        # item, so a single frame larger than the cap still flows.
+                        while not stopped():
+                            with slot["lock"]:
+                                fits = (slot["bytes"] == 0
+                                        or slot["bytes"] + nbytes <= slot["max_bytes"])
+                            if fits:
+                                break
+                            time.sleep(0.05)
+                        if stopped():
+                            return
+                        while not stopped():
+                            try:
+                                slot["q"].put(item, timeout=0.2)
+                                with slot["lock"]:
+                                    slot["bytes"] += nbytes  # thread-shared-state: guarded by slot['lock'] (consumer decrements under the same lock)
+                                break
+                            except queue.Full:
+                                continue
+                        if stopped():
+                            return
+                    clean = not stopped()
+                except Exception as e:  # noqa: BLE001 — fault-barrier: re-raised classified at consume time
+                    slot["err"] = e  # thread-shared-state: published by the ready Event / _DONE sentinel in finally
+                finally:
+                    slot["ready"].set()
                     while not stopped():
                         try:
-                            slot["q"].put(item, timeout=0.2)
-                            with slot["lock"]:
-                                slot["bytes"] += nbytes  # thread-shared-state: guarded by slot['lock'] (consumer decrements under the same lock)
+                            slot["q"].put(self._DONE, timeout=0.2)
                             break
-                        except queue.Full:
+                        except queue.Full:  # consumer will drain; retry
                             continue
-                    if stopped():
-                        return
-                clean = not stopped()
-            except Exception as e:  # noqa: BLE001 — fault-barrier: re-raised classified at consume time
-                slot["err"] = e  # thread-shared-state: published by the ready Event / _DONE sentinel in finally
-            finally:
-                slot["ready"].set()
-                while not stopped():
-                    try:
-                        slot["q"].put(self._DONE, timeout=0.2)
-                        break
-                    except queue.Full:  # consumer will drain; retry
-                        continue
         finally:
-            if sid is not None:
-                if segment is None:
-                    self._journal.end("decode", sid, video=path)
-                else:
-                    self._journal.end("decode", sid, video=path,
-                                      segment=segment, segments=segments)
             if clean and segment is not None:
                 with self._resize_lock:
                     self._segments_decoded += 1  # thread-shared-state: guarded by the 'resize' lock (stats counter, segment_stats reads under it)
@@ -689,7 +681,7 @@ def prefetch_to_device(
     arrays: Iterable[np.ndarray],
     sharding=None,
     depth: int = 2,
-    clock=None,
+    span=None,
     commit: Optional[Callable] = None,
 ) -> Iterator[jax.Array]:
     """Iterate device arrays with ``depth`` transfers in flight.
@@ -700,8 +692,9 @@ def prefetch_to_device(
     ``sharding`` a matching pytree of shardings — ``jax.device_put`` accepts
     both.
 
-    ``clock``: optional :class:`..utils.metrics.StageClock` — the put
-    dispatch time and the staged payload bytes land on the 'transfer' stage.
+    ``span``: the extractor's one span call (``Extractor._span``) — each put
+    is a ``put`` span: the dispatch time and the staged payload bytes land on
+    the 'transfer' stage.
     ``commit(host, dev)``: optional hook called right after each put — the
     extractors pass :meth:`HostStagingRing.commit` so ring-staged batches are
     guarded against rewrite until their transfer completes.
@@ -712,14 +705,12 @@ def prefetch_to_device(
     it = iter(arrays)
 
     def put(host):
-        if clock is None:
+        if span is None:
             return jax.device_put(host, sharding)
-        with clock.stage("transfer"):
-            dev = jax.device_put(host, sharding)
-        clock.add_bytes("transfer", sum(
-            int(getattr(leaf, "nbytes", 0))
-            for leaf in jax.tree_util.tree_leaves(host)))
-        return dev
+        nbytes = sum(int(getattr(leaf, "nbytes", 0))
+                     for leaf in jax.tree_util.tree_leaves(host))
+        with span("put", stage="transfer", nbytes=nbytes):
+            return jax.device_put(host, sharding)
 
     def enqueue() -> bool:
         try:

@@ -542,7 +542,12 @@ class ExtractionService:
         d0 = clock.seconds.get("decode", 0.0)
         x0 = clock.seconds.get("transfer", 0.0)
         try:
-            self.session.ingest(path, model, retries=0)
+            # the 'job' span: one popped job of the serving loop. Its
+            # request id is inherited by the spans inside it ('extract' and
+            # everything under that)
+            with ex._span("job", request=job.request.request_id,
+                          tenant=tenant, video=path):
+                self.session.ingest(path, model, retries=0)
         except KeyboardInterrupt:
             raise
         except Exception as e:  # noqa: BLE001 — fault-barrier: the per-video isolation point (serving loop)
@@ -614,7 +619,6 @@ class ExtractionService:
         if self._wal is not None:
             self._wal.close()
         self.sessions.close()
-        self.ex.clock = None
 
     def _try_cache(self, job, ex) -> bool:
         """Feature-cache consult + in-flight coalescing for one popped job.
@@ -1002,8 +1006,8 @@ class ExtractionService:
         """Host→device staging counters from the service-lifetime clock plus
         the staging ring's reuse/backpressure accounting."""
         clock = self.ex.clock
-        seconds = clock.seconds.get("transfer", 0.0) if clock else 0.0
-        nbytes = clock.bytes.get("transfer", 0) if clock else 0
+        seconds = clock.seconds.get("transfer", 0.0)
+        nbytes = clock.bytes.get("transfer", 0)
         ring = self.ex._staging
         return {
             "seconds": round(seconds, 3),
@@ -1079,9 +1083,8 @@ class ExtractionService:
                 # meter for it (tools/service_smoke.py pins the section).
                 # dict() snapshots atomically under the GIL before iterating
                 # — the run loop may be inserting a first-seen stage key
-                "stages": ({k: round(v, 3)
-                            for k, v in dict(self.ex.clock.seconds).items()}
-                           if self.ex.clock is not None else {}),
+                "stages": {k: round(v, 3)
+                           for k, v in dict(self.ex.clock.seconds).items()},
                 "cache": (dict(self.ex._cache.stats(),
                                coalesced=self._coalescer.coalesced,
                                waiting=self._coalescer.waiting())

@@ -2,9 +2,20 @@
 bars and nothing else; diagnosing whether decode, transfer, or compute bounds a
 run is the whole perf game on TPU).
 
-Opt-in: ``--profile_dir DIR`` wraps the run in a ``jax.profiler`` trace (view
-with TensorBoard/XProf) and enables the per-video stage report; ``VFT_METRICS=1``
-enables the report alone.
+The stage accumulators (:class:`StageClock`) are always on. One switch —
+``VFT_METRICS=1``, ``--profile_dir DIR`` (which also wraps the run in a
+``jax.profiler`` trace; view with TensorBoard/XProf) or ``--telemetry_dir DIR``
+— turns on the printed stage report and the in-memory span records
+(:class:`SpanRecorder`).
+
+One span call marks every layer boundary: :func:`span`, reached through
+``Extractor._span``. One entry/exit of it adds to the stage clock, enters a
+``jax.profiler.TraceAnnotation``, emits the journal's start/end pair and, with
+recording on, keeps one record stamped in ``time.time_ns()`` — the clock a
+profiler trace is on too (``profile_start_time`` of its ``Task Environment``
+plane plus an event's ``start_ns``), so host spans and device operations lie
+on one axis without the program knowing that a trace is being taken
+(docs/observability.md).
 
 Stage semantics (async device dispatch makes naive timing lie):
 - ``decode``: host time blocked pulling frames from the decoder/transform
@@ -31,8 +42,10 @@ import time
 from typing import Callable, Dict, Iterable, Iterator, Optional
 
 
-def metrics_enabled(profile_dir=None) -> bool:
-    return bool(profile_dir) or os.environ.get("VFT_METRICS") == "1"
+def metrics_enabled(profile_dir=None, telemetry_dir=None) -> bool:
+    """The one switch of the printed stage report and the span records."""
+    return (bool(profile_dir) or bool(telemetry_dir)
+            or os.environ.get("VFT_METRICS") == "1")
 
 
 # decode-starvation heuristic (--pack_corpus): warn when the packer burned a
@@ -88,7 +101,7 @@ class StageClock:
     """Accumulates seconds per named stage.
 
     Thread-safe: increments arrive from the run-loop/daemon thread
-    (``timed_iter``, ``stage``), the staging ring's commit hooks, and the
+    (``timed_iter``, a span's ``add``), the staging ring's commit hooks, and the
     async writer's reap concurrently, so every mutation holds ``_lock`` — a
     lost ``+=`` would silently skew the report and the starvation heuristic.
     The accumulator dicts are declared under the ``clock`` lock in vftlint's
@@ -137,23 +150,23 @@ class StageClock:
         self._feed("stage_seconds_total", name, seconds)
 
     def add_bytes(self, name: str, n: int) -> None:
-        """Attribute payload bytes to a stage measured via :meth:`stage`
-        (timed_iter's ``bytes_of`` does this for iterator stages)."""
+        """Attribute payload bytes to a stage (timed_iter's ``bytes_of``
+        does this for iterator stages, :meth:`add` for a span's)."""
         with self._lock:
             self.bytes[name] += n
         self._feed("stage_bytes_total", name, n)
 
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self.seconds[name] += dt
-                self.counts[name] += 1
-            self._feed("stage_seconds_total", name, dt)
+    def add(self, name: str, seconds: float, nbytes: int = 0) -> None:
+        """One finished interval of a stage: its seconds, one count and its
+        payload bytes (what one :func:`span` exit adds)."""
+        with self._lock:
+            self.seconds[name] += seconds
+            self.counts[name] += 1
+            if nbytes:
+                self.bytes[name] += nbytes
+        self._feed("stage_seconds_total", name, seconds)
+        if nbytes:
+            self._feed("stage_bytes_total", name, nbytes)
 
     # registry mirroring from timed_iter is batched: the iterator runs per
     # FRAME on the decode hot path, and a per-item registry inc (label-key
@@ -165,11 +178,15 @@ class StageClock:
     _FEED_EVERY = 64
 
     def timed_iter(self, it: Iterable, name: str,
-                   bytes_of: Optional[Callable] = None) -> Iterator:
+                   bytes_of: Optional[Callable] = None,
+                   on_blocked: Optional[Callable] = None) -> Iterator:
         """Wrap an iterator, attributing time blocked in ``next()`` to ``name``.
 
         ``bytes_of(item)``, when given, accounts each item's payload size so
         the report can state the stage's throughput (e.g. decoded MB/s).
+        ``on_blocked(seconds)``, when given, is called right after a
+        ``next()`` that blocked :data:`BLOCKED_RECORD_SECONDS` or longer (the
+        ``pull`` span records: bounded by blocked time, not by item count).
         """
         it = iter(it)
         pending_s = 0.0
@@ -185,8 +202,12 @@ class StageClock:
                     with self._lock:
                         self.seconds[name] += dt
                     pending_s += dt
+                    if on_blocked is not None and dt >= BLOCKED_RECORD_SECONDS:
+                        on_blocked(dt)
                     return
                 dt = time.perf_counter() - t0
+                if on_blocked is not None and dt >= BLOCKED_RECORD_SECONDS:
+                    on_blocked(dt)
                 nbytes = bytes_of(item) if bytes_of is not None else 0
                 with self._lock:
                     self.seconds[name] += dt
@@ -230,6 +251,161 @@ class StageClock:
             occ = units["packed_clips"] / units["packed_slots"]
             parts.append(f"pack_occupancy {occ:.1%}")
         return " | ".join(parts)
+
+
+# a blocked interval shorter than this leaves no span record (it still adds
+# to the stage clock): an idle gap under a millisecond is not worth a name
+BLOCKED_RECORD_SECONDS = 1e-3
+
+# the identifiers the spans of one unit of work share; a span opened inside
+# another inherits those it was not given (a `put` inside page 7's `launch`
+# belongs to page 7)
+UNIT_IDS = ("video", "page", "request")
+
+
+class SpanRecorder:
+    """Bounded in-memory list of span records, on the ``time.time_ns()`` clock.
+
+    A record is ``{"name", "thread", "start", "end", "parent", "ids"}``:
+    ``parent`` is the index of the span that was open on the same thread when
+    this one started (None for a root). Records are appended at a span's start,
+    so a parent's index is below its children's; beyond ``limit`` a span is
+    counted in ``dropped`` and leaves no record (its children then hang from
+    the nearest recorded ancestor). Any thread may record; one lock guards the
+    list, each thread keeps its own stack of open spans.
+    """
+
+    # a 40 s window of the I3D configuration keeps a few hundred records, of
+    # the ResNet one (16 s blocked on decode, 1 ms or more at a time) at most
+    # some ten thousand
+    DEFAULT_LIMIT = 65536
+
+    def __init__(self, limit: int = DEFAULT_LIMIT):
+        self.limit = limit
+        self.records: list = []
+        self.dropped = 0
+        self._lock = threading.Lock()
+        self._open = threading.local()
+
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _append(self, name: str, start: int, end: Optional[int],
+                stack: list, ids: Dict) -> Optional[int]:
+        parent = next((i for i in reversed(stack) if i is not None), None)
+        with self._lock:
+            if len(self.records) >= self.limit:
+                self.dropped += 1
+                return None
+            if parent is not None:
+                inherited = self.records[parent]["ids"]
+                ids = {**{k: inherited[k] for k in UNIT_IDS if k in inherited},
+                       **ids}
+            self.records.append({
+                "name": name, "thread": threading.current_thread().name,
+                "start": start, "end": end, "parent": parent, "ids": ids})
+            return len(self.records) - 1
+
+    def begin(self, name: str, ids: Dict) -> Optional[int]:
+        stack = self._stack()
+        index = self._append(name, time.time_ns(), None, stack, ids)
+        stack.append(index)
+        return index
+
+    def end(self, index: Optional[int], ids: Optional[Dict] = None) -> None:
+        """Close the innermost open span of this thread; ``ids`` adds what
+        was only known at the end (bytes written, retries)."""
+        self._stack().pop()
+        if index is not None:
+            record = self.records[index]
+            if ids:
+                record["ids"].update(ids)
+            record["end"] = time.time_ns()
+
+    def add(self, name: str, seconds: float, **ids) -> None:
+        """A span that just ended and lasted ``seconds``, recorded after the
+        fact with its real start and end (a blocked ``next()``, a wait for a
+        pending transfer)."""
+        end = time.time_ns()
+        self._append(name, end - int(seconds * 1e9), end, self._stack(), ids)
+
+    def self_seconds(self) -> Dict[str, float]:
+        """Per span name, duration less what child spans on the same thread
+        cover: the time that belongs to the span's own code. (Spans of one
+        thread nest and never overlap, so a span's children are disjoint.)"""
+        with self._lock:
+            records = list(self.records)
+        covered = collections.defaultdict(int)
+        for r in records:
+            if r["parent"] is not None and r["end"] is not None:
+                covered[r["parent"]] += r["end"] - r["start"]
+        out: Dict[str, float] = collections.defaultdict(float)
+        for i, r in enumerate(records):
+            if r["end"] is not None:
+                out[r["name"]] += (r["end"] - r["start"] - covered[i]) / 1e9
+        return dict(out)
+
+    def export(self) -> Dict:
+        """What ``_pack_stats["spans"]`` holds at the end of a run."""
+        self_seconds = self.self_seconds()
+        with self._lock:
+            records = [dict(r) for r in self.records]
+            dropped = self.dropped
+        return {"clock": "time_ns", "records": records,
+                "self_seconds": {k: round(v, 6)
+                                 for k, v in self_seconds.items()},
+                "dropped": dropped}
+
+    def report(self) -> str:
+        """One line for the stage report: self time per span name."""
+        parts = [f"{name} {s:.2f}s" for name, s in
+                 sorted(self.self_seconds().items(), key=lambda kv: -kv[1])]
+        tail = f" | dropped {self.dropped}" if self.dropped else ""
+        return "span self time: " + " | ".join(parts) + tail
+
+
+class SpanHandle:
+    """What :func:`span` yields: ``ids`` takes what is only known inside the
+    span (it lands in the record and the journal's end event); ``seconds`` is
+    the span's duration once it has ended."""
+
+    __slots__ = ("ids", "seconds")
+
+    def __init__(self):
+        self.ids: Dict = {}
+        self.seconds = 0.0
+
+
+@contextlib.contextmanager
+def span(name: str, clock: Optional[StageClock] = None,
+         recorder: Optional[SpanRecorder] = None, journal=None,
+         stage: Optional[str] = None, nbytes: int = 0, **ids):
+    """THE span call (module docstring). ``stage`` names the clock stage the
+    seconds (and ``nbytes``) are added to; ``ids`` go to the annotation, the
+    journal pair and the record. None-valued ids are left out. Every sink is
+    optional: a decode pool, packer or writer built without an extractor
+    (the tests') uses the bare call, which still times and annotates."""
+    from jax.profiler import TraceAnnotation
+
+    ids = {k: v for k, v in ids.items() if v is not None}
+    handle = SpanHandle()
+    index = recorder.begin(name, dict(ids)) if recorder is not None else None
+    sid = journal.begin(name, **ids) if journal is not None else None
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name, **ids):
+            yield handle
+    finally:
+        handle.seconds = time.perf_counter() - t0
+        if stage is not None and clock is not None:
+            clock.add(stage, handle.seconds, nbytes)
+        if sid is not None:
+            journal.end(name, sid, **{**ids, **handle.ids})
+        if recorder is not None:
+            recorder.end(index, handle.ids)
 
 
 @contextlib.contextmanager
