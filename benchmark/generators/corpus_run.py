@@ -1,20 +1,22 @@
 """Traffic generator ``corpus_run``: a batch job over a corpus of trimmed clips.
 
 Set-up writes ``clips`` distinct clips from the seed: each a contiguous range
-(seeded start, seeded length in ``min_frames``..``max_frames``, clamped to its
-source) of one of ``sources`` long enough to hold it, decoded with OpenCV and written again with
-``fourcc`` at the source's own size and frame rate. Every seed gives the same
-multiset of lengths in another order and other start frames, so the work of a
-window does not change with the seed.
+(seeded start, a length of an even spread over ``min_frames``..``max_frames``)
+of one of ``sources`` long enough to hold it, decoded with OpenCV and written
+again with ``fourcc`` at the source's own size and frame rate. Every seed
+gives the same multiset of (length, source) in another order and other start
+frames, with the longest clip first (``clip_plan`` says why), so the work of
+a window does not change with the seed.
 
 The window is ONE call of ``get_extractor(cfg).run(paths)`` with
 ``on_extraction=save_numpy``, the call ``run.main`` makes, over
 ``window_videos`` paths: the clips cycled, each entry a hard link under a stem
 of its own. ``window_videos`` is fixed work that the configuration's file
-states: what a window of ``run_seconds`` holds (a multiple of ``clips``, so
-that every seed has the same frames). Another ``--seconds`` scales it, never
-under ``min_window_videos``.
-The extractor is the one the warm-up pass compiled.
+states for a run of ``run_seconds`` (a multiple of ``clips``, so that every
+seed has the same frames); how long it lasts is the program's business.
+Another ``--seconds`` scales it, never under ``min_window_videos``.
+The extractor is the one the warm-up pass compiled. A traced run's slice
+starts when the window's first output file is there (``first_written``).
 """
 
 from __future__ import annotations
@@ -22,7 +24,7 @@ from __future__ import annotations
 import glob
 import os
 import time
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import cv2
 import numpy as np
@@ -30,21 +32,24 @@ import numpy as np
 
 def clip_plan(traffic: dict, seed: int, source_frames: List[int]) -> List[dict]:
     """Which range of which source each clip is. Lengths are an even spread
-    over min..max, the same multiset for every seed, permuted by the seed; a
-    clip takes a seeded source among those long enough to hold it, so no
-    length is ever cut and every seed has the same frames in all; the start
-    frame is seeded within what the source allows."""
+    over min..max; a length takes its source by rule (by its rank among those
+    long enough to hold it), so no length is ever cut and every seed has the
+    same multiset of (frames, source). The seed gives the order and the start
+    frames, but not the first clip: the consumer takes the window's first
+    entry alone for its first page, so that clip's decode is the fill, and a
+    seeded one made the window swing by 0.2 s with the seed (PERF.md section
+    6, PR 32). The longest goes first for every seed; the rest are permuted."""
     rng = np.random.default_rng([int(seed), 0xC11F5])
     k = int(traffic["clips"])
     lengths = np.linspace(traffic["min_frames"], traffic["max_frames"], k).round().astype(int)
     if lengths.max() > max(source_frames):
         raise ValueError(f"max_frames {lengths.max()} exceeds every source {source_frames}")
-    lengths = lengths[rng.permutation(k)]
+    order = [k - 1] + [int(r) for r in rng.permutation(k - 1)]
     plan = []
-    for i in range(k):
-        n = int(lengths[i])
+    for i, rank in enumerate(order):
+        n = int(lengths[rank])
         fits = [j for j, total in enumerate(source_frames) if total >= n]
-        src = fits[int(rng.integers(len(fits)))]
+        src = fits[rank % len(fits)]
         start = int(rng.integers(0, source_frames[src] - n + 1))
         plan.append({"clip": i, "source": src, "start": start, "frames": n})
     return plan
@@ -84,15 +89,26 @@ def write_corpus(traffic: dict, seed: int, root: str, out_dir: str) -> List[str]
     return paths
 
 
+WINDOW_PREFIX = "w"  # no clip's own name starts with it: the warm-up's outputs do not match
+
+
 def window_paths(clips: List[str], n: int, out_dir: str) -> List[str]:
     os.makedirs(out_dir, exist_ok=True)
     paths = []
     for i in range(n):
         src = clips[i % len(clips)]
-        dst = os.path.join(out_dir, f"w{i:05d}_{os.path.basename(src)}")
+        dst = os.path.join(out_dir, f"{WINDOW_PREFIX}{i:05d}_{os.path.basename(src)}")
         os.link(src, dst)
         paths.append(dst)
     return paths
+
+
+def window_count(conf: dict, traffic: dict, seconds: float, run_seconds: float) -> int:
+    """Entries of one window: the configuration's ``window_videos`` at
+    ``run_seconds``, in proportion at another ``--seconds``, never under the
+    mix's ``min_window_videos``."""
+    return max(int(traffic["min_window_videos"]),
+               int(round(int(conf["window_videos"]) * seconds / run_seconds)))
 
 
 def build_extractor(ctx):
@@ -121,6 +137,16 @@ def output_files(output_dir: str, path: str) -> Dict[str, str]:
             for f in glob.glob(os.path.join(output_dir, stem + "_*.npy"))}
 
 
+def first_written(output_dir: str) -> Optional[float]:
+    """When the window finished its first video: the modification time (Unix
+    seconds) of the oldest ``.npy`` of a window entry in ``output_dir``, None
+    while there is none. The writer publishes a file by ``os.replace``, so one
+    that is listed is whole."""
+    times = [e.stat().st_mtime for e in os.scandir(output_dir)
+             if e.name.startswith(WINDOW_PREFIX) and e.name.endswith(".npy")]
+    return min(times) if times else None
+
+
 def run_window(ctx) -> dict:
     """Set up, warm, measure. Returns the facts every later step reads."""
     traffic, conf = ctx.traffic, ctx.conf
@@ -133,12 +159,11 @@ def run_window(ctx) -> dict:
     warm_ok = ex.run(warm)
     if warm_ok != len(warm):
         raise RuntimeError(f"warm-up: {warm_ok}/{len(warm)} clips succeeded")
-    n = max(int(traffic["min_window_videos"]),
-            int(round(int(conf["window_videos"]) * ctx.seconds / ctx.run_seconds)))
+    n = window_count(conf, traffic, ctx.seconds, ctx.run_seconds)
     paths = window_paths(clips, n, os.path.join(ctx.scratch, "window"))
     if ctx.trace:
         os.environ["VFT_METRICS"] = "1"  # fills StageClock; traced run only
-    ctx.before_window()
+    ctx.before_window(lambda: first_written(ex.output_dir))
     t0 = time.perf_counter()
     ok = ex.run(paths)
     t1 = time.perf_counter()

@@ -7,10 +7,8 @@ The program stamps its spans in ``time.time_ns()``
 (``_pack_stats["spans"]["records"]``); the profiler gives every event's start
 relative to the session's start and the session's start in Unix nanoseconds
 (stat ``profile_start_time`` of the plane ``Task Environment``), so
-``profile_start_time + start_ns`` is on the same clock. ``run.py`` hands a
-reader the reduction and not the trace's path, so the file is found here: the
-newest ``*.xplane.pb`` under ``output/benchmark/*/trace`` (``run.py`` clears
-the cell's scratch at start and one process runs one cell).
+``profile_start_time + start_ns`` is on the same clock. The file is the one
+the reduction was made from: its ``path`` (``trace_reduce.reduce_trace_dir``).
 
 The scope of a device operation (``jax.named_scope``) is the ``tf_op`` stat
 of its event's METADATA, which ``jax.profiler.ProfileData`` does not show (it
@@ -25,16 +23,14 @@ returns None, and the reader leaves its metric out.
 from __future__ import annotations
 
 import bisect
-import glob
 import os
 import struct
 import sys
 from typing import Dict, Iterator, List, Optional, Tuple
 
-from trace_reduce import DEVICE_PREFIX, OPS_LINE, self_times, union_length
+from trace_reduce import (DEVICE_PREFIX, MODULES_LINE, OPS_LINE, page_program, self_times,
+                          union_length)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-MODULES_LINE = "XLA Modules"
 ENVIRONMENT_PLANE = "Task Environment"
 START_STAT = "profile_start_time"
 SCOPE_STAT = "tf_op"
@@ -220,20 +216,14 @@ def read_xspace(path: str) -> dict:
     return {"profile_start_ns": start, "devices": devices}
 
 
-# --- finding this run's trace ------------------------------------------------
+# --- this run's trace ----------------------------------------------------------
 
 _CACHE: Dict[Tuple[str, float], dict] = {}
 
 
-def newest_trace(root: str = ROOT) -> Optional[str]:
-    found = glob.glob(os.path.join(root, "output", "benchmark", "*", "trace",
-                                   "**", "*.xplane.pb"), recursive=True)
-    return max(found, key=os.path.getmtime) if found else None
-
-
-def load(path: Optional[str] = None) -> Optional[dict]:
-    """This run's trace, read once per process."""
-    path = path or newest_trace()
+def load(path: Optional[str]) -> Optional[dict]:
+    """The trace a reduction was made from (its ``path``), read once per
+    process; None where the reduction names no file."""
     if path is None:
         return None
     key = (path, os.path.getmtime(path))
@@ -320,8 +310,9 @@ def clock_check(plane: dict, records: List[dict], start_ns: int) -> Optional[dic
     must start after the ``launch`` span of a page starts and end before that
     page's ``device`` span ends, consecutive executions in consecutive pages.
     Returns the worst residual (how far outside its page's interval an
-    execution lies, 0 when inside), and the one offset that brings every
-    execution inside where there is one."""
+    execution lies, 0 when inside), the one offset that brings every
+    execution inside where there is one, and how far out each alignment
+    tried was (the first traced execution taken for each page in turn)."""
     launches = {r["ids"]["page"]: r["start"] for r in records
                 if r["name"] == "launch" and "page" in r["ids"]}
     fetched = {r["ids"]["page"]: r["end"] for r in records
@@ -331,26 +322,32 @@ def clock_check(plane: dict, records: List[dict], start_ns: int) -> Optional[dic
     modules = plane["lines"].get(MODULES_LINE, ())
     if not pages or not modules:
         return None
-    # the page program is the module that holds most of the device's time
-    seconds: Dict[int, int] = {}
-    for m, _s, d in modules:
-        seconds[m] = seconds.get(m, 0) + d
-    program = max(seconds, key=seconds.get)
+    program = page_program(modules)
     runs = sorted((s, s + d) for m, s, d in modules if m == program)
     # which page is the first traced execution's? The alignment under which
     # the executions lie best inside their pages' intervals (a device-bound
     # run launches a page one execution ahead and fetches it as it ends; a
     # host-bound one launches into an idle device and fetches a page late:
-    # one of the two ends is tight either way).
+    # one of the two ends is tight either way). An idle device begins a page
+    # as it is launched, so an execution that the one before did not hold up
+    # is also out by what it began later than that: a late fetch alone would
+    # let it lie inside the page before its own as well.
     def outside(first: int) -> int:
-        return max(max(launches[p] - (start_ns + s), (start_ns + e) - fetched[p], 0)
-                   for (s, e), p in zip(runs, pages[first:]))
+        out, before = 0, None
+        for (s, e), p in zip(runs, pages[first:]):
+            out = max(out, launches[p] - (start_ns + s), (start_ns + e) - fetched[p])
+            if before is not None and s - before > ALIGN_SLACK_NS:
+                out = max(out, (start_ns + s) - launches[p] - ALIGN_SLACK_NS)
+            before = e
+        return out
 
-    # (where a late fetch lets two alignments fit, the later page is the
-    # tight one; a plane a few milliseconds off the clock must not read as a
-    # shift by a page, which is hundreds of milliseconds)
+    # the least out wins, the later page where two are level. (A plane a few
+    # milliseconds off the clock still reads as that and not as a shift by a
+    # page: a device-bound run launches page p+1 a few milliseconds after
+    # execution p began, 1.9-7.0 ms in PR 32's traced runs, so no fixed slack
+    # tells the two apart, and the page's own interval has to.)
     worst = [outside(k) for k in range(max(len(pages) - len(runs), 0) + 1)]
-    first = max(k for k, w in enumerate(worst) if w <= min(worst) + ALIGN_SLACK_NS)
+    first = max(k for k, w in enumerate(worst) if w == min(worst))
     matched = list(zip(runs, pages[first:]))
     # offset window: launch - start <= offset <= fetched - end
     low = max(launches[p] - (start_ns + s) for (s, _e), p in matched)
@@ -360,20 +357,18 @@ def clock_check(plane: dict, records: List[dict], start_ns: int) -> Optional[dic
         offset = (low + high) // 2
     return {"executions": len(runs), "matched": len(matched),
             "worst_residual_ns": max(low, -high, 0),
-            "offset_ns": offset, "offset_window_ns": [low, high]}
+            "offset_ns": offset, "offset_window_ns": [low, high],
+            "first_page": pages[first], "out_by_alignment_ns": worst}
 
 
-def idle_shares(trace: dict, stats: dict, facts: dict) -> Optional[Dict[str, float]]:
-    """``{"decode", "transfer", "other"}``: the share (%) of the traced span
-    in which no device operation ran and the consumer thread's innermost open
-    span was ``pull`` / ``stage`` or ``put`` / anything else. Gaps and span
-    are ``trace_reduce.union_length``'s over the same first-start-to-last-end
-    span ``device_idle_pct`` uses, averaged over the cell's device planes, so
-    the three add up to it."""
+def on_one_clock(trace: dict, stats: dict) -> Optional[Tuple[List[dict], list, int]]:
+    """The device planes the reduction read, the consumer thread's timeline,
+    and the Unix time (ns) of the trace's zero by the clock rule above; None
+    where the records, the trace or its ``profile_start_time`` are missing."""
     records = records_of(stats)
-    if records is None or not trace.get("span_s"):
+    if records is None:
         return None
-    space = load()
+    space = load(trace.get("path"))
     if space is None or space["profile_start_ns"] is None:
         return None
     planes = device_planes(space, trace)
@@ -385,6 +380,22 @@ def idle_shares(trace: dict, stats: dict, facts: dict) -> Optional[Dict[str, flo
     if check is not None:
         print(f"[spans] clock: {check}", file=sys.stderr, flush=True)
         start_ns += check["offset_ns"]
+    return planes, pieces, start_ns
+
+
+def idle_shares(trace: dict, stats: dict, facts: dict) -> Optional[Dict[str, float]]:
+    """``{"decode", "transfer", "other"}``: the share (%) of the traced span
+    in which no device operation ran and the consumer thread's innermost open
+    span was ``pull`` / ``stage`` or ``put`` / anything else. Gaps and span
+    are ``trace_reduce.union_length``'s over the same first-start-to-last-end
+    span ``device_idle_pct`` uses, averaged over the cell's device planes, so
+    the three add up to it."""
+    if not trace.get("span_s"):
+        return None
+    aligned = on_one_clock(trace, stats)
+    if aligned is None:
+        return None
+    planes, pieces, start_ns = aligned
     decode = transfer = 0
     for plane in planes:
         _busy, gaps = union_length([(s, s + d) for _m, s, d in plane["lines"][OPS_LINE]])
@@ -399,6 +410,31 @@ def idle_shares(trace: dict, stats: dict, facts: dict) -> Optional[Dict[str, flo
     return shares
 
 
+GAP_NAMES = ("pull", "stage", "put", "launch", "device", "finalize", "write_reap")
+
+
+def name_idle_gaps(trace: dict, stats: dict) -> List[list]:
+    """``trace["idle_gaps"]`` with each gap named by the consumer thread's
+    innermost span at the moment the gap began: one of ``GAP_NAMES``, else
+    ``other`` (its own Python, or no span). Where two gaps share a name, each
+    keeps its rank as a suffix (``device.0``, ``device.3``). The durations are
+    the reduction's own; without records the names by rank stand."""
+    gaps = trace["idle_gaps"]
+    aligned = on_one_clock(trace, stats)
+    if aligned is None or len(trace.get("idle_gap_starts", ())) != len(gaps):
+        return gaps
+    _planes, pieces, start_ns = aligned
+    starts = [p[0] for p in pieces]
+    names = []
+    for gap_start in trace["idle_gap_starts"]:
+        at = start_ns + gap_start
+        i = bisect.bisect_right(starts, at) - 1
+        inside = i >= 0 and pieces[i][0] <= at < pieces[i][1]
+        names.append(pieces[i][2] if inside and pieces[i][2] in GAP_NAMES else "other")
+    return [[name if names.count(name) == 1 else f"{name}.{rank}", seconds]
+            for rank, (name, (_old, seconds)) in enumerate(zip(names, gaps))]
+
+
 # --- device operations by scope ------------------------------------------------
 
 
@@ -406,7 +442,7 @@ def scope_seconds(trace: dict, scopes: Tuple[str, ...]) -> Optional[float]:
     """Self time (s, averaged over the device planes) of the traced
     operations whose scope holds one of ``scopes``; None where no operation
     carries a scope at all (a program without them)."""
-    space = load()
+    space = load(trace.get("path"))
     if space is None:
         return None
     planes = device_planes(space, trace)

@@ -1,8 +1,10 @@
 """The PWC cost-volume kernels' share of their roofline, from the device trace.
 
-Kernel time: the summed self time of the traced slice's Mosaic custom calls
-(``tpu_custom_call`` in the event's HLO text; the cost volumes are the only
-Pallas kernels of this step). Work: each call's result shape ``[b, h, w, 81]``
+Kernel time: the summed self time of the traced slice's operations whose HLO
+instruction carries one of the kernels' names (``KERNELS``: the ``name=`` the
+program gives its ``pallas_call``s, which the compiler keeps as the
+instruction's name, ``%pwc_corr81_tiled.23``); a Mosaic call of any other name
+is another kernel's and is not counted. Work: each call's result shape ``[b, h, w, 81]``
 and its first operand's channel count name the pyramid level and the pairs it
 served; operations (``2 * 81 * h * w * c`` per pair) and bytes (``f1`` and
 ``f2`` read once, the volume written once, float32) come from
@@ -18,6 +20,8 @@ import re
 from flops import i3d_pwc
 from peaks import peaks_for
 
+KERNELS = ("pwc_corr81_single", "pwc_corr81_tiled", "pwc_warp_corr81_fused")
+INSTRUCTION = re.compile(r"%?([A-Za-z_][\w-]*?)(?:\.\d+)? = ")
 SHAPE = re.compile(r"(?:f32|bf16)\[(\d+),(\d+),(\d+),(\d+)\]")
 
 
@@ -26,7 +30,8 @@ def read(trace, stats, facts):
     level_of = {c: lvl for lvl, c in i3d_pwc.LEVEL_FEAT.items()}
     kernel_s = least_s = 0.0
     for name, seconds in trace["op_seconds"].items():
-        if "tpu_custom_call" not in name:
+        instruction = INSTRUCTION.match(name)
+        if instruction is None or instruction.group(1) not in KERNELS:
             continue
         shapes = [tuple(int(g) for g in m.groups()) for m in SHAPE.finditer(name)]
         volumes = [s for s in shapes if s[3] == 81]

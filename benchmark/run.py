@@ -88,7 +88,7 @@ def make_context(cell: dict, seed: int, seconds: float, trace: bool, variant: st
         cell=cell, conf=conf, traffic=traffic, seed=seed, seconds=seconds,
         trace=trace, variant=variant, chips=int(cell["chips"]), scratch=scratch,
         root=ROOT, t_start=T_START, compiles=0, in_window=False,
-        before_window=lambda: None, after_window=lambda: None, tracer=None)
+        before_window=lambda progressed: None, after_window=lambda: None, tracer=None)
     return ctx
 
 
@@ -133,13 +133,14 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
     if trace:
         from tracing import SliceTracer
 
-        ctx.tracer = SliceTracer(os.path.join(ctx.scratch, "trace"),
-                                 ctx.conf.get("trace_slice", {}), seconds)
+        ctx.tracer = SliceTracer(os.path.join(ctx.scratch, "trace"), ctx.conf["trace_slice"])
 
-    def before_window():
+    def before_window(progressed):
+        # `progressed()`: None until the window has finished its first unit
+        # of work, then the Unix time at which it did (the generator's word)
         ctx.in_window = True
         if ctx.tracer:
-            ctx.tracer.arm()
+            ctx.tracer.arm(progressed)
 
     def after_window():
         ctx.in_window = False
@@ -153,6 +154,14 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
     window = gen.run_window(ctx)
     log(f"window closed: {window['attempted']} attempted, {window['failed']} failed, "
         f"{window['wall_s']:.2f} s, {ctx.compiles} compile(s) inside")
+    if trace:
+        t = ctx.tracer
+        if not t.started:
+            raise SystemExit("no slice was traced: the window closed before its first "
+                             "video was written")
+        log(f"slice: first video written {t.progress_at - t.armed_at:.3f} s into the window, "
+            f"seen {t.seen_lag_s:.3f} s and traced from {t.start_lag_s:.3f} s after that, "
+            f"for {t.slice_seconds:.3f} s")
     # The runtime keeps a running program's temporaries in a reservation of
     # its own, which peak_bytes_in_use (the buffers) leaves out (PERF.md §4
     # has the probe). The two peaks need not fall at the same moment, so the
@@ -188,6 +197,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
             m["name"]: {"value": window["end_to_end"][m["name"]], "unit": m["unit"]}
             for m in wanted if m["name"] in window["end_to_end"]}
     else:
+        from layer_metrics._spans import name_idle_gaps
         from trace_reduce import reduce_trace_dir
 
         reduction = reduce_trace_dir(ctx.tracer.directory, ctx.chips)
@@ -206,12 +216,16 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
         # operation's start to the last one's end, and the union of the
         # operations' intervals inside it. The host's clock around
         # start_trace/stop_trace (logged for comparison) is another clock
-        log(f"traced: busy {reduction['busy_s']:.4f} s of span {reduction['span_s']:.4f} s; "
+        log(f"traced: busy {reduction['busy_s']:.4f} s of span {reduction['span_s']:.4f} s, "
+            f"{reduction['slice_pages']} whole page(s); "
             f"the host counted {ctx.tracer.slice_seconds:.4f} s between start and stop")
         device["busy_s"] = reduction["busy_s"]
         device["window_s"] = reduction["span_s"]
+        # whole executions of the page program in the slice: what the
+        # per-layer numbers of this line rest on
+        device["slice_pages"] = reduction["slice_pages"]
         result["breakdown"] = {"device_ops": reduction["top_ops"][:10],
-                               "idle_gaps": reduction["idle_gaps"][:10]}
+                               "idle_gaps": name_idle_gaps(reduction, window["stats"])[:10]}
     result["device"] = device
     result["window_s"] = window["wall_s"]
     result["checks"] = numbers

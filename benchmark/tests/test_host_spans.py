@@ -13,7 +13,8 @@ import pytest
 import trace_reduce as tr
 from layer_metrics import (_spans, flow_resize_pct, idle_decode_pct,
                            idle_host_other_pct, idle_transfer_pct,
-                           writer_backlog_max, writer_s_per_video)
+                           pwc_corr_roofline, writer_backlog_max,
+                           writer_s_per_video)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 T0 = 1_790_000_000_000_000_000  # the session's start, Unix ns
@@ -144,11 +145,28 @@ def test_missing_inputs_give_none_and_never_a_zero(use_space, monkeypatch, broke
     elif broken == "no_run_span":
         stats = {"spans": {"clock": "time_ns", "records": RECORDS[1:]}}
     trace = reduction_of(space_of(OPS))
-    if broken == "no_trace":
-        monkeypatch.setattr(_spans, "newest_trace", lambda root=None: None)
-    else:
+    if broken != "no_trace":  # else the reduction names no file: nothing is looked for
         use_space(space)
     assert shares(trace, stats) == (None, None, None)
+    assert _spans.name_idle_gaps(trace, stats) == trace["idle_gaps"]  # ranks stand
+
+
+def test_idle_gaps_are_named_by_the_span_they_began_in(use_space):
+    space = space_of(OPS)
+    use_space(space)
+    trace = reduction_of(space)
+    # by length: 100-300 began inside the pull; 500-560 and 400-420 began in
+    # extract's own Python (the launch starts at 405), which is `other`
+    assert _spans.name_idle_gaps(trace, STATS) == [
+        ["pull", pytest.approx(0.2)], ["other.1", pytest.approx(0.06)],
+        ["other.2", pytest.approx(0.02)]]
+    assert [g[1] for g in _spans.name_idle_gaps(trace, STATS)] == \
+        [g[1] for g in trace["idle_gaps"]]  # labels only
+    # a gap that begins under a put inside a launch, and one under no span
+    space = space_of([(0, 412), (414, 690), (720, 800)])
+    use_space(space)
+    trace = reduction_of(space)
+    assert [g[0] for g in _spans.name_idle_gaps(trace, STATS)] == ["other", "put"]
 
 
 def test_clock_check_matches_executions_to_consecutive_pages():
@@ -191,6 +209,28 @@ def test_clock_check_host_bound_pages_are_fetched_late():
     assert check["offset_window_ns"][0] == -2 * MS  # tight at the start
     early = _spans.clock_check(plane, records, T0 - 3 * MS)  # plane 3 ms early
     assert early["worst_residual_ns"] == 1 * MS and early["offset_ns"] == 1 * MS
+
+
+def test_clock_check_device_bound_page_launched_just_after_the_one_before_began():
+    """Execution p begins as p-1 ends; the host sees p-1's result 2 ms later
+    and launches p+1 another 2.9 ms on, so the alignment one page late is out
+    by 4.9 ms only (1.9-7.0 ms in PR 32's traced runs): the one that fits
+    wins, and a plane off the clock still reads as that."""
+    records = [rec("run", 0, 20000)]
+    for page in range(5):
+        t = 3900 * page
+        records.append(rec("launch", t - 3900 + 4.9, t - 3900 + 6, parent=0, page=page))
+        records.append(rec("device", t + 10, t + 3902, parent=0, page=page))
+    space = space_of([(0, 1)], modules=[(3900 * k, 3900 * (k + 1)) for k in (1, 2, 3)])
+    plane = space["devices"]["/device:TPU:0"]
+    check = _spans.clock_check(plane, records, T0)
+    assert check["executions"] == check["matched"] == 3
+    assert check["worst_residual_ns"] == 0 and check["offset_ns"] == 0
+    assert check["offset_window_ns"] == [int(-3895.1 * MS), 2 * MS]  # pages 1, 2, 3
+    assert check["first_page"] == 1
+    assert check["out_by_alignment_ns"] == [3898 * MS, 0, int(4.9 * MS)]
+    late = _spans.clock_check(plane, records, T0 + 3 * MS)  # plane 3 ms late
+    assert late["worst_residual_ns"] == 1 * MS and late["offset_ns"] == -1 * MS
 
 
 def test_writer_metrics_read_spans_and_counters():
@@ -242,5 +282,46 @@ def test_flow_resize_pct_is_silent_on_a_trace_without_scopes(use_space):
     path = os.path.join(HERE, "data", "small.xplane.pb")
     use_space(_spans.read_xspace(path))
     assert flow_resize_pct.read(tr.reduce_trace_dir(path), {}, {}) is None
-    use_space(space_of(OPS))  # scopes, none of them a resize
+    use_space(space_of(OPS))  # scopes, none of them the flow net's
     assert flow_resize_pct.read(reduction_of(space_of(OPS)), {}, {}) is None
+
+
+def test_flow_resize_pct_reads_zero_where_the_flow_net_ran_without_resizes(use_space):
+    # after the resizes are fused away or made free: pwc/ scopes, no resize scope
+    space = space_of(OPS)
+    space["devices"]["/device:TPU:0"]["metadata"][1] = (
+        "%fusion.1", "jit(paged)/i3d/page/i3d/flow/pwc/decoder2/conv")
+    use_space(space)
+    assert flow_resize_pct.read(reduction_of(space), {}, {}) == 0.0
+
+
+def _facts():
+    import json
+
+    with open(os.path.join(os.path.dirname(HERE), "peaks.json")) as f:
+        return {"device_kind": "TPU v5 lite", "peaks": json.load(f)}
+
+
+def test_pwc_corr_roofline_on_the_recorded_trace():
+    trace = tr.reduce_trace_dir(os.path.join(HERE, "data", "pwc_page.xplane.pb"))
+    assert pwc_corr_roofline.read(trace, {}, _facts()) == pytest.approx(3.93, abs=0.01)
+
+
+def test_pwc_corr_roofline_counts_the_kernels_by_name_and_no_other_mosaic_call():
+    call = ('%{name} = f32[16,64,96,81]{{3,2,1,0:T(8,128)}} custom-call('
+            'f32[16,64,96,32]{{3,2,1,0}} %bitcast.1, f32[16,72,104,32]{{3,2,1,0}} %pad.1), '
+            'custom_call_target="tpu_custom_call"')
+
+    def read(*names):
+        ops = {call.format(name=n): 0.01 for n in names}
+        return pwc_corr_roofline.read(
+            {"op_seconds": ops, "op_counts": {k: 1 for k in ops}}, {}, _facts())
+
+    one = read("pwc_corr81_tiled.23")
+    assert one is not None and 0 < one < 100
+    assert read("pwc_corr81_tiled") == one                   # the first instance has no suffix
+    assert read("pwc_corr81_tiled.23", "resize_matmul.4", "custom-call.7") == one
+    assert read("pwc_warp_corr81_fused.2") == one            # same level, same work
+    assert read("resize_matmul.4") is None
+    assert read("corr81_pallas_tiled.23") is None            # the names before PR 31
+    assert read("my_pwc_corr81_tiled.1") is None

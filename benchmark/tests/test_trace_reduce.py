@@ -34,6 +34,29 @@ def test_reduce_planes_by_hand():
     assert r["idle_gaps"][0][1] == pytest.approx(200e-9)
     assert r["top_ops"][0][0] == "%a f32[2]"
     assert r["op_counts"]["%a = f32[2]{0} add(x)"] == 1
+    assert r["idle_gap_starts"] == [400]
+    assert r["slice_pages"] == 0  # one execution: it may be cut at either end
+
+
+def test_whole_executions_leave_out_the_pieces_at_the_trace_ends():
+    page, other = "jit_paged(1)", "jit_other(2)"
+    # the tail of a page, two whole pages, the head of a fourth cut by the stop
+    cut = [(page, 0, 200), (page, 201, 3900), (page, 4102, 3900), (page, 8003, 3000)]
+    assert tr.whole_executions(cut) == 2
+    assert tr.whole_executions(cut[:3]) == 1
+    assert tr.whole_executions(cut[:2]) == 0
+    assert tr.whole_executions([]) == 0
+    # a small program before the first page: that page started inside the trace
+    led = [(other, 0, 5)] + [(page, s + 10, d) for _n, s, d in cut[1:]]
+    assert tr.whole_executions(led) == 2
+    assert tr.whole_executions(list(reversed(led))) == 2  # order on the line is free
+    # another program between pages is not a page
+    assert tr.whole_executions(cut[:2] + [(other, 4102, 50), (page, 4200, 100)]) == 1
+    planes = {"/device:TPU:0": {"XLA Ops": [("%a = f32[2]{0} add(x)", 0, 11003)],
+                                "XLA Modules": cut},
+              "/device:TPU:1": {"XLA Ops": [("%a = f32[2]{0} add(x)", 0, 11003)],
+                                "XLA Modules": cut[:3]}}
+    assert tr.reduce_planes(planes, chips=2)["slice_pages"] == 1  # the least over the chips
 
 
 def test_no_device_plane_is_an_error():
@@ -46,6 +69,7 @@ def test_recorded_trace():
     if not os.path.exists(path):
         pytest.skip("no recorded trace")
     r = tr.reduce_trace_dir(path)
+    assert r["path"] == path
     assert r["planes"] == ["/device:TPU:0"]
     assert 0 < r["busy_s"] <= r["span_s"]
     assert abs(sum(r["op_seconds"].values()) - r["busy_s"]) < 1e-6 + 0.02 * r["busy_s"]

@@ -16,6 +16,13 @@ epilogue) -- ``correct`` comes out false. The control (the program's own
 ``--matmul_precision high``) comes out not correct on the chip only: the CPU
 has one float32 product, so there the test is skipped
 (``chiprun -- python3 -m pytest benchmark/tests -k control``).
+
+The traced run goes the same way with the profiler faked (a CPU has no TPU
+plane to trace): the slice is placed by the window's own first written video,
+``run.py`` reduces the trace under the tracer's own directory (the recorded
+``data/pwc_page.xplane.pb`` put there by the fake's stop) and calls every
+reader; a window that closes before its first video is seen ends the run with
+one line and no traceback.
 """
 
 import json
@@ -47,7 +54,15 @@ SIZES = {
 CONFIGS = sorted(SIZES)
 
 
-def _run(tmp_path, monkeypatch, config, variant=""):
+class _Device:
+    """What ``run_cell`` asks of a device, for the traced run's peaks table."""
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def memory_stats(self):
+        return {}
+
+
+def _run(tmp_path, monkeypatch, config, variant="", trace=False):
     bench = bench_run.load_json(ROOT, "BENCHMARK.json")
     cell = {"name": config + ".corpus_clips", "config": config,
             "traffic": "corpus_clips", "chips": 1}
@@ -65,8 +80,9 @@ def _run(tmp_path, monkeypatch, config, variant=""):
         for name, value in size["reference"].items():
             monkeypatch.setattr(ref, name, value)
     return bench_run.run_cell(
-        bench, cell, seed=2147483659, seconds=bench["run_seconds"], trace=False,
-        variant=variant, scratch=str(tmp_path / "cell"), conf=conf, traffic=traffic)
+        bench, cell, seed=2147483659, seconds=bench["run_seconds"], trace=trace,
+        variant=variant, scratch=str(tmp_path / "cell"), conf=conf, traffic=traffic,
+        devices=[_Device()] if trace else None)
 
 
 def _checks(result):
@@ -119,3 +135,58 @@ def test_a_video_that_never_comes_is_not_correct(tmp_path, monkeypatch):
                                               if k != "resnet50"})
     r = _run(tmp_path, monkeypatch, "resnet50_fp32")
     assert r["correct"] is False
+
+
+def _fake_profiler(monkeypatch):
+    import os
+    import shutil
+
+    import tracing
+
+    calls = []
+
+    def start(directory):
+        calls.append(directory)
+
+    def stop():
+        run_dir = os.path.join(calls[-1], "plugins", "profile", "recorded")
+        os.makedirs(run_dir)
+        shutil.copy(os.path.join(BENCH, "tests", "data", "pwc_page.xplane.pb"),
+                    os.path.join(run_dir, "host.xplane.pb"))
+
+    monkeypatch.setattr(tracing, "start_device_trace", start)
+    monkeypatch.setattr(tracing, "stop_device_trace", stop)
+    return calls
+
+
+def test_traced_run_goes_through_the_tracer_and_every_reader(tmp_path, monkeypatch, capfd):
+    calls = _fake_profiler(monkeypatch)
+    r = _run(tmp_path, monkeypatch, "i3d_pwc_fp32", trace=True)
+    assert calls == [str(tmp_path / "cell" / "trace")]
+    assert r["correct"] is True and r["failed"] == 0
+    bench = bench_run.load_json(ROOT, "BENCHMARK.json")
+    wanted = {m["name"] for m in bench["per_layer"]}
+    # the recorded trace is of another day, so the clock rule fits an offset
+    # of hours and the idle shares mean nothing here; but every reader is
+    # called on the trace under the tracer's directory and finds its input
+    assert set(r["metrics"]) == wanted
+    assert r["metrics"]["flow_resize_pct"]["value"] == pytest.approx(82.1, abs=0.1)
+    assert r["metrics"]["pwc_corr_roofline"]["value"] == pytest.approx(3.93, abs=0.01)
+    assert r["device"]["slice_pages"] == 0  # the recording holds one execution
+    assert r["device"]["busy_s"] > 0 and r["device"]["window_s"] >= r["device"]["busy_s"]
+    assert len(r["breakdown"]["idle_gaps"]) == 10 and len(r["breakdown"]["device_ops"]) == 10
+    err = capfd.readouterr().err
+    assert "slice: first video written" in err
+    json.dumps(r)
+
+
+def test_a_window_that_closes_before_its_first_video_is_seen_says_so(tmp_path, monkeypatch):
+    from generators import corpus_run
+
+    calls = _fake_profiler(monkeypatch)
+    monkeypatch.setattr(corpus_run, "first_written", lambda output_dir: None)
+    with pytest.raises(SystemExit) as stopped:
+        _run(tmp_path, monkeypatch, "resnet50_fp32", trace=True)
+    assert stopped.value.code == ("no slice was traced: the window closed before its "
+                                  "first video was written")
+    assert calls == []

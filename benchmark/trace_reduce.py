@@ -21,6 +21,7 @@ from typing import Dict, List, Tuple
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 def find_xplane(directory: str) -> str:
@@ -95,6 +96,29 @@ def self_times(events: List[Tuple[str, int, int]]) -> Dict[str, int]:
     return dict(out)
 
 
+def page_program(modules: List[tuple]):
+    """The page program among a device's ``XLA Modules`` events ``(key, start,
+    duration)``: the module that holds most of the line's time."""
+    seconds: Dict[object, int] = defaultdict(int)
+    for key, _s, d in modules:
+        seconds[key] += d
+    return max(seconds, key=seconds.get)
+
+
+def whole_executions(modules: List[Tuple[str, int, int]]) -> int:
+    """How many whole executions of the page program a device's ``XLA
+    Modules`` line holds. A device runs one module at a time, and an
+    execution cut by the trace's start or stop is recorded as the piece
+    inside the trace: so one that has a predecessor on the line started
+    inside the trace, one that has a successor ended inside it, and the
+    line's first and last events are not counted."""
+    if not modules:
+        return 0
+    program = page_program(modules)
+    ordered = sorted(modules, key=lambda e: e[1])
+    return sum(1 for name, _s, _d in ordered[1:-1] if name == program)
+
+
 def reduce_planes(planes: dict, chips: int = 1) -> dict:
     device_planes = sorted(n for n in planes if n.startswith(DEVICE_PREFIX)
                            and OPS_LINE in planes[n])
@@ -108,7 +132,7 @@ def reduce_planes(planes: dict, chips: int = 1) -> dict:
         busy_ns += total
         if events:
             span = max(span, max(s + d for _n, s, d in events) - min(s for _n, s, _d in events))
-        gaps_all += [(e - s) for s, e in gaps]
+        gaps_all += [(e - s, s) for s, e in gaps]
         for op, ns in self_times(events).items():
             op_ns[op] += ns
         for op, _s, _d in events:
@@ -122,14 +146,22 @@ def reduce_planes(planes: dict, chips: int = 1) -> dict:
         "op_seconds": {k: v / n / 1e9 for k, v in top},
         "op_counts": {k: op_n[k] / n for k, _v in top},
         "top_ops": [[short_name(k), v / n / 1e9] for k, v in top[:10]],
-        # the program has no host spans, so a gap is named only by its rank
-        "idle_gaps": [[f"gap{i}", g / 1e9] for i, g in enumerate(gaps_all[:10])],
+        # named by rank here; the caller that holds the program's span records
+        # names them by host span (layer_metrics/_spans.name_idle_gaps)
+        "idle_gaps": [[f"gap{i}", g / 1e9] for i, (g, _s) in enumerate(gaps_all[:10])],
+        # where the same ten begin, in ns from the session's start
+        "idle_gap_starts": [s for _g, s in gaps_all[:10]],
+        "slice_pages": min(whole_executions(planes[name].get(MODULES_LINE, []))
+                           for name in device_planes),
         "planes": device_planes,
     }
 
 
 def reduce_trace_dir(directory: str, chips: int = 1) -> dict:
-    return reduce_planes(load_planes(directory), chips)
+    """The reduction of the one trace under ``directory``, with the file's
+    path beside it (``path``) for the readers that need more than this."""
+    path = find_xplane(directory)
+    return dict(reduce_planes(load_planes(path), chips), path=path)
 
 
 def describe(path: str) -> dict:
