@@ -7,6 +7,7 @@ bf16 TapConv3D lowering every bf16 I3D conv takes.
 # fast-registry: default tier — kernel parity vs torch mirrors
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -224,6 +225,60 @@ def test_warp_onehot_bf16_within_budget():
     # identical zero-set (mask parity) and bounded value drift
     np.testing.assert_array_equal(out == 0, np.abs(ref) < 1e-7)
     np.testing.assert_allclose(out, ref, rtol=0.02, atol=0.02)
+
+
+@pytest.mark.parametrize("size,out_size,channels", [
+    ((256, 341), (256, 384), 3),     # PWC at the I3D geometry: height skipped
+    ((64, 96), (256, 341), 2),       # PWC's flow back to the frame
+    ((96, 128), (128, 171), 3),      # R(2+1)D: a downscale, no antialiasing
+    ((720, 1280), (768, 1280), 3),   # PWC alone at 720p: width skipped
+    ((37, 53), (37, 53), 3),         # identity: the input's values exactly
+    ((1, 9), (4, 20), 1),            # a 1-pixel axis
+])
+def test_resize_bilinear_matches_torch(size, out_size, channels):
+    """ops/warp.resize_bilinear_torch == F.interpolate(mode='bilinear',
+    align_corners=False) over the geometries its callers use and their
+    edges. The interpolation matrices hold torch's own weights bit for bit
+    (the source coordinate is rounded once, as torch's fused multiply-add
+    does); what is left is the order of the two lerps."""
+    import torch
+
+    from video_features_tpu.ops.warp import resize_bilinear_torch
+
+    x = np.random.default_rng(9).random((2,) + size + (channels,), dtype=np.float32)
+    out = np.asarray(resize_bilinear_torch(jnp.asarray(x), *out_size))
+    assert out.dtype == np.float32 and out.shape == (2,) + out_size + (channels,)
+    if size == out_size:
+        np.testing.assert_array_equal(out, x)
+        return
+    ref = torch.nn.functional.interpolate(
+        torch.from_numpy(x).permute(0, 3, 1, 2), size=out_size, mode="bilinear",
+        align_corners=False).permute(0, 2, 3, 1).numpy()
+    np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+
+
+def test_resize_bilinear_is_pinned_contractions_not_gathers():
+    """A resize between static geometries holds no gather, one contraction
+    per axis that changes, and pins float32 products itself: the CLI's
+    default matmul precision (one bfloat16 pass on a TPU) must not reach it."""
+    from jax import lax
+
+    from video_features_tpu.ops.warp import resize_bilinear_torch
+
+    x = jnp.asarray(np.random.default_rng(2).random((2, 12, 16, 3), dtype=np.float32))
+    for out_size, n_dots in (((24, 31), 2), ((12, 31), 1), ((24, 16), 1), ((12, 16), 0)):
+        with jax.default_matmul_precision("bfloat16"):
+            jaxpr = jax.make_jaxpr(lambda a: resize_bilinear_torch(a, *out_size))(x)
+        prims = [e.primitive.name for e in jaxpr.eqns]
+        assert "gather" not in prims and "dynamic_slice" not in prims, prims
+        dots = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+        assert len(dots) == n_dots, (out_size, prims)
+        for e in dots:
+            assert e.params["precision"] == (lax.Precision.HIGHEST,) * 2
+            assert e.params["preferred_element_type"] == jnp.float32
+    plain = np.asarray(resize_bilinear_torch(x, 24, 31))
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_array_equal(np.asarray(resize_bilinear_torch(x, 24, 31)), plain)
 
 
 def test_raft_on_demand_matmul_matches_gather():

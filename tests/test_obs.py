@@ -6,6 +6,7 @@ stats/metrics-op latency histograms consistent with the journal."""
 
 import json
 import os
+import re
 import threading
 
 import numpy as np
@@ -722,3 +723,25 @@ def test_i3d_flow_step_lowers_with_pwc_scopes(tmp_path, monkeypatch):
                   "i3d/flow/I3D/i3d/stem"):
         assert scope in text, scope
     assert "jit__flow_forward" in text  # the step keeps its name
+
+
+def test_pwc_forward_keeps_resize_scopes_at_i3d_geometry():
+    """`flow_resize_pct` reads the time of whatever runs under
+    `pwc/resize_in` and `pwc/resize_out`: at 256x341 (the /64 grid is
+    256x384) both scopes name contractions with constant matrices, pinned
+    at float32 products, and no gather is left under either."""
+    import jax
+
+    from video_features_tpu.models.pwc import pwc_forward, pwc_init_params
+
+    frames = jax.ShapeDtypeStruct((1, 256, 341, 3), np.uint8)
+    text = jax.jit(pwc_forward).lower(pwc_init_params(0), frames, frames).as_text(
+        debug_info=True)
+    # an operation's line ends in loc(#locN); "#locN = loc("<scopes>/<op>"…)"
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+    for scope in ("pwc/resize_in", "pwc/resize_out"):
+        ops = [ln for ln in text.splitlines() if " = stablehlo." in ln
+               and scope + "/" in named.get(ln[ln.rindex("loc(") + 4:-1], "")]
+        dots = [ln for ln in ops if "stablehlo.dot_general" in ln]
+        assert dots and all("precision = [HIGHEST, HIGHEST]" in ln for ln in dots), scope
+        assert not any("gather" in ln for ln in ops), scope

@@ -172,7 +172,7 @@ def pwc_forward_frames(params: Dict, frames: jnp.ndarray,
 
     TPU-first formulation of the reference's pair loop: the feature pyramid —
     PWC's dominant stage by an earlier stage profile (small-channel convs at
-    128²/64²; on the v5e the resize gathers are, PERF.md §5)
+    128²/64²; on the v5e the level-2 decoder is, PERF.md §5)
     — is computed ONCE per frame (clips flattened into the conv batch axis) and
     pairs are formed by slicing the shared per-frame features, instead of
     re-encoding ``frames[:-1]`` and ``frames[1:]`` separately (which encodes

@@ -9,7 +9,10 @@ coordinates directly — the normalize/denormalize round-trip of grid_sample wit
 align_corners=True is the identity — keeps the math exact and avoids the (W−1)/2
 rescaling noise.
 
-XLA lowers the gathers to dynamic-slice-friendly ops; all shapes static.
+All shapes static. What still gathers is what samples at data: the warp's
+corner taps (:func:`bilinear_sample`; on a TPU they run on the scalar unit,
+hence :func:`bilinear_sample_onehot`) and RAFT's lookup (models/raft.py).
+:func:`resize_bilinear_torch` samples at compile-time constants and does not.
 """
 
 from __future__ import annotations
@@ -18,6 +21,7 @@ import math
 import os
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
@@ -204,39 +208,35 @@ def coords_grid(n: int, h: int, w: int) -> jnp.ndarray:
     return jnp.broadcast_to(jnp.stack([xs, ys], axis=-1), (n, h, w, 2))
 
 
-def upsample_bilinear_align(img: jnp.ndarray, out_h: int, out_w: int) -> jnp.ndarray:
-    """Bilinear resize with align_corners=True on (N, H, W, C).
-
-    torch ``F.interpolate(..., mode='bilinear', align_corners=True)``: output pixel i
-    maps to input coordinate i·(H−1)/(out−1). In-bounds by construction, so the
-    zero-padding masks in :func:`bilinear_sample` never fire.
-    """
-    n, h, w, _ = img.shape
-    sy = (h - 1) / (out_h - 1) if out_h > 1 else 0.0
-    sx = (w - 1) / (out_w - 1) if out_w > 1 else 0.0
-    ys = jnp.arange(out_h, dtype=jnp.float32) * sy
-    xs = jnp.arange(out_w, dtype=jnp.float32) * sx
-    gx, gy = jnp.meshgrid(xs, ys)
-    coords = jnp.broadcast_to(jnp.stack([gx, gy], -1), (n, out_h, out_w, 2))
-    return bilinear_sample(img, coords)
+def _lerp_matrix(size: int, out_size: int) -> np.ndarray:
+    """(out_size, size) float32 taps of torch's bilinear resize along one axis
+    (align_corners=False): ``1 − f`` and ``f`` around the source coordinate
+    (i + 0.5)·scale − 0.5, clamped to [0, size − 1]. The scale is float32 and
+    the coordinate rounded once (float64 holds it exactly), as the fused
+    multiply-add of torch's kernels gives it: the weights are torch's own."""
+    scale = np.float64(np.float32(size / out_size))
+    src = (scale * (np.arange(out_size) + 0.5) - 0.5).astype(np.float32)
+    src = np.clip(src, 0, size - 1)
+    i0 = np.floor(src).astype(np.int64)
+    f = src - i0.astype(np.float32)
+    m = np.zeros((out_size, size), np.float32)
+    m[np.arange(out_size), i0] = 1 - f
+    m[np.arange(out_size), np.minimum(i0 + 1, size - 1)] += f
+    return m
 
 
 def resize_bilinear_torch(img: jnp.ndarray, out_h: int, out_w: int) -> jnp.ndarray:
-    """Bilinear resize with align_corners=False (torch default), NHWC.
+    """Bilinear resize with align_corners=False (torch default), NHWC → float32.
 
-    Source coordinate: (i + 0.5)·scale − 0.5, clamped taps at the border (replicate
-    edge — torch clamps the corner indices, it does not zero them).
-    """
-    n, h, w, c = img.shape
-    sy = h / out_h
-    sx = w / out_w
-    ys = jnp.clip((jnp.arange(out_h, dtype=jnp.float32) + 0.5) * sy - 0.5, 0.0, None)
-    xs = jnp.clip((jnp.arange(out_w, dtype=jnp.float32) + 0.5) * sx - 0.5, 0.0, None)
-    # clamping low keeps coords ≥ 0; high side handled by corner clipping because
-    # weights for the out-of-range corner go to the in-range one only when the
-    # coordinate itself is in range — clamp high too for exactness
-    ys = jnp.minimum(ys, h - 1)
-    xs = jnp.minimum(xs, w - 1)
-    gx, gy = jnp.meshgrid(xs, ys)
-    coords = jnp.broadcast_to(jnp.stack([gx, gy], -1), (n, out_h, out_w, 2))
-    return bilinear_sample(img, coords)
+    Both geometries are static: each axis that changes is one contraction with
+    its constant :func:`_lerp_matrix` (H, then W), no gather. The products are
+    float32 whatever the ambient matmul precision says: pinned here."""
+    _, h, w, _ = img.shape
+    out = img.astype(jnp.float32)
+    for spec, size, out_size in (("oh,nhwc->nowc", h, out_h),
+                                 ("ow,nhwc->nhoc", w, out_w)):
+        if out_size != size:
+            out = jnp.einsum(spec, _lerp_matrix(size, out_size), out,
+                             precision=lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+    return out
