@@ -94,6 +94,11 @@ EXECUTION_FIELDS = (
     "paged_batching",          # dispatch mechanics; page outputs byte-match
                                # bucketed (pinned by tests/test_paged.py)
     "pages_in_flight",         # in-flight depth, not numerics
+    "page_tokens",             # laguna's page size: which transcripts share
+                               # a page moves a row by float rounding only
+                               # (attention's blocks fall elsewhere), as which
+                               # neighbours it met already does at one size
+                               # (pinned by tests/test_laguna.py)
     "raft_corr",               # impl choice, parity pinned (tests/test_raft)
     "pwc_corr",                # impl choice, parity pinned (test_pallas_corr)
     "pwc_warp",                # impl choice, parity pinned (tests/test_pwc)
@@ -139,6 +144,7 @@ _CHECKPOINT_NAMES = {
     "vggish": ("vggish",),
     "raft": ("raft-sintel",),
     "pwc": ("pwc-sintel",),
+    "laguna": ("laguna",),
 }
 
 

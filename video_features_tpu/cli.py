@@ -199,6 +199,10 @@ def build_parser() -> argparse.ArgumentParser:
                              "total in-flight rows match one bucketed "
                              "batch; >= 2 overlaps host refill with device "
                              "compute)")
+    parser.add_argument("--page_tokens", type=int, default=16384,
+                        help="laguna: token slots of one device page (whole "
+                             "transcripts share a page first-fit; a longer "
+                             "transcript is refused); a multiple of 512")
     parser.add_argument("--pack_flush_age", type=int, default=8,
                         help="--pack_corpus anti-starvation flush: dispatch a "
                              "bucket's partial queue once this many videos "

@@ -13,7 +13,7 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-FEATURE_TYPES = ("i3d", "vggish", "r21d_rgb", "resnet50", "raft", "pwc")
+FEATURE_TYPES = ("i3d", "vggish", "r21d_rgb", "resnet50", "raft", "pwc", "laguna")
 ON_EXTRACTION = ("print", "save_numpy")
 FLOW_TYPES = ("raft", "pwc")
 STREAMS = ("rgb", "flow")
@@ -130,6 +130,12 @@ class ExtractionConfig:
     # dispatch; page_rows = ceil(batch budget / depth), so total in-flight
     # rows stay at one bucketed batch regardless of depth).
     pages_in_flight: int = 2
+    # laguna (the text stream): token slots of one device page. A page holds
+    # whole transcripts first-fit, so this is also the longest transcript the
+    # type takes; a multiple of the attention kernel's block of 512. One
+    # program per value; which transcripts share a page moves a row by
+    # rounding only (docs/models/laguna.md).
+    page_tokens: int = 16384
     # Flow-net (RAFT/PWC) conv compute + correlation storage dtype, independent
     # of `dtype` (which governs the feature networks): bfloat16 halves flow-net
     # HBM traffic and MXU passes; correlation ACCUMULATION and coordinate math
@@ -425,6 +431,8 @@ class ExtractionConfig:
         if self.pages_in_flight < 1:
             raise ValueError("pages_in_flight must be >= 1 (2 = the "
                              "double-buffered default)")
+        if self.page_tokens < 1:
+            raise ValueError("page_tokens must be >= 1")
         if self.retries < 0:
             raise ValueError("retries must be >= 0")
         if self.retry_backoff < 0:
@@ -526,6 +534,9 @@ MODEL_DEFAULTS = {
     "raft": dict(),
     "pwc": dict(),
     "vggish": dict(),
+    # the text stream has one path, the packed one, and one page program on
+    # one chip: the checkpoint is that chip's share of the experts
+    "laguna": dict(num_devices=1),
 }
 
 
@@ -538,6 +549,8 @@ def resolve_model_defaults(cfg: ExtractionConfig) -> ExtractionConfig:
         streams = ("rgb", "flow")
     if streams is not None:
         updates["streams"] = tuple(streams)
+    if cfg.feature_type == "laguna" and not cfg.pack_corpus:
+        updates["pack_corpus"] = True
     return cfg.replace(**updates) if updates else cfg
 
 

@@ -25,4 +25,7 @@ def get_extractor(cfg):
     if ft == "vggish":
         from .vggish import ExtractVGGish
         return ExtractVGGish(cfg)
+    if ft == "laguna":
+        from .laguna import ExtractLaguna
+        return ExtractLaguna(cfg)
     raise ValueError(f"unknown feature_type: {ft}")

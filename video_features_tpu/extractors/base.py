@@ -1033,6 +1033,10 @@ class Extractor(abc.ABC):
             # observed in-flight ring — the bench's batches-in-flight proof
             "pages_dispatched": packer.pages_dispatched,
             "max_in_flight": packer.max_in_flight,
+            # token pages: table rows dispatched, and what the model counted
+            # on the device (laguna's routing counters)
+            "segments": packer.segments,
+            **self._extra_pack_stats(),
             # per-stage wall seconds for the whole corpus, the writer's
             # counters and — with recording on — the span records
             **self._run_stats(dict(self.clock.seconds)),
@@ -1059,6 +1063,10 @@ class Extractor(abc.ABC):
             print(f"extracted {extracted}/{len(paths)} videos "
                   f"({resumed} resumed{hits}) in {dt:.2f}s")
         return self._ok
+
+    def _extra_pack_stats(self) -> Dict:
+        """Counters a model keeps itself for ``_pack_stats`` (override)."""
+        return {}
 
     def _run_stats(self, stage_seconds: Dict[str, float]) -> Dict:
         """What every run leaves in ``_pack_stats`` whatever its loop: the

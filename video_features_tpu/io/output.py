@@ -111,7 +111,7 @@ def action_on_extraction(
                 os.makedirs(output_path, exist_ok=True)
             except OSError as e:
                 raise OutputError(f"cannot create output dir {output_path}: {e}") from e
-            fname = f"{pathlib.Path(video_path).stem}_{key}.npy"
+            fname = f"{output_stem(video_path)}_{key}.npy"
             fpath = os.path.join(output_path, fname)
             if value.ndim > 0 and len(value) == 0:
                 print(f"Warning: the value is empty for {key} @ {fpath}")
@@ -205,6 +205,12 @@ class FeatureAssembly:
         :meth:`stacked`'s ``np.stack`` copied the data, so outputs are safe.
         """
         self._rows.clear()
+
+
+def output_stem(video_path: str) -> str:
+    """``<stem>`` of a video's output files: the file name without its
+    extension, and without the ``.tokens`` of a transcript's ``.tokens.npz``."""
+    return pathlib.Path(video_path).stem.removesuffix(".tokens")
 
 
 def feats_nbytes(feats_dict: Mapping[str, np.ndarray]) -> int:

@@ -91,7 +91,8 @@ import numpy as np
 from ..io.output import FeatureAssembly
 from ..reliability.faults import fault_point
 from ..utils.metrics import span as bare_span
-from .pages import TABLE_COLS, build_row_table
+from .pages import (TABLE_COLS, TOKEN_PLANES, build_row_table,
+                    build_token_page, fit_documents)
 
 
 @dataclass
@@ -138,6 +139,16 @@ class PackSpec:
     Raw-pixels wire formats (``--device_resize``/``--device_preproc``) DO
     page — their slot queues key by decoded geometry, so every page is
     shape-homogeneous and runs that geometry's compiled family.
+
+    ``page_tokens``, when set (with ``paged_step``), makes the model's pages
+    **token pages**: a slot is one whole *document* (``open_clips`` yields
+    one object with ``ids`` and cumulative ``segment_ends`` per video) instead
+    of one fixed-shape array, a page holds as many whole documents as fit its
+    ``page_tokens`` token slots and ``page_rows`` table rows (first-fit over
+    the queue), and a document's output is its block of segment rows. One
+    queue and one compiled program whatever the lengths; ``real_slots`` and
+    ``dispatched_slots`` count tokens. A document that cannot fit an empty
+    page is the model's to refuse in ``open_clips``.
     """
 
     batch_size: int
@@ -160,6 +171,8 @@ class PackSpec:
     # refills page k+1 while the device chews on page k AND k-1's scatter
     # overlaps); bucketed dispatch always keeps exactly 1
     pages_in_flight: int = 2
+    # token slots per page: set = token pages of whole documents (above)
+    page_tokens: Optional[int] = None
 
 
 class ShapeBuckets:
@@ -332,6 +345,7 @@ class CorpusPacker:
         self.dispatched_slots = 0  # clips + padding/boundary slots dispatched
         self.staged_bytes = 0  # host bytes staged per dispatched device batch
         self.pages_dispatched = 0  # paged-mode dispatches (bench/stats)
+        self.segments = 0  # token pages: table rows (segments) dispatched
         self.max_in_flight = 0  # deepest observed in-flight ring (any key)
         self.video_clips: Dict[str, int] = {}  # per finished video
         # per shape key: {"real_slots", "dispatched_slots", "stale_flushes"}
@@ -390,7 +404,10 @@ class CorpusPacker:
         """Queue one clip; dispatches device batches when queues fill."""
         asm = self._open[path]
         slot = _Slot(asm, asm.reserve(), clip, vid=self._video_ids[path])
-        key = (self._video_model[path], clip.shape)
+        model = self._video_model[path]
+        tokens = self._specs[model].page_tokens
+        # token pages: one queue whatever the documents' lengths
+        key = (model, ("tokens", tokens) if tokens else clip.shape)
         self._video_keys.setdefault(path, set()).add(key)
         queue = self._pending.setdefault(key, [])
         # a bucket receiving slots is being fed, not stranded: age counts
@@ -415,8 +432,16 @@ class CorpusPacker:
 
     def _full(self, key: tuple) -> bool:
         queue = self._pending.get(key)
-        return bool(queue) and len(queue) >= self._batch_rows(
-            self._spec_for(key))
+        if not queue:
+            return False
+        spec = self._spec_for(key)
+        if spec.page_tokens:
+            # a token page goes when the queued documents fill it, or
+            # overflow it (the ones that do not fit wait for the next page)
+            return (sum(len(s.clip.ids) for s in queue) >= spec.page_tokens
+                    or sum(len(s.clip.segment_ends) for s in queue)
+                    >= self._batch_rows(spec))
+        return len(queue) >= self._batch_rows(spec)
 
     def _pump(self) -> None:
         """Dispatch every full queue, one batch per key per round,
@@ -511,13 +536,30 @@ class CorpusPacker:
         candidates = queue[:batch_size]
         page = self._page_seq  # the running page number: this dispatch's id
         self._page_seq += 1
-        with self._span("stage", page=page):
+        with self._span("stage", page=page) as staged:
             if spec.collate is not None:
                 batch, n_used, row_of = spec.collate(
                     [s.clip for s in candidates],
                     [(id(s.assembly), s.idx) for s in candidates])
                 slots = candidates[:n_used]
                 del queue[:n_used]  # in place: flush() iterates this same list
+            elif spec.page_tokens:
+                take = fit_documents(
+                    [(len(s.clip.ids), len(s.clip.segment_ends)) for s in queue],
+                    spec.page_tokens, batch_size)
+                if not take:
+                    raise ValueError(
+                        f"a document of {len(queue[0].clip.ids)} tokens and "
+                        f"{len(queue[0].clip.segment_ends)} segments fits no "
+                        f"page of {spec.page_tokens} tokens and {batch_size} rows")
+                slots = [queue[i] for i in take]
+                taken = set(take)
+                queue[:] = [s for i, s in enumerate(queue) if i not in taken]
+                batch, table, row_of = self._stage_token_page(
+                    slots, spec.page_tokens, batch_size)
+                # what the page holds, for whoever reads the span records
+                # (the benchmark's readers count attention's work from it)
+                staged.ids["documents"] = [len(s.clip.ids) for s in slots]
             else:
                 slots = candidates
                 del queue[:batch_size]
@@ -535,7 +577,7 @@ class CorpusPacker:
         # a full batch assembled but never stepped — recovery must replay
         # every co-packed video of every admitted request
         fault_point("device", str(key))
-        if paged:
+        if paged and not spec.page_tokens:
             with self._span("stage", page=page):
                 table = self._stage_table(slots, batch_size)
         # host time of the step call: the extractor's puts (their own spans,
@@ -561,23 +603,28 @@ class CorpusPacker:
         # a bucket being served is not starving: age counts from its last
         # activity (dispatch here, slot arrival in add())
         self._queue_born[key] = self._videos_finished
-        self.real_slots += len(slots)
+        real = len(slots)
+        if spec.page_tokens:  # a token page's slots are its token slots
+            real = sum(len(s.clip.ids) for s in slots)
+            self.segments += sum(len(s.clip.segment_ends) for s in slots)
+            batch_size = spec.page_tokens
+        self.real_slots += real
         self.dispatched_slots += batch_size
         stats = self._bucket_stats.setdefault(
             key, {"real_slots": 0, "dispatched_slots": 0, "stale_flushes": 0,
                   "pages_dispatched": 0})
         stats.setdefault("pages_dispatched", 0)
-        stats["real_slots"] += len(slots)
+        stats["real_slots"] += real
         stats["dispatched_slots"] += batch_size
         if paged:
             stats["pages_dispatched"] += 1
             self.pages_dispatched += 1
         if self._clock is not None:
             self._clock.add_units("packed_slots", batch_size)
-            self._clock.add_units("packed_clips", len(slots))
+            self._clock.add_units("packed_clips", real)
         if self._journal is not None:
             self._journal.emit("dispatch", bucket=self._bucket_name(key),
-                               real_slots=len(slots), batch_slots=batch_size,
+                               real_slots=real, batch_slots=batch_size,
                                paged=paged, inflight=len(ring), page=page)
         if self._metrics is not None:
             occ = round(stats["real_slots"] / stats["dispatched_slots"], 4)
@@ -600,6 +647,22 @@ class CorpusPacker:
             return build_row_table(entries, page_rows)
         buf = self._staging.acquire((page_rows, TABLE_COLS), np.int32)
         return build_row_table(entries, page_rows, out=buf)
+
+    def _stage_token_page(self, slots: List[_Slot], page_tokens: int,
+                          page_rows: int):
+        """One token page and its row table (staging-ring buffers when a
+        ring is wired) from whole documents → (page, table, each slot's
+        slice of the output rows)."""
+        shapes = ((TOKEN_PLANES, page_tokens), (page_rows, TABLE_COLS))
+        if self._staging is None:
+            page, table = (np.empty(shape, np.int32) for shape in shapes)
+        else:
+            page, table = (self._staging.acquire(shape, np.int32)
+                           for shape in shapes)
+        row_of = build_token_page(
+            [(s.vid, s.clip.ids, s.clip.segment_ends) for s in slots],
+            page, table)
+        return page, table, row_of
 
     def _stage_batch(self, clips: List[np.ndarray],
                      batch_size: int) -> np.ndarray:

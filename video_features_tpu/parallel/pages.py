@@ -34,6 +34,15 @@ Three pieces live here; the dispatch mechanics stay in
   aliases the buffer in place — the one dispatch-path donation the uint8
   wire format admits (``mesh.py::sharded_apply``'s documented seam).
 
+**Token pages** (the ``laguna`` text stream) are the same idea one level
+down: the page is ``page_tokens`` token slots filled with WHOLE documents of
+different lengths, each token carrying its document, its position in it and
+the table row of its segment, and the row table names the page's output rows:
+``(video id, segment idx, valid)``. :func:`fit_documents` chooses the
+documents first-fit, :func:`build_token_page` fills page and table, and the
+model's own forward masks pads (no epilogue multiply: a pad token must not be
+attended to, routed or averaged, which only the model can see to).
+
 Host scatter never reads the table (slots carry their assembly references —
 slot-level fault attribution is unchanged); the table is the device-side
 contract plus the journal/bench's occupancy ground truth.
@@ -78,6 +87,72 @@ def build_row_table(entries: Sequence[Tuple[int, int]], page_rows: int,
         table[i, 2] = 1
     table[n:] = PAD_ROW
     return table
+
+
+# token-page planes: page[plane, slot], int32
+TOKEN_PLANES = 4
+IDS, DOC, POS, SEG = range(TOKEN_PLANES)
+
+
+def fit_documents(sizes: Sequence[Tuple[int, int]], page_tokens: int,
+                  page_rows: int) -> list:
+    """First-fit: indices (in queue order) of the ``(tokens, segments)``
+    documents that go into the next page — each one that still fits what the
+    ones before it left, in both tokens and table rows."""
+    take, tokens, rows = [], 0, 0
+    for i, (n, s) in enumerate(sizes):
+        if tokens + n <= page_tokens and rows + s <= page_rows:
+            take.append(i)
+            tokens += n
+            rows += s
+            if tokens == page_tokens or rows == page_rows:
+                break
+    return take
+
+
+def build_token_page(docs: Sequence[Tuple[int, np.ndarray, np.ndarray]],
+                     page: np.ndarray, table: np.ndarray) -> list:
+    """Fill one token page and its row table in place (staging-ring buffers:
+    ``page`` int32 ``(4, page_tokens)``, ``table`` int32 ``(page_rows, 3)``).
+
+    ``docs``: ``(video id, ids, segment_ends)`` per document, ``segment_ends``
+    cumulative token counts. Planes: token id; document index in the page;
+    position in the document (restarting at 0); table row of the token's
+    segment. Pads are ``(0, -1, 0, -1)``, pad rows ``(-1, -1, 0)``. Returns
+    each document's slice of the table's rows."""
+    t = r = 0
+    row_slices = []
+    for d, (vid, ids, ends) in enumerate(docs):
+        n, s = len(ids), len(ends)
+        page[IDS, t:t + n] = ids
+        page[DOC, t:t + n] = d
+        page[POS, t:t + n] = np.arange(n, dtype=np.int32)
+        page[SEG, t:t + n] = r + np.repeat(
+            np.arange(s, dtype=np.int32), np.diff(ends, prepend=0))
+        table[r:r + s, 0] = vid
+        table[r:r + s, 1] = np.arange(s, dtype=np.int32)
+        table[r:r + s, 2] = 1
+        row_slices.append(slice(r, r + s))
+        t += n
+        r += s
+    page[IDS, t:] = 0
+    page[DOC, t:] = -1
+    page[POS, t:] = 0
+    page[SEG, t:] = -1
+    table[r:] = PAD_ROW
+    return row_slices
+
+
+def token_paged_program(forward: Callable[[Any, Any], Any]) -> Callable:
+    """Wrap a token model's ``forward(params, page) -> out`` into the paged
+    step ``(params, page, table) -> (out, table)``: the table rides through
+    for the same donation as :func:`paged_program`'s; masking by it is the
+    forward's own business (pads are in the page's planes)."""
+
+    def paged(params, page, table):
+        return forward(params, page), table
+
+    return paged
 
 
 def mask_rows(rows: Any, valid) -> Any:

@@ -17,6 +17,7 @@ Resolution order for model key ``<name>``:
 
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Callable, Dict, Optional
 
@@ -122,6 +123,29 @@ def looks_like_tf_vars(flat: Dict[str, np.ndarray]) -> bool:
     return any(
         k.replace(":0", "").rsplit("/", 1)[-1] in ("weights", "biases") for k in flat
     )
+
+
+@contextlib.contextmanager
+def open_checkpoint(name: str, init_fn: Optional[Callable[[], Dict[str, np.ndarray]]] = None):
+    """A flat checkpoint read leaf by leaf: yields ``(names, read)`` where
+    ``read(leaf)`` is that array alone. For a tree too large to hold twice
+    (laguna's share is 12.6 GB as float32 on disk and 6.3 GB as the bfloat16
+    the device keeps): the caller casts and places each leaf as it arrives,
+    so the host never holds more than one. Resolution as :func:`resolve_params`
+    for ``.npz`` files; ``init_fn`` (flat ``a/b/c`` → array) stands in where
+    random weights are allowed."""
+    for path in _candidates(name):
+        if path.endswith(".npz") and os.path.exists(path):
+            with np.load(path) as z:
+                yield list(z.files), z.__getitem__
+            return
+    if os.environ.get(ENV_ALLOW_RANDOM) == "1" and init_fn is not None:
+        flat = init_fn()
+        yield list(flat), flat.__getitem__
+        return
+    raise FileNotFoundError(
+        f"no checkpoint found for {name!r}: place its leaves at "
+        f"${ENV_DIR}/{name}.npz, or set {ENV_ALLOW_RANDOM}=1 for random weights")
 
 
 def resolve_params(
