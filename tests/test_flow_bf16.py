@@ -14,9 +14,17 @@ CPU runs bf16 in emulation — slow but bit-faithful; shapes stay small.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from video_features_tpu.models.pwc import pwc_forward, pwc_init_params
+from video_features_tpu.models.pwc import (
+    DEC_CURRENT,
+    DENSE_OUT,
+    LEVEL_NAMES,
+    _decoder,
+    pwc_forward,
+    pwc_init_params,
+)
 from video_features_tpu.models.raft import raft_forward, raft_init_params
 
 
@@ -42,6 +50,30 @@ def test_pwc_bf16_drift_bounded(frames):
     # bf16 has ~3 decimal digits; one conv stack + refiner accumulates to
     # sub-percent relative error in practice — bound at 2% of peak flow
     assert err.max() <= 0.02 * scale + 1e-3, (err.max(), scale)
+
+
+def test_pwc_bf16_decoder_accumulates_in_float32():
+    """The by-source dense block adds six partial convolutions: under
+    ``dtype=bfloat16`` each must come out of the MXU as float32 and be added in
+    float32, and only the finished sum rounded (the concatenated form rounded
+    one float32 sum over all input channels). Read from the jaxpr, so the
+    rounding point cannot drift back unseen."""
+    p = pwc_init_params(0)[LEVEL_NAMES[6]]
+    f = jnp.zeros((1, 4, 6, 196), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda a, b: _decoder(p, 6, a, b, None, "xla"))(f, f)
+    convs = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "conv_general_dilated"]
+    assert len(convs) == 6
+    for eqn in convs:
+        assert eqn.invars[0].aval.dtype == jnp.bfloat16  # the products stay bfloat16
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+    # every sum over feature maps (21 in the block: a bias and up to six
+    # partial convolutions for each of six consumers) is a float32 sum
+    adds = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "add"
+            and len(e.outvars[0].aval.shape) == 4]
+    assert len(adds) >= 21 and all(e.outvars[0].aval.dtype == jnp.float32 for e in adds)
+    feat, flow = jaxpr.out_avals  # a dict's leaves, by key
+    assert flow.dtype == feat.dtype == jnp.bfloat16
+    assert flow.shape[-1] == 2 and feat.shape[-1] == DEC_CURRENT[6] + sum(DENSE_OUT)
 
 
 def test_raft_bf16_drift_bounded(frames):

@@ -39,6 +39,7 @@ DEFAULT_TIER: Dict[str, str] = {
     "test_multihost": "loopback two-process jax.distributed init",
     "test_packer_models": "real-model packed parity (jit compiles)",
     "test_paged": "paged dispatch parity (jit compiles)",
+    "test_pwc": "the decoder's dense block by source; whole-model parity stays slow, test by test",
     "test_resnet": "resnet50 forward parity (heavy compile)",
     "test_segmented_decode": "real-sleep pool concurrency + e2e parity runs",
     "test_vggish": "vggish DSP + forward parity",

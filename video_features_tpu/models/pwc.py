@@ -10,7 +10,12 @@ Behavioral spec — ``/root/reference/models/pwc/pwc_src/pwc_net.py``:
   ``correlation.py:44-112``: channel k ↔ (dy=k//9−4, dx=k%9−4)), LeakyReLU'd;
   below level 6 the second feature map is backward-warped by the upsampled flow
   scaled per level (0.625/1.25/2.5/5.0), with the partial-tap zeroing mask
-  (``:23-41``); DenseNet-style conv block (new features concatenated in front).
+  (``:23-41``); DenseNet-style conv block. The reference concatenates each new
+  map in front and convolves the growing stack six times; here every map is
+  convolved ONCE, against the kernel rows all of its later consumers keep for
+  it, and the partial results are added in float32 (:func:`_dense_block`: the
+  same products, each 450–2 output columns wide instead of 128–2, and one
+  concatenate a level, for the 565-channel map's two readers outside).
 - Dilated refiner on the level-2 feature tail (``:189-210``).
 - Output: 20 × bilinear resize of (flow₂ + refinement) to the *original* size, u
   scaled by W/W₆₄, v by H/H₆₄ (``:256-261``).
@@ -32,6 +37,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..ops.nnf import conv2d, conv2d_transpose, leaky_relu
 from ..ops.pallas_corr import corr81, warp_corr81
@@ -45,7 +51,8 @@ PYR_CHANNELS = (16, 32, 64, 96, 128, 196)
 # decoder input channels per level: 81 + fmap + 2 flow + 2 upfeat (level 6: corr only)
 DEC_CURRENT = {6: 81, 5: 81 + 128 + 4, 4: 81 + 96 + 4, 3: 81 + 64 + 4, 2: 81 + 32 + 4}
 DEC_BACKWARD = {5: 0.625, 4: 1.25, 3: 2.5, 2: 5.0}
-DENSE_OUT = (128, 128, 96, 64, 32)  # moduleOne..moduleFiv
+DENSE_NAMES = ("moduleOne", "moduleTwo", "moduleThr", "moduleFou", "moduleFiv", "moduleSix")
+DENSE_OUT = (128, 128, 96, 64, 32)  # moduleOne..moduleFiv; moduleSix is the level's flow, 2 wide
 LEVEL_NAMES = {2: "moduleTwo", 3: "moduleThr", 4: "moduleFou", 5: "moduleFiv", 6: "moduleSix"}
 
 
@@ -92,10 +99,55 @@ def _decoder(p: Dict, level: int, f1: jnp.ndarray, f2: jnp.ndarray, prev,
         feat = jnp.concatenate([volume, f1, flow, upfeat], axis=-1)
 
     with jax.named_scope(f"pwc/decoder{level}"):
-        for name in ("moduleOne", "moduleTwo", "moduleThr", "moduleFou", "moduleFiv"):
-            feat = jnp.concatenate([leaky_relu(conv2d(p[name]["0"], feat, 1, 1)), feat], axis=-1)
-        flow = conv2d(p["moduleSix"]["0"], feat, 1, 1)
+        flow, feat = _dense_block(p, feat)
     return {"flow": flow, "feat": feat}
+
+
+def _dense_block(p: Dict, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The DenseNet block of one decoder level (pwc_net.py:166-187), convolved
+    by SOURCE. The reference's consumer k (0 … 5: moduleOne … moduleSix) reads
+    ``concat([x_k, …, x_1, x_0])``, so every map is re-read by every later
+    convolution, behind a fresh concatenate, into 128/128/96/64/32/2 columns.
+    A convolution is linear in its input channels, so each source x_j meets
+    the MXU once instead, against the kernel rows that every later consumer
+    keeps for it, side by side (450/322/194/98/34/2 columns, the consumers in
+    order, so the wide slices start on a 128-column tile):
+
+        y_j = conv3x3(x_j, W'_j);  acc_k += y_j[..., columns of consumer k]
+        x_{j+1} = leaky_relu(acc_j), complete once sources 0 … j are in
+
+    The same products, a different order of float32 additions. Six separate
+    sums and not one buffer: XLA:TPU then evaluates each where it is read, in
+    the convolutions' own layout (PERF.md §6, PR 35). ``acc`` is float32
+    whatever ``x.dtype`` is: under bfloat16 the concatenated form rounded ONE
+    float32 sum over all input channels, and six partial sums each rounded
+    first would be a worse number. Returns the level's flow and the 565-channel
+    map (new features in front) for its two readers outside the block: the one
+    concatenate a level that stays."""
+    widths = DENSE_OUT + (2,)
+    dtype = x.dtype
+    acc = [p[name]["0"]["bias"].astype(jnp.float32) for name in DENSE_NAMES]
+    maps = [x]
+    for j in range(len(DENSE_NAMES)):
+        # consumer k's input is [x_k | … | x_{j+1} | x_j | … | x_0] and W'_j's
+        # columns are [consumer j | … | consumer 5]: x_j's first row there and
+        # consumer k's first column here are both the summed widths of the
+        # maps between, x_{j+1} … x_k
+        starts = np.cumsum((0,) + widths[j:-1])
+        cin = x.shape[-1]
+        kernel = jnp.concatenate(
+            [p[name]["0"]["kernel"][:, :, start:start + cin, :]
+             for name, start in zip(DENSE_NAMES[j:], starts)], axis=-1)
+        y = lax.conv_general_dilated(
+            x, kernel.astype(dtype), (1, 1), ((1, 1), (1, 1)),
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            preferred_element_type=jnp.float32)
+        for k, part in enumerate(jnp.split(y, starts[1:], axis=-1), start=j):
+            acc[k] = acc[k] + part
+        if j < len(DENSE_OUT):
+            x = leaky_relu(acc[j]).astype(dtype)
+            maps.append(x)
+    return acc[-1].astype(dtype), jnp.concatenate(maps[::-1], axis=-1)
 
 
 @jax.named_scope("pwc/refiner")
@@ -301,11 +353,9 @@ def pwc_conv_shapes() -> Dict[str, Tuple]:
             shapes[f"{mod}.moduleUpflow"] = ("T", 2, 2, 4, 4)
             shapes[f"{mod}.moduleUpfeat"] = ("T", prev_feat, 2, 4, 4)
         ch = current
-        for name, cout in zip(("moduleOne", "moduleTwo", "moduleThr", "moduleFou", "moduleFiv"),
-                              DENSE_OUT):
+        for name, cout in zip(DENSE_NAMES, DENSE_OUT + (2,)):
             shapes[f"{mod}.{name}.0"] = (ch, cout, 3, 3)
             ch += cout
-        shapes[f"{mod}.moduleSix.0"] = (ch, 2, 3, 3)
 
     ch = DEC_CURRENT[2] + sum(DENSE_OUT)
     for idx, (cout, _d) in zip(("0", "2", "4", "6", "8", "10", "12"),
