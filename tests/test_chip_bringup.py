@@ -61,29 +61,30 @@ def test_compile_cache_placed_from_outside_is_left_alone(tmp_path, monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_compilation_cache_flag_is_gone():
+@pytest.mark.parametrize("field,flag,value", [
+    ("compilation_cache", "--compilation_cache", "/tmp/x"),
+    ("pwc_warp", "--pwc_warp", "gather"),
+])
+def test_removed_flags_are_gone(field, flag, value):
     from video_features_tpu.cli import parse_args
     from video_features_tpu.config import ExtractionConfig
 
-    assert "compilation_cache" not in ExtractionConfig.__dataclass_fields__
+    assert field not in ExtractionConfig.__dataclass_fields__
     with pytest.raises(SystemExit):
         parse_args(["--feature_type", "resnet50", "--video_paths", "a.mp4",
-                    "--compilation_cache", "/tmp/x"])
+                    flag, value])
 
 
 # ---- the kernels still lower for TPU ---------------------------------------
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["fp32", "bf16"])
-@pytest.mark.parametrize("kernel", ["single", "tiled", "fused"])
+@pytest.mark.parametrize("kernel", ["single", "tiled"])
 def test_pallas_kernels_cross_lower_for_tpu(kernel, dtype):
     """jaxpr → Mosaic MLIR at every PWC level shape, with no TPU present. The
     Mosaic compile itself happens on the chip (``chip_smoke.py``)."""
-    fn = {"single": pc.corr81_pallas, "tiled": pc.corr81_pallas_tiled,
-          "fused": pc.warp_corr81_pallas}[kernel]
+    fn = {"single": pc.corr81_pallas, "tiled": pc.corr81_pallas_tiled}[kernel]
     for (h, w, c) in LEVELS:
         args = [jax.ShapeDtypeStruct((2, h, w, c), dtype)] * 2
-        if kernel == "fused":
-            args.append(jax.ShapeDtypeStruct((2, h, w, 2), jnp.float32))
         exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
         assert "tpu_custom_call" in exported.mlir_module(), (kernel, h, w, c)
 
@@ -139,12 +140,37 @@ def test_lowering_selection_on_a_tpu_backend(monkeypatch):
     assert pc.corr81_lowering(big, jnp.float16, jnp.float16, "auto") == "xla"
 
 
-def test_fused_kernel_gate():
-    assert pc._warp_corr_supported(32, 48, 64, 4)
-    assert not pc._warp_corr_supported(64, 96, 32, 4)   # one-hot width cap
-    assert pc._warp_corr_supported(4, 6, 196, 2)
-    assert not pc._warp_corr_supported(5, 5, 196, 2)    # bf16 at an odd width
-    assert pc._warp_corr_supported(5, 5, 196, 4)
+# ---- warp, then correlate, under the scopes the benchmark reads -------------
+
+def _name_stacks(jaxpr):
+    """Every equation's name stack, through the sub-jaxprs (jit, scan, …)."""
+    for eqn in jaxpr.eqns:
+        yield str(eqn.source_info.name_stack)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _name_stacks(sub)
+
+
+@pytest.mark.parametrize("k,shape", list(zip((6, 5, 4, 3, 2), LEVELS[:5])))
+def test_warp_corr81_is_the_composition_under_both_scopes(rng, k, shape):
+    """``warp_corr81`` is ``warp_backward`` then ``corr81``, and a trace shows
+    them under ``pwc/warp<k>`` and ``pwc/corr<k>``: the names the benchmark's
+    breakdown and ``pwc_corr_roofline`` find the work by."""
+    from video_features_tpu.ops.warp import warp_backward
+
+    h, w, c = shape
+    f1 = jnp.asarray(rng.normal(size=(1, h, w, c)).astype(np.float32))
+    f2 = jnp.asarray(rng.normal(size=(1, h, w, c)).astype(np.float32))
+    flow = jnp.asarray(rng.uniform(-3, 3, (1, h, w, 2)).astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(pc.warp_corr81(f1, f2, flow, "xla", level=str(k))),
+        np.asarray(pc.corr81_xla(f1, warp_backward(f2, flow))))
+    jaxpr = jax.make_jaxpr(
+        lambda a, b, f: pc.warp_corr81(a, b, f, "xla", level=str(k)))(f1, f2, flow)
+    stacks = set(_name_stacks(jaxpr.jaxpr))
+    warp = {s for s in stacks if f"pwc/warp{k}" in s}
+    corr = {s for s in stacks if f"pwc/corr{k}" in s}
+    assert warp and corr and not warp & corr
+    assert warp | corr == stacks - {""}  # nothing of it outside the two
 
 
 # ---- chip_smoke.py refuses to pass without a chip ---------------------------

@@ -1,5 +1,9 @@
 """Config dataclass + CLI shim behavior (reference flag surface)."""
 
+import ast
+import os
+import re
+
 import pytest
 
 from video_features_tpu.cli import parse_args
@@ -73,13 +77,11 @@ def test_cli_tpu_knobs_round2():
     cfg = parse_args([
         "--feature_type", "raft", "--video_paths", "a.mp4",
         "--raft_corr", "on_demand", "--pwc_corr", "pallas",
-        "--pwc_warp", "onehot",
         "--matmul_precision", "highest", "--profile_dir", "/tmp/trace",
         "--clips_per_batch", "8", "--dtype", "bfloat16",
     ])
     assert cfg.raft_corr == "on_demand"
     assert cfg.pwc_corr == "pallas"
-    assert cfg.pwc_warp == "onehot"
     assert cfg.matmul_precision == "highest"
     assert cfg.profile_dir == "/tmp/trace"
     assert cfg.clips_per_batch == 8
@@ -95,8 +97,6 @@ def test_config_rejects_bad_round2_values():
         ExtractionConfig(feature_type="raft", raft_corr="cuda").validate()
     with pytest.raises(ValueError):
         ExtractionConfig(feature_type="pwc", pwc_corr="cupy").validate()
-    with pytest.raises(ValueError):
-        ExtractionConfig(feature_type="pwc", pwc_warp="bilinear").validate()
     with pytest.raises(ValueError):
         ExtractionConfig(feature_type="i3d", matmul_precision="bf16").validate()
 
@@ -188,3 +188,39 @@ def test_config_rejects_bad_flow_dtype_and_ffmpeg():
         ExtractionConfig(feature_type="pwc", flow_dtype="fp16").validate()
     with pytest.raises(ValueError):
         ExtractionConfig(feature_type="pwc", use_ffmpeg="maybe").validate()
+
+
+def test_environment_reads_are_deployment_and_debugging_settings_only():
+    """The package names seven ``VFT_*`` variables that are deployment or
+    debugging settings: where the weights are and what stands in for them,
+    the cache's pin, the multi-host switch, and the metrics and fault hooks.
+    A lowering is chosen from what the code can observe (backend, dtype,
+    shape) or by a flag — never from the environment, where no configuration,
+    cache key or benchmark cell can see it. One such switch is left of six:
+    ``VFT_I3D_TAP_FP32``, whose alternative read faster on the chip (PERF.md
+    §6, PR 36) and which goes when ROADMAP S4 makes that lowering the only one."""
+    package = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "video_features_tpu")
+    named = {}
+    for dirpath, _dirs, files in os.walk(package):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Constant) and isinstance(node.value, str)
+                        and re.fullmatch(r"VFT_[A-Z0-9_]+", node.value)):
+                    named.setdefault(node.value, set()).add(
+                        os.path.relpath(path, package))
+    assert named == {
+        "VFT_CHECKPOINT_DIR": {"weights/store.py"},
+        "VFT_ALLOW_RANDOM_WEIGHTS": {"weights/store.py"},
+        "VFT_WEIGHTS_VERSION": {"cache/key.py"},
+        "VFT_VGGISH_PCA_PARAMS": {"extractors/vggish.py"},
+        "VFT_MULTIHOST": {"parallel/pipeline.py"},
+        "VFT_METRICS": {"utils/metrics.py"},
+        "VFT_FAULTS": {"reliability/faults.py"},
+        "VFT_I3D_TAP_FP32": {"models/layers.py"},
+    }

@@ -83,7 +83,7 @@ def test_pad_to_shape_into_matches_pad_to_shape_uint8_round_trip():
 
 
 def test_pad_batch_preserves_uint8_zero_pad():
-    from video_features_tpu.extractors.base import pad_batch
+    from video_features_tpu.parallel.pipeline import pad_batch
 
     arr = np.full((2, 4, 4, 3), 200, np.uint8)
     padded = pad_batch(arr, 5)

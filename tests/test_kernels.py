@@ -129,26 +129,22 @@ def test_i3d_bf16_tap_path_close_to_fp32():
 
 
 def test_resolve_corr_impl_auto_switches_on_volume_size(monkeypatch):
+    from video_features_tpu.models import raft
     from video_features_tpu.models.raft import resolve_corr_impl
 
-    # ambient escape-hatch exports must not leak into these assertions
-    monkeypatch.delenv("VFT_RAFT_ON_DEMAND_IMPL", raising=False)
     # 16 pairs at 256²: pyramid 16·(32·32)²·4 B·1.328 ≈ 89 MB → volume
     assert resolve_corr_impl("auto", 16, 256, 256) == "volume"
     # 16 pairs at 1080p: 16·(135·240)²·4 B·1.328 ≈ 89 GB — several times
-    # HBM; the GATHER on-demand path is the big-frame default (ADVICE r5:
+    # HBM; the GATHER on-demand path is the big-frame choice (ADVICE r5:
     # the matmul remat's FLOPs scale with frame area and its win was only
-    # measured at 64×64 on CPU), with the env escape hatch opting into the
-    # remat once a committed 1080p TPU sweep justifies the flip
+    # measured at 64×64 on CPU). The remat is never auto's choice: it is
+    # asked for by name
     assert resolve_corr_impl("auto", 16, 1080, 1920) == "on_demand"
-    monkeypatch.setenv("VFT_RAFT_ON_DEMAND_IMPL", "matmul")
-    assert resolve_corr_impl("auto", 16, 1080, 1920) == "on_demand_matmul"
-    monkeypatch.delenv("VFT_RAFT_ON_DEMAND_IMPL")
     # explicit choices pass through untouched
     for impl in ("volume", "volume_gather", "on_demand", "on_demand_matmul"):
         assert resolve_corr_impl(impl, 16, 1080, 1920) == impl
     # bf16 halves the volume: a geometry just past the fp32 budget fits
-    monkeypatch.setenv("VFT_RAFT_VOLUME_BUDGET", str(16 * (32 * 32) ** 2 * 4))
+    monkeypatch.setattr(raft, "_VOLUME_HBM_BUDGET", 16 * (32 * 32) ** 2 * 4)
     assert resolve_corr_impl("auto", 16, 256, 256) == "on_demand"
     # mesh-sharded step: the budget is per DEVICE — 8 devices hold 2 pairs
     # each, so the same global batch fits (advisor round-3 finding)
@@ -187,44 +183,63 @@ def test_r21d_bf16_close_to_fp32():
     assert np.abs(f32 - fbf).max() <= 0.05 * scale
 
 
-def test_warp_onehot_matches_gather():
-    """MXU one-hot selector warp == gather warp (ops/warp.bilinear_sample_onehot):
-    same zero-padding semantics (OOB taps fall off the iota), ≤ 1-ulp fp
-    association differences, incl. far-OOB flows and edge-exact coords."""
-    from video_features_tpu.ops.warp import (bilinear_sample, bilinear_sample_onehot, warp_backward)
+def _warp_backward_numpy(img, flow):
+    """The reference's backward warp (pwc_net.py:23-41) written out: bilinear
+    taps at ``base + flow`` with zeros outside the frame, a ones channel
+    sampled alongside, and every pixel whose sampled one is <= 0.999 zeroed."""
+    img = img.astype(np.float32)
+    _, h, w, _ = img.shape
+    ys, xs = np.meshgrid(np.arange(h, dtype=np.float32),
+                         np.arange(w, dtype=np.float32), indexing="ij")
+    x, y = xs[None] + flow[..., 0], ys[None] + flow[..., 1]
+    x0, y0 = np.floor(x), np.floor(y)
+    fx, fy = x - x0, y - y0
+    batch = np.arange(img.shape[0])[:, None, None]
+    out = np.zeros(img.shape, np.float32)
+    ones = np.zeros(img.shape[:-1], np.float32)
+    for yi, wy in ((y0, 1 - fy), (y0 + 1, fy)):
+        for xi, wx in ((x0, 1 - fx), (x0 + 1, fx)):
+            inside = (yi >= 0) & (yi <= h - 1) & (xi >= 0) & (xi <= w - 1)
+            weight = (wy * wx * inside).astype(np.float32)
+            taps = img[batch, np.clip(yi, 0, h - 1).astype(int),
+                       np.clip(xi, 0, w - 1).astype(int)]
+            out += weight[..., None] * taps
+            ones += weight
+    return out * (ones > 0.999)[..., None]
+
+
+@pytest.mark.parametrize("case", ["float32", "bfloat16_image", "whole_pixels"])
+def test_warp_backward_matches_numpy_reference(case):
+    """The one warp lowering (corner gathers) against the warp written in
+    NumPy, on flows that leave the frame on every side."""
+    from video_features_tpu.ops.warp import warp_backward
 
     rng = np.random.default_rng(3)
     img = rng.standard_normal((2, 11, 15, 6)).astype(np.float32)
-    flow = (rng.uniform(-12, 12, (2, 11, 15, 2))).astype(np.float32)
-    ref = np.asarray(warp_backward(jnp.asarray(img), jnp.asarray(flow), impl="gather"))
-    out = np.asarray(warp_backward(jnp.asarray(img), jnp.asarray(flow), impl="onehot"))
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-    # raw sampler: edge-exact + OOB coords, and the chunked path (chunk < P)
-    coords = rng.uniform(-4, 18, (2, 5, 7, 2)).astype(np.float32)
-    coords[0, 0, 0] = [0.0, 0.0]
-    coords[0, 0, 1] = [14.0, 10.0]   # exact far corner
-    coords[0, 0, 2] = [-1.0, -1.0]   # fully OOB → 0
-    a = np.asarray(bilinear_sample(jnp.asarray(img), jnp.asarray(coords)))
-    b = np.asarray(bilinear_sample_onehot(jnp.asarray(img), jnp.asarray(coords),
-                                          chunk_budget=15 * 6 * 3))
-    np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
-
-
-def test_warp_onehot_bf16_within_budget():
-    """bf16 one-hot warp error vs the fp32 gather path stays within ~2× the
-    bf16 VALUE-rounding floor (selector-weight rounding adds ~0.4%·|v|);
-    the keep-mask is fp32 closed-form, so no spurious border zeroing."""
-    from video_features_tpu.ops.warp import warp_backward
-
-    rng = np.random.default_rng(4)
-    img = rng.standard_normal((2, 16, 16, 8)).astype(np.float32)
-    flow = rng.uniform(-5, 5, (2, 16, 16, 2)).astype(np.float32)
-    ref = np.asarray(warp_backward(jnp.asarray(img), jnp.asarray(flow), impl="gather"))
-    out = np.asarray(warp_backward(jnp.asarray(img).astype(jnp.bfloat16),
-                                   jnp.asarray(flow), impl="onehot"))
-    # identical zero-set (mask parity) and bounded value drift
-    np.testing.assert_array_equal(out == 0, np.abs(ref) < 1e-7)
-    np.testing.assert_allclose(out, ref, rtol=0.02, atol=0.02)
+    flow = rng.uniform(-12, 12, (2, 11, 15, 2)).astype(np.float32)
+    if case == "whole_pixels":
+        # every tap lands on a pixel, the far corner and one past each edge
+        # among them: the kept pixels are the shifted image exactly
+        flow = np.round(flow)
+    device_img = jnp.asarray(img)
+    if case == "bfloat16_image":
+        device_img = device_img.astype(jnp.bfloat16)
+        img = np.asarray(device_img.astype(jnp.float32))
+    out = np.asarray(warp_backward(device_img, jnp.asarray(flow)))
+    ref = _warp_backward_numpy(img, flow)
+    assert out.dtype == np.float32 and out.shape == ref.shape
+    zeroed = np.all(ref == 0, axis=-1)
+    # both are there to be wrong about: pixels kept and pixels zeroed, and
+    # flows past the left, right, top and bottom edges
+    assert 0.1 < zeroed.mean() < 0.9
+    xs = np.arange(15, dtype=np.float32)[None, None] + flow[..., 0]
+    ys = np.arange(11, dtype=np.float32)[None, :, None] + flow[..., 1]
+    assert xs.min() < -1 and xs.max() > 15 and ys.min() < -1 and ys.max() > 11
+    np.testing.assert_array_equal(np.all(out == 0, axis=-1), zeroed)
+    if case == "whole_pixels":
+        np.testing.assert_array_equal(out, ref)
+    else:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("size,out_size,channels", [

@@ -299,15 +299,28 @@ def test_span_records_carry_parent_and_shared_ids():
     assert all(r["end"] >= r["start"] for r in recs)
 
 
-def test_pull_under_threshold_adds_to_clock_and_leaves_no_record():
+def test_pull_under_threshold_adds_to_clock_and_leaves_no_record(monkeypatch):
+    # a clock the test owns: a real 0.2 ms sleep overshoots the 1 ms threshold
+    # on a loaded machine and leaves a second record
+    import types
+
+    from video_features_tpu.utils import metrics
+
+    now = [1000.0]
+    monkeypatch.setattr(metrics, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0], time_ns=lambda: int(now[0] * 1e9)))
+
+    def sleep(seconds):
+        now[0] += seconds
+
     rec = SpanRecorder()
     clock = StageClock()
 
     def frames():
         yield 0                      # no wait
-        time.sleep(0.0002)           # under the 1 ms threshold
+        sleep(0.0002)                # under the 1 ms threshold
         yield 1
-        time.sleep(0.02)             # a real stall
+        sleep(0.02)                  # a stall
         yield 2
 
     with span("extract", clock, rec, video="v"):
@@ -315,12 +328,12 @@ def test_pull_under_threshold_adds_to_clock_and_leaves_no_record():
                                       on_blocked=lambda s: rec.add("pull", s)))
     assert items == [0, 1, 2]
     assert clock.counts["decode"] == 3           # every pull on the clock
-    assert clock.seconds["decode"] >= 0.02
+    assert clock.seconds["decode"] == pytest.approx(0.0202)
     pulls = [r for r in rec.records if r["name"] == "pull"]
     assert len(pulls) == 1                       # only the blocked one
     (pull,) = pulls
     assert BLOCKED_RECORD_SECONDS == 1e-3
-    assert (pull["end"] - pull["start"]) / 1e9 >= 0.02  # real start and end
+    assert pull["end"] - pull["start"] == pytest.approx(0.02e9)  # its start and end
     assert pull["parent"] == 0 and pull["ids"] == {"video": "v"}
     assert rec.records[0]["start"] <= pull["start"]
 

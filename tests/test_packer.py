@@ -13,7 +13,8 @@ import pytest
 import jax.numpy as jnp
 
 from video_features_tpu.config import ExtractionConfig
-from video_features_tpu.extractors.base import Extractor, pad_batch
+from video_features_tpu.extractors.base import Extractor
+from video_features_tpu.parallel.pipeline import pad_batch
 from video_features_tpu.io.output import FeatureAssembly, load_done_set
 from video_features_tpu.parallel.packer import CorpusPacker, PackSpec
 from video_features_tpu.reliability import (

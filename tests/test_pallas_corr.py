@@ -78,11 +78,11 @@ def test_corr81_auto_dispatch(fmaps):
         np.asarray(corr81(f1, f2, "auto")), np.asarray(corr81(f1, f2, "xla")))
 
 
-def test_warp_corr81_fused_matches_composition(rng):
-    """Fused warp+corr kernel (interpreter) == warp_backward → corr81_xla,
-    including out-of-bounds flow (partial-tap zeroing) and a non-multiple-of-
-    16 geometry (tile padding)."""
-    from video_features_tpu.ops.pallas_corr import warp_corr81, warp_corr81_pallas
+def test_warp_corr81_matches_composition(rng):
+    """``warp_corr81`` == warp_backward → corr81_xla under every ``impl`` a
+    CPU can run, including out-of-bounds flow (partial-tap zeroing) and a
+    non-multiple-of-16 geometry (tile padding)."""
+    from video_features_tpu.ops.pallas_corr import warp_corr81
     from video_features_tpu.ops.warp import warp_backward
 
     for h, w in ((24, 40), (20, 28)):
@@ -91,9 +91,6 @@ def test_warp_corr81_fused_matches_composition(rng):
         # flows spanning in-bounds, fractional, and far out-of-bounds targets
         flow = jnp.asarray(rng.uniform(-10, 10, (2, h, w, 2)).astype(np.float32))
         ref = np.asarray(corr81_xla(f1, warp_backward(f2, flow)))
-        out = np.asarray(warp_corr81_pallas(f1, f2, flow, interpret=True))
-        np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-5)
-        # dispatcher: xla impl is the composition; interpret impl the kernel
         np.testing.assert_allclose(
             np.asarray(warp_corr81(f1, f2, flow, "xla")), ref, rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(
@@ -101,32 +98,31 @@ def test_warp_corr81_fused_matches_composition(rng):
             rtol=1e-4, atol=1e-5)
 
 
-def test_warp_corr81_fused_bf16(rng):
-    """bf16 features through the fused kernel: fp32 accumulation in-kernel,
-    bf16 store — matches the bf16 composition within bf16 rounding."""
-    from video_features_tpu.ops.pallas_corr import warp_corr81_pallas
+def test_warp_corr81_bf16(rng):
+    """bf16 features: the warp hands the tiled kernel a float32 f2 beside the
+    bf16 f1 — matches the same composition in XLA within bf16 rounding."""
+    from video_features_tpu.ops.pallas_corr import warp_corr81
     from video_features_tpu.ops.warp import warp_backward
 
     f1 = jnp.asarray(rng.normal(size=(1, 24, 24, 16))).astype(jnp.bfloat16)
     f2 = jnp.asarray(rng.normal(size=(1, 24, 24, 16))).astype(jnp.bfloat16)
     flow = jnp.asarray(rng.uniform(-6, 6, (1, 24, 24, 2)).astype(np.float32))
     ref = np.asarray(corr81_xla(f1, warp_backward(f2, flow)), dtype=np.float32)
-    out = np.asarray(warp_corr81_pallas(f1, f2, flow, interpret=True))
+    out = np.asarray(warp_corr81(f1, f2, flow, "pallas_interpret"))
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.float32(out), ref, rtol=0.03, atol=0.03)
 
 
 def test_warp_corr81_zero_flow_is_plain_corr(rng):
-    """Zero flow degenerates to corr81 of (f1, f2) away from the border (the
-    warp zeroes nothing in-bounds; border pixels differ only where corr taps
-    read beyond the image, which both paths zero-pad identically)."""
-    from video_features_tpu.ops.pallas_corr import warp_corr81_pallas
+    """Zero flow degenerates to corr81 of (f1, f2): the warp zeroes nothing
+    in-bounds, and both zero-pad the taps beyond the image identically."""
+    from video_features_tpu.ops.pallas_corr import warp_corr81
 
     f1 = jnp.asarray(rng.normal(size=(1, 32, 32, 8)).astype(np.float32))
     f2 = jnp.asarray(rng.normal(size=(1, 32, 32, 8)).astype(np.float32))
     flow = jnp.zeros((1, 32, 32, 2), jnp.float32)
     ref = np.asarray(corr81_xla(f1, f2))
-    out = np.asarray(warp_corr81_pallas(f1, f2, flow, interpret=True))
+    out = np.asarray(warp_corr81(f1, f2, flow, "pallas_interpret"))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 

@@ -174,22 +174,6 @@ def test_i3d_clip_batching_consistency(tmp_path, rng):
     )
 
 
-def test_pwc_onehot_warp_sharded_matches_single(tmp_path, rng):
-    """The one-hot selector warp (pwc_warp=onehot) under the 8-device mesh:
-    the selector einsums and lax.map chunking batch over the sharded pair
-    axis, so mesh size must not change the numbers."""
-    from video_features_tpu.extractors.flow import ExtractFlow
-
-    frames = rng.uniform(0, 255, (9, 64, 64, 3)).astype(np.float32)
-    ex1 = ExtractFlow(_cfg(tmp_path, "pwc", 1, batch_size=8, pwc_warp="onehot"))
-    ex8 = ExtractFlow(_cfg(tmp_path, "pwc", 8, batch_size=8, pwc_warp="onehot"))
-    f1 = np.asarray(ex1._step(ex1.params, ex1.runner.put(frames[:-1]),
-                              ex1.runner.put(frames[1:])))
-    f8 = np.asarray(ex8._step(ex8.params, ex8.runner.put(frames[:-1]),
-                              ex8.runner.put(frames[1:])))
-    np.testing.assert_allclose(f8, f1, rtol=1e-5, atol=1e-4)
-
-
 def test_raft_on_demand_matmul_sharded_matches_single(tmp_path, rng):
     """raft_corr=on_demand_matmul under the 8-device mesh: the per-chunk
     volume remat einsums batch over the sharded pair axis.

@@ -90,23 +90,6 @@ def test_flow_parity(converted):
     assert cos > 1 - 1e-5
 
 
-@slow
-def test_pwc_forward_onehot_warp_matches_default(converted, monkeypatch):
-    """Whole-model guard for VFT_WARP_IMPL=onehot: the MXU selector warp must
-    reproduce the gather-warp forward through all five decoder levels (the
-    lowering the production `auto` path would take if the default flips)."""
-    _, params = converted
-    rng = np.random.default_rng(2)
-    img1 = rng.uniform(0, 255, (1, 96, 128, 3)).astype(np.float32)
-    img2 = rng.uniform(0, 255, (1, 96, 128, 3)).astype(np.float32)
-    ref = np.asarray(pwc_forward(params, jnp.asarray(img1), jnp.asarray(img2)))
-    monkeypatch.setenv("VFT_WARP_IMPL", "onehot")
-    out = np.asarray(pwc_forward(params, jnp.asarray(img1), jnp.asarray(img2)))
-    # per-op drift is ≤1 ulp; five decoder levels + the 20× output scaling
-    # amplify it — bound well under a hundredth of a pixel
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=5e-3)
-
-
 # ---- the dense block: by source against by concatenation -------------------
 
 def _dense_block_concatenated(p, feat):

@@ -38,7 +38,7 @@ def test_tail_padding_does_not_leak(extractor):
     rng = np.random.default_rng(0)
     frames = rng.integers(0, 256, (64, 224, 224, 3), dtype=np.uint8)
     full = np.asarray(extractor._step(extractor.params, frames))
-    from video_features_tpu.extractors.base import pad_batch
+    from video_features_tpu.parallel.pipeline import pad_batch
 
     tail = pad_batch(frames[:5], 64)
     padded = np.asarray(extractor._step(extractor.params, tail))[:5]

@@ -544,7 +544,12 @@ def test_e2e_segmented_run_matches_sequential(
 def test_e2e_poisoned_segment_fails_only_its_video_and_retries(
         tmp_path, seg_corpus, monkeypatch):
     monkeypatch.setenv("VFT_FAULTS", "decode_segment:raise:vid2.mp4#seg1")
-    ex = StreamHasher(_cfg(tmp_path, "a", decode_workers=4, decode_segments=2))
+    # two permits a video: the run loop schedules the whole corpus ahead at
+    # its first video, and a video splits only into permits that are spare
+    # then — with four, vid0 and vid1 take them all, vid2 decodes in one piece
+    # and the poisoned segment is never opened
+    ex = StreamHasher(_cfg(tmp_path, "a", decode_workers=2 * len(seg_corpus),
+                           decode_segments=2))
     assert ex.run(seg_corpus) == len(seg_corpus) - 1
     failures = load_failures(ex.output_dir)
     assert set(failures) == {os.path.abspath(seg_corpus[2])}

@@ -127,14 +127,14 @@ def test_pair_batch_plan_tail_never_exceeds_batch():
 
 
 def test_pad_batch_full_batch_is_identity():
-    from video_features_tpu.extractors.base import pad_batch
+    from video_features_tpu.parallel.pipeline import pad_batch
 
     arr = np.arange(8, dtype=np.uint8).reshape(4, 2)
     assert pad_batch(arr, 4) is arr  # no copy on the hot full-batch path
 
 
 def test_pad_batch_empty_input_pads_to_all_zeros():
-    from video_features_tpu.extractors.base import pad_batch
+    from video_features_tpu.parallel.pipeline import pad_batch
 
     out = pad_batch(np.zeros((0, 3), np.float32), 4)
     assert out.shape == (4, 3) and out.dtype == np.float32
@@ -142,7 +142,7 @@ def test_pad_batch_empty_input_pads_to_all_zeros():
 
 
 def test_pad_batch_preserves_rows_and_dtype():
-    from video_features_tpu.extractors.base import pad_batch
+    from video_features_tpu.parallel.pipeline import pad_batch
 
     arr = np.arange(6, dtype=np.uint8).reshape(3, 2)
     padded = pad_batch(arr[:1], 4)
@@ -152,7 +152,7 @@ def test_pad_batch_preserves_rows_and_dtype():
 
 
 def test_pad_batch_overfull_raises():
-    from video_features_tpu.extractors.base import pad_batch
+    from video_features_tpu.parallel.pipeline import pad_batch
 
     with pytest.raises(ValueError, match="exceeds batch_size"):
         pad_batch(np.zeros((5, 2)), 4)

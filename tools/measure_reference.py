@@ -9,7 +9,7 @@ The reference publishes no benchmark numbers, so this script anchors
 - ResNet-50: 224² frames (/root/reference/models/resnet50/extract_resnet50.py:54)
 
 Numbers are recorded with hardware metadata; on this build host that is torch-CPU
-(the reference's CUDA path has no GPU here). Run once; bench.py reads the result.
+(the reference's CUDA path has no GPU here). Run once.
 
 Usage: python tools/measure_reference.py [--quick]
 """

@@ -26,7 +26,6 @@ from ..core import Finding, Rule, SourceFile, register
 # Deliberate default-tier modules: too compile-heavy for the fast tier, too
 # load-bearing for slow-only CI. Each file carries a matching annotation.
 DEFAULT_TIER: Dict[str, str] = {
-    "test_bench_record": "bench record/merge logic drives jitted extractors",
     "test_chip_bringup": "subprocesses that import jax + TPU cross-lowerings",
     "test_decode_pool": "real-sleep concurrency tests on the decode pool",
     "test_device_preproc": "device-preproc parity over real-model compiles",

@@ -101,7 +101,6 @@ EXECUTION_FIELDS = (
                                # (pinned by tests/test_laguna.py)
     "raft_corr",               # impl choice, parity pinned (tests/test_raft)
     "pwc_corr",                # impl choice, parity pinned (test_pallas_corr)
-    "pwc_warp",                # impl choice, parity pinned (tests/test_pwc)
     "flow_pair_chunk",         # lax.map chunking, parity pinned
     "precompile",              # compile scheduling
     "async_writer",            # write scheduling, same bytes

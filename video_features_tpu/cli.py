@@ -73,21 +73,14 @@ def build_parser() -> argparse.ArgumentParser:
                              "pyramid with MXU matmul lookup unless the volume "
                              "would outgrow HBM for the frame size, then "
                              "on_demand (the alt_cuda_corr equivalent, O(H*W) "
-                             "memory; VFT_RAFT_ON_DEMAND_IMPL=matmul opts into "
-                             "the MXU volume remat pending a 1080p TPU sweep); "
-                             "or force volume / volume_gather / on_demand / "
-                             "on_demand_matmul")
+                             "memory); or force volume / volume_gather / "
+                             "on_demand / on_demand_matmul (the MXU volume "
+                             "remat, never auto's choice)")
     parser.add_argument("--pwc_corr", choices=["auto", "xla", "pallas"],
                         default="auto",
                         help="PWC cost-volume implementation: auto picks the "
                              "Pallas tile kernel where its VMEM gate admits "
                              "the shape, else the fused XLA formulation")
-    parser.add_argument("--pwc_warp", choices=["auto", "gather", "onehot"],
-                        default="auto",
-                        help="PWC backward-warp lowering: gather corner taps "
-                             "or one-hot MXU selector matmuls (covers the "
-                             "levels the Mosaic cliff bars from the fused "
-                             "kernel); auto defers to VFT_WARP_IMPL")
     parser.add_argument("--flow_pair_chunk", type=int, default=None,
                         help="i3d flow sandwich: decode PWC pairs in sub-batches "
                              "of this size to bound HBM (default: auto; 0 = never; "
@@ -97,7 +90,7 @@ def build_parser() -> argparse.ArgumentParser:
                         help="flow models: stage frame windows as float32 on "
                              "the host (the pre-uint8 wire format) — 4x the "
                              "host->device bytes for byte-identical outputs; "
-                             "A/B escape hatch and the bench baseline "
+                             "an escape hatch "
                              "(docs/performance.md ingest fast path)")
     parser.add_argument("--device_resize", action="store_true", default=False,
                         help="resnet50: ship RAW decoded frames and run the "

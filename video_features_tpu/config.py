@@ -145,10 +145,10 @@ class ExtractionConfig:
     # RAFT correlation: "auto" (default) materializes the all-pairs pyramid
     # (reference default path, same numerics) unless the volume would outgrow
     # HBM for the frame geometry, then switches to "on_demand" (the
-    # alt_cuda_corr equivalent — O(H·W·D) memory; VFT_RAFT_ON_DEMAND_IMPL=
-    # matmul opts into the MXU volume remat once a committed 1080p TPU sweep
-    # justifies it — models/raft.py resolve_corr_impl, ADVICE r5); explicit
-    # "volume"/"volume_gather"/"on_demand"/"on_demand_matmul" force a path.
+    # alt_cuda_corr equivalent — O(H·W·D) memory; models/raft.py
+    # resolve_corr_impl); explicit "volume"/"volume_gather"/"on_demand"/
+    # "on_demand_matmul" (the MXU volume remat, never auto's choice: no 1080p
+    # TPU sweep justifies it yet) force a path.
     raft_corr: str = "auto"
     # PWC cost volume: "auto" (default) picks the Pallas tile kernel where its
     # VMEM gates admit the shape and the fused XLA formulation elsewhere
@@ -156,12 +156,6 @@ class ExtractionConfig:
     # the XLA formulation, "pallas" the kernels — and raises where they
     # cannot run rather than substituting XLA (ops/pallas_corr).
     pwc_corr: str = "auto"
-    # PWC backward-warp lowering: "gather" (take_along_axis corner taps) or
-    # "onehot" (MXU selector matmuls, ops/warp.bilinear_sample_onehot —
-    # covers the levels the Mosaic compile cliff bars from the fused
-    # kernel). "auto" (default) defers to VFT_WARP_IMPL, unset -> gather,
-    # pending a decision measured on the chip (ROADMAP S4).
-    pwc_warp: str = "auto"
     # I3D flow sandwich: decode the PWC pairs in sub-batches of this size
     # under lax.map to bound peak decoder memory (the 64-pair stack at the
     # sample videos' 256×341 geometry exceeds HBM in one piece). None = auto
@@ -222,8 +216,8 @@ class ExtractionConfig:
     # decoded uint8 bytes and casting inside the jitted step. 4× the
     # host→device bytes and host staging churn for IDENTICAL output bytes
     # (the u8→fp32 cast is exact; pinned by tests/test_ingest.py); exists as
-    # the A/B baseline for the bench uint8_ingest_flow scenario and as an
-    # escape hatch if a backend ever mishandles uint8 transfers.
+    # an escape hatch if a backend ever mishandles uint8 transfers (nothing
+    # measures it: ROADMAP D4).
     float32_wire: bool = False
     # Device-side resize (resnet50): ship RAW decoded frames and run the
     # smaller-edge bilinear resize + center crop inside the jitted step
@@ -249,8 +243,8 @@ class ExtractionConfig:
     # the log-mel STFT/mel pipeline on device (ops/audio.log_mel_examples,
     # ≤2e-5 vs the numpy oracle — fingerprints); r21d's transform has been
     # fully device-fused since its port (the flag is a documented no-op
-    # there). The bench `device_preproc` scenario records the decode-seconds
-    # vs host→device-bytes trade; parity pins live in
+    # there). It trades host decode seconds for host→device bytes (not
+    # measured on this installation); parity pins live in
     # tests/test_device_preproc.py.
     device_preproc: bool = False
     # Dense-flow D2H transfer dtype (raft/pwc extractors): the device casts
@@ -333,8 +327,7 @@ class ExtractionConfig:
     # WAL group-commit window: admissions acknowledged within this many
     # seconds of the last fsync share one (batched) fsync. 0 (default) =
     # fsync every appended record before acknowledging — strongest
-    # durability; set ~0.05 under high submit rates (the bench scenario
-    # budget assumes batching on).
+    # durability; set ~0.05 under high submit rates.
     wal_fsync_sec: float = 0.0
     # Replay unresolved WAL admissions at startup (--no_recover disables):
     # each entry is deduped against published result records and per-model
@@ -409,8 +402,6 @@ class ExtractionConfig:
                 "raft_corr must be auto|volume|volume_gather|on_demand|on_demand_matmul")
         if self.pwc_corr not in ("auto", "xla", "pallas"):
             raise ValueError("pwc_corr must be auto|xla|pallas")
-        if self.pwc_warp not in ("auto", "gather", "onehot"):
-            raise ValueError("pwc_warp must be auto|gather|onehot")
         if self.matmul_precision not in (None, "default", "high", "highest"):
             raise ValueError("matmul_precision must be default|high|highest")
         if self.decode_workers < 0:

@@ -175,7 +175,7 @@ class Extractor(abc.ABC):
         self._ok = 0
         self._failures = 0
         self._inline_writes = {"videos_written": 0, "write_bytes": 0}
-        # --pack_corpus occupancy of the last packed run (bench/run.py report):
+        # --pack_corpus occupancy of the last packed run (the benchmark reads it):
         # {"real_slots", "dispatched_slots", "occupancy", "video_clips"}
         self._pack_stats: Optional[Dict] = None
         # content-addressed feature cache (--cache_dir, docs/caching.md):
@@ -1026,11 +1026,11 @@ class Extractor(abc.ABC):
             "video_clips": dict(packer.video_clips),
             "buckets": packer.bucket_stats(),
             "stale_flushes": packer.stale_flushes,
-            # host bytes staged per dispatched device batch (the wire-format
-            # counter the bench's uint8-vs-float32_wire ratio reads)
+            # host bytes staged per dispatched device batch (the wire
+            # format's counter: uint8 against --float32_wire, tests/test_ingest.py)
             "staged_bytes": packer.staged_bytes,
             # paged dispatch (parallel/pages.py): page count and the deepest
-            # observed in-flight ring — the bench's batches-in-flight proof
+            # observed in-flight ring (tests/test_paged.py)
             "pages_dispatched": packer.pages_dispatched,
             "max_in_flight": packer.max_in_flight,
             # token pages: table rows dispatched, and what the model counted
@@ -1567,15 +1567,3 @@ class MultiModelSessions:
             ex._writer = None  # the shared writer is closed and drained
             ex._reap_abandoned_writes()
             ex._prune_succeeded(ex._succeeded)
-
-
-def pad_batch(arr: np.ndarray, batch_size: int) -> np.ndarray:
-    """Zero-pad the leading axis to ``batch_size`` (static shapes: one XLA compile
-    per geometry instead of one per partial tail batch)."""
-    n = arr.shape[0]
-    if n == batch_size:
-        return arr
-    if n > batch_size:
-        raise ValueError(f"batch of {n} exceeds batch_size {batch_size}")
-    pad = np.zeros((batch_size - n,) + arr.shape[1:], arr.dtype)
-    return np.concatenate([arr, pad], axis=0)

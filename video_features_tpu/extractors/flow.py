@@ -118,7 +118,7 @@ class ExtractFlow(Extractor):
         self._upcast = cfg.transfer_dtype != "float32"
         # H2D wire dtype: decoded uint8 end-to-end (the jitted steps' first
         # op is the exact u8→fp32 cast); --float32_wire restores the retired
-        # host-side cast at 4× the staged bytes (A/B + bench baseline)
+        # host-side cast at 4× the staged bytes (an escape hatch)
         self._wire = np.float32 if cfg.float32_wire else np.uint8
         if self.feature_type == "raft":
             self.params = self.runner.put_replicated(
@@ -155,15 +155,12 @@ class ExtractFlow(Extractor):
                 )
             )
             self._forward = functools.partial(
-                pwc_forward, corr_impl=cfg.pwc_corr, dtype=flow_dtype,
-                warp_impl=cfg.pwc_warp)
+                pwc_forward, corr_impl=cfg.pwc_corr, dtype=flow_dtype)
             self._forward_frames = functools.partial(
-                pwc_forward_frames, corr_impl=cfg.pwc_corr, dtype=flow_dtype,
-                warp_impl=cfg.pwc_warp)
+                pwc_forward_frames, corr_impl=cfg.pwc_corr, dtype=flow_dtype)
             self._forward_frames_sharded = functools.partial(
                 pwc_forward_frames_sharded, mesh=self.runner.mesh,
-                corr_impl=cfg.pwc_corr, dtype=flow_dtype,
-                warp_impl=cfg.pwc_warp)
+                corr_impl=cfg.pwc_corr, dtype=flow_dtype)
             self._pads_input = False
         else:
             raise ValueError(f"not a flow feature type: {self.feature_type}")
@@ -178,7 +175,7 @@ class ExtractFlow(Extractor):
         # frame twice. No longer the production multi-device path (the
         # encode-once _frames_step_sharded replaced it) — retained as the
         # parity reference the sharded paths are tested against and for the
-        # dryrun/bench harnesses that compare both.
+        # dryrun harness that compares both.
         def step(params, prev, nxt):  # each (B, H, W, 3) float32
             return fwd(params, prev, nxt).astype(tdt)
 
@@ -361,7 +358,7 @@ class ExtractFlow(Extractor):
     def _dispatch_pairs(self, frames: np.ndarray):
         """Dispatch one premade pair-window ARRAY to the device; returns an
         async handle. The compatibility seam for callers holding a stacked
-        window (tests, bench, the dryrun harness) — the production loops
+        window (tests, the dryrun harness) — the production loops
         stage through :meth:`_dispatch_window` / the packed collate instead.
 
         The jitted call returns immediately (JAX async dispatch) and

@@ -30,7 +30,6 @@ TPU design (vs the reference's one-stack-at-a-time GPU loop, ``:139-169``):
 from __future__ import annotations
 
 import functools
-import os
 from typing import Dict, List
 
 import numpy as np
@@ -48,10 +47,11 @@ from ..models.raft import (
 )
 from ..ops.image import device_edge_resize_hwc, pil_edge_resize
 from ..parallel import DATA_AXIS, prefetch_to_device
+from ..parallel.pipeline import pad_batch
 from ..utils.labels import show_predictions_on_dataset
 from ..weights.convert_torch import convert_i3d, convert_pwc, convert_raft
 from ..weights.store import resolve_params
-from .base import Extractor, pad_batch
+from .base import Extractor
 
 # Reference geometry (256-edge resize, 224 center crop — extract_i3d.py:25 +
 # transforms) lives in config.py as the i3d_pre_crop_size/i3d_crop_size defaults.
@@ -116,13 +116,7 @@ class ExtractI3D(Extractor):
         if self._flow_frame_sharded:
             self.clips_per_batch = 1  # one frame-sharded clip per step
 
-        # VFT_I3D_S2D=1 opts into the space-to-depth stem lowering; measured
-        # SLOWER on v5e (the fold relayout costs more than the small-channel
-        # stem conv, which XLA already runs at ~20 TF/s — an earlier
-        # installation's stage profile; not measured on this one)
-        s2d = os.environ.get("VFT_I3D_S2D") == "1"
-        self.i3d = {s: I3D(modality=s, s2d_stem=s2d, dtype=self.dtype)
-                    for s in self.streams}
+        self.i3d = {s: I3D(modality=s, dtype=self.dtype) for s in self.streams}
         self.i3d_params = {
             s: self.runner.put_replicated(
                 resolve_params(
@@ -244,8 +238,7 @@ class ExtractI3D(Extractor):
                 return pwc_forward_frames(self.flow_params, fr,
                                           corr_impl=self.cfg.pwc_corr,
                                           dtype=flow_dtype,
-                                          pair_chunk=chunk,
-                                          warp_impl=self.cfg.pwc_warp)
+                                          pair_chunk=chunk)
 
             if n_dev > 1:
                 flow_net = jax.shard_map(flow_net, mesh=self.runner.mesh,
@@ -282,7 +275,6 @@ class ExtractI3D(Extractor):
                       else jnp.float32)
         raft_corr = self.cfg.raft_corr
         pwc_corr = self.cfg.pwc_corr
-        pwc_warp = self.cfg.pwc_warp
         crop = self.crop_size
         pre_crop = self.pre_crop_size
         device_preproc = self._device_preproc
@@ -314,7 +306,7 @@ class ExtractI3D(Extractor):
                 # decoder batch, so --flow_pair_chunk does not apply here
                 flow = pwc_forward_frames_sharded(
                     flow_params, frames, last, mesh,
-                    corr_impl=pwc_corr, dtype=flow_dtype, warp_impl=pwc_warp)
+                    corr_impl=pwc_corr, dtype=flow_dtype)
             # flow: (S, Hp, Wp, 2) sharded on the pair axis → one clip
             x = i3d_preprocess_flow(_center_crop_nhwc(flow[None], crop),
                                     dtype=dtype)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from ..audio.melspec import wav_to_examples, wav_to_pcm_slabs
 from ..io import ffmpeg as ffmpeg_io
 from ..ops.audio import log_mel_examples
+from ..parallel.pipeline import pad_batch
 from ..models.vggish import (
     EMBEDDING_SIZE,
     Postprocessor,
@@ -43,7 +44,7 @@ from ..models.vggish import (
     vggish_init_params,
 )
 from ..weights.store import resolve_params
-from .base import Extractor, pad_batch
+from .base import Extractor
 
 # examples per jitted call; audio shorter than this pads, longer chunks
 EXAMPLE_BATCH = 32

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 
 from .layers import (
-    S2DStemConv,
     TorchBatchNorm,
     avg_pool_valid,
     conv3d_module,
@@ -70,16 +69,11 @@ class Unit3D(nn.Module):
     use_bn: bool = True
     use_bias: bool = False
     relu: bool = True
-    s2d: bool = False  # space-to-depth lowering (7³/2³ stem only, see layers.py)
     dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        if self.s2d:
-            assert tuple(self.kernel) == (7, 7, 7) and tuple(self.stride) == (2, 2, 2)
-            assert not self.use_bias
-            x = S2DStemConv(self.features, dtype=self.dtype, name="conv3d")(x)
-        elif not self.use_bias:
+        if not self.use_bias:
             # shared chooser: bf16 takes the TapConv3D lowering (conv3d-bf16
             # backend pathology), fp32 the direct conv — same param tree
             x = conv3d_module(self.features, self.kernel, self.stride,
@@ -131,7 +125,6 @@ class I3D(nn.Module):
 
     num_classes: int = 400
     modality: str = "rgb"
-    s2d_stem: bool = False  # MXU space-to-depth stem (fp-reassociation only)
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -146,8 +139,7 @@ class I3D(nn.Module):
             for op, name, *spec in ops:
                 if op == "conv":
                     feats, kernel, stride = spec
-                    s2d = self.s2d_stem and name == "conv3d_1a_7x7"
-                    x = Unit3D(feats, kernel, stride, s2d=s2d, dtype=self.dtype,
+                    x = Unit3D(feats, kernel, stride, dtype=self.dtype,
                                name=name)(x)
                 elif op == "pool":
                     kernel, stride = spec

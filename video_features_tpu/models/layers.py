@@ -53,66 +53,6 @@ def tf_same_pads(kernel: Sequence[int], stride: Sequence[int]) -> Tuple[Tuple[in
     return tuple(pads)
 
 
-class S2DStemConv(nn.Module):
-    """Stride-2³ 7³ stem conv computed space-to-depth: the MXU formulation.
-
-    Measured result (an earlier installation's stage profile, v5e,
-    4×64×224² fp32; not measured on this one): SLOWER than
-    the direct conv — 37 ms vs 10.5 ms — because the fold's input relayout
-    costs more than the stem conv, which XLA already runs at ~20 TF/s despite
-    cin=3. Kept as a tested opt-in (``VFT_I3D_S2D=1`` /
-    ``I3D(s2d_stem=True)``) for hardware/compiler versions where the tradeoff
-    flips; the mechanics:
-
-    - pad input with the reference's TF-SAME pads (2, 3) per axis
-      (``i3d_net.py:8-25`` rule), plus trailing zeros to an even size;
-    - pad the 7-tap kernel to 8 with one trailing zero tap per axis;
-    - fold input and kernel by tap parity (k = 2m + r) and run the 4³ conv
-      VALID at stride 1.
-
-    Output values equal the direct conv up to fp reassociation (the extra taps
-    multiply zeros). The param tree is identical to ``nn.Conv(name="conv3d")``
-    — ``kernel`` HWIO — so converted checkpoints load unchanged.
-    """
-
-    features: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        c = x.shape[-1]
-        kernel = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(),
-            (7, 7, 7, c, self.features),
-            jnp.float32,
-        )
-        sizes = x.shape[1:-1]
-        out_sizes = [(n + 5 - 7) // 2 + 1 for n in sizes]
-        pads = []
-        for n in sizes:
-            lo, hi = 2, 3  # max(k - s, 0) = 5 split floor/ceil
-            hi += 1  # kernel tap 8 reads one past the SAME window
-            if (n + lo + hi) % 2:
-                hi += 1  # even length for the 2-fold
-            pads.append((lo, hi))
-        xp = jnp.pad(x.astype(self.dtype), [(0, 0)] + pads + [(0, 0)])
-        b, tp, hp, wp, _ = xp.shape
-        xf = xp.reshape(b, tp // 2, 2, hp // 2, 2, wp // 2, 2, c)
-        xf = xf.transpose(0, 1, 3, 5, 2, 4, 6, 7).reshape(
-            b, tp // 2, hp // 2, wp // 2, 8 * c
-        )
-        w8 = jnp.pad(kernel.astype(self.dtype),
-                     ((0, 1), (0, 1), (0, 1), (0, 0), (0, 0)))
-        wf = w8.reshape(4, 2, 4, 2, 4, 2, c, self.features)
-        wf = wf.transpose(0, 2, 4, 1, 3, 5, 6, 7).reshape(4, 4, 4, 8 * c, self.features)
-        y = lax.conv_general_dilated(
-            xf, wf, window_strides=(1, 1, 1), padding="VALID",
-            dimension_numbers=("NDHWC", "DHWIO", "NDHWC"),
-        )
-        return y[:, : out_sizes[0], : out_sizes[1], : out_sizes[2], :]
-
-
 class TapConv3D(nn.Module):
     """conv3d lowered as a sum of per-temporal-tap conv2ds (TF-SAME pads by
     default; torch-style explicit per-axis pads via ``padding``).

@@ -82,7 +82,7 @@ def _pyramid(p: Dict, x: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
 
 
 def _decoder(p: Dict, level: int, f1: jnp.ndarray, f2: jnp.ndarray, prev,
-             corr_impl: str = "xla", warp_impl: str = "auto"):
+             corr_impl: str = "xla"):
     """One coarse-to-fine stage (pwc_net.py:152-187)."""
     if prev is None:
         with jax.named_scope(f"pwc/corr{level}"):
@@ -92,10 +92,8 @@ def _decoder(p: Dict, level: int, f1: jnp.ndarray, f2: jnp.ndarray, prev,
         with jax.named_scope(f"pwc/decoder{level}"):
             flow = conv2d_transpose(p["moduleUpflow"], prev["flow"])
             upfeat = conv2d_transpose(p["moduleUpfeat"], prev["feat"])
-        # fused warp+correlate (ops/pallas_corr.warp_corr81): under pallas/auto
-        # the warped f2 never exists in HBM — warp gathers were the PWC floor
         volume = leaky_relu(warp_corr81(f1, f2, flow * DEC_BACKWARD[level],
-                                        corr_impl, warp_impl, level=str(level)))
+                                        corr_impl, level=str(level)))
         feat = jnp.concatenate([volume, f1, flow, upfeat], axis=-1)
 
     with jax.named_scope(f"pwc/decoder{level}"):
@@ -170,13 +168,12 @@ def _preprocess(image: jnp.ndarray, h64: int, w64: int) -> jnp.ndarray:
 
 
 def _decode(params: Dict, pyr1, pyr2, h: int, w: int, h64: int, w64: int,
-            corr_impl: str, warp_impl: str = "auto") -> jnp.ndarray:
+            corr_impl: str) -> jnp.ndarray:
     """Coarse-to-fine decoders + refiner + output scaling (pwc_net.py:241-261)."""
     est = None
     for level in (6, 5, 4, 3, 2):
         est = _decoder(params[LEVEL_NAMES[level]], level,
-                       pyr1[level - 1], pyr2[level - 1], est, corr_impl,
-                       warp_impl)
+                       pyr1[level - 1], pyr2[level - 1], est, corr_impl)
 
     flow = est["flow"] + _refiner(params["moduleRefiner"]["moduleMain"], est["feat"])
     with jax.named_scope("pwc/resize_out"):
@@ -191,8 +188,7 @@ def _grid64(h: int, w: int) -> Tuple[int, int]:
 
 
 def pwc_forward(params: Dict, image1: jnp.ndarray, image2: jnp.ndarray,
-                corr_impl: str = "xla", dtype=jnp.float32,
-                warp_impl: str = "auto") -> jnp.ndarray:
+                corr_impl: str = "xla", dtype=jnp.float32) -> jnp.ndarray:
     """Flow frame1→frame2. Inputs (B, H, W, 3) RGB [0, 255] — uint8 (the
     extractors' wire format; ``_preprocess``'s fp32 cast is the first traced
     op, exact) or float — any size. Returns (B, H, W, 2) float32 flow in
@@ -210,13 +206,12 @@ def pwc_forward(params: Dict, image1: jnp.ndarray, image2: jnp.ndarray,
     x2 = _preprocess(image2, h64, w64).astype(dtype)
     pyr1 = _pyramid(params["moduleExtractor"], x1)
     pyr2 = _pyramid(params["moduleExtractor"], x2)
-    return _decode(params, pyr1, pyr2, h, w, h64, w64, corr_impl, warp_impl)
+    return _decode(params, pyr1, pyr2, h, w, h64, w64, corr_impl)
 
 
 def pwc_forward_frames(params: Dict, frames: jnp.ndarray,
                        corr_impl: str = "xla", dtype=jnp.float32,
-                       pair_chunk: int = None,
-                       warp_impl: str = "auto") -> jnp.ndarray:
+                       pair_chunk: int = None) -> jnp.ndarray:
     """Flow for all consecutive frame pairs, sharing per-frame features.
 
     ``frames``: (F, H, W, 3) → (F−1, H, W, 2), or a clip batch (N, F, H, W, 3)
@@ -261,8 +256,7 @@ def pwc_forward_frames(params: Dict, frames: jnp.ndarray,
         # must never silently disengage on an odd pair count.
         def chunked(level_maps):
             p1, p2 = level_maps
-            return _decode(params, p1, p2, h, w, h64, w64, corr_impl,
-                           warp_impl)
+            return _decode(params, p1, p2, h, w, h64, w64, corr_impl)
 
         nch = -(-total // chunk)
         pad = nch * chunk - total
@@ -277,15 +271,14 @@ def pwc_forward_frames(params: Dict, frames: jnp.ndarray,
                                      tuple(to_chunks(p) for p in pyr2)))
         flow = flow.reshape((nch * chunk, h, w, 2))[:total]
     else:
-        flow = _decode(params, pyr1, pyr2, h, w, h64, w64, corr_impl,
-                       warp_impl)
+        flow = _decode(params, pyr1, pyr2, h, w, h64, w64, corr_impl)
     return flow.reshape(lead[:-1] + (f - 1, h, w, 2))
 
 
 def pwc_forward_frames_sharded(params: Dict, frames: jnp.ndarray,
                                frame_last: jnp.ndarray, mesh,
-                               corr_impl: str = "xla", dtype=jnp.float32,
-                               warp_impl: str = "auto") -> jnp.ndarray:
+                               corr_impl: str = "xla",
+                               dtype=jnp.float32) -> jnp.ndarray:
     """Encode-once flow over a multi-device mesh, frame axis sharded.
 
     ``frames``: the window's B source frames (B, H, W, 3) sharded on axis 0
@@ -321,7 +314,7 @@ def pwc_forward_frames_sharded(params: Dict, frames: jnp.ndarray,
                 [lvl[1:], boundary_from_next(lvl[:1], lvl_l, axis, n_dev)],
                 axis=0)
             for lvl, lvl_l in zip(pyr, pyr_l))
-        return _decode(p, pyr, pyr2, h, w, h64, w64, corr_impl, warp_impl)
+        return _decode(p, pyr, pyr2, h, w, h64, w64, corr_impl)
 
     fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P(), P(axis), P()), out_specs=P(axis))

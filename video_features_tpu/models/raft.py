@@ -44,7 +44,7 @@ ITERS = 20  # reference inference default (raft.py:115)
 # HBM budget for the materialized all-pairs pyramid; past it, corr_impl
 # "auto" switches to the on-demand path (the alt_cuda_corr equivalent).
 # ~4 GiB leaves room for the one-hot selectors, activations, and double
-# buffering on a 16 GiB chip; override via VFT_RAFT_VOLUME_BUDGET (bytes).
+# buffering on a 16 GiB chip.
 _VOLUME_HBM_BUDGET = 4 * 1024**3
 
 
@@ -52,11 +52,10 @@ def resolve_corr_impl(corr_impl: str, n_pairs: int, h: int, w: int,
                       dtype=jnp.float32, n_devices: int = 1) -> str:
     """Resolve ``auto`` per frame geometry: the reference-default materialized
     volume while it fits, the O(H·W·D) on-demand GATHER path beyond
-    (``VFT_RAFT_ON_DEMAND_IMPL=matmul`` opts into the MXU volume remat once a
-    1080p TPU sweep justifies it — its FLOPs scale with frame area, the
-    gather's with the fixed window; see the big-frame comment below). In fp32
-    the paths agree to reduction-order ulps (~3e-3 px
-    through 20 iterations, a CPU comparison of an earlier round); under
+    (``--raft_corr on_demand_matmul`` asks for the MXU volume remat — its
+    FLOPs scale with frame area, the gather's with the fixed window; see the
+    big-frame comment below). In fp32 the paths agree to reduction-order ulps
+    (~3e-3 px through 20 iterations, a CPU comparison of an earlier round); under
     ``dtype=bfloat16`` the volume path stores a bf16 pyramid while the remat
     rounds the einsum inputs — the same one-bf16-rounding drift class,
     bounded in tests/test_flow_bf16.py.
@@ -68,36 +67,26 @@ def resolve_corr_impl(corr_impl: str, n_pairs: int, h: int, w: int,
     ``n_devices``: mesh size of the surrounding sharded step. Inside a jit the
     traced ``n_pairs`` is the GLOBAL pair count but each device materializes
     only its ``n_pairs / n_devices`` shard of the pyramid, so the budget
-    (``VFT_RAFT_VOLUME_BUDGET`` bytes, per device) is compared against the
+    (``_VOLUME_HBM_BUDGET`` bytes, per device) is compared against the
     per-device share — without it a mesh-sharded step near the boundary would
     needlessly take the ~40× slower on-demand path.
     """
     if corr_impl != "auto":
         return corr_impl
-    import os
-
-    budget = float(os.environ.get("VFT_RAFT_VOLUME_BUDGET", _VOLUME_HBM_BUDGET))
     q = (h // 8) * (w // 8)
     itemsize = 2 if dtype == jnp.bfloat16 else 4
     per_device_pairs = max(1, -(-n_pairs // max(n_devices, 1)))
     vol_bytes = per_device_pairs * q * q * itemsize * (1 + 1 / 4 + 1 / 16 + 1 / 64)
-    if vol_bytes <= budget:
+    if vol_bytes <= _VOLUME_HBM_BUDGET:
         return "volume"
     # past the budget, the GATHER formulation is the default (ADVICE r5
     # revert): the matmul remat's contraction FLOPs per query scale with the
     # level's hi·wi (quadratic in frame area) while the gather's scale with
     # the fixed 10×10 window, so the 3.2-3.6× win measured at 64×64 on CPU
     # can invert by ~300× more remat work at 1080p — exactly the regime auto
-    # selects this path. Flip back to matmul only on a committed 1080p TPU
-    # measurement (ROADMAP S5)
-    # (VFT_RAFT_ON_DEMAND_IMPL=matmul opts in per run meanwhile).
-    choice = os.environ.get("VFT_RAFT_ON_DEMAND_IMPL", "gather")
-    if choice not in ("gather", "matmul"):
-        # fail loudly like VFT_RAFT_VOLUME_BUDGET does — a typo'd revert
-        # that silently stayed on matmul would mislabel a measurement
-        raise ValueError(
-            f"VFT_RAFT_ON_DEMAND_IMPL must be gather|matmul, got {choice!r}")
-    return "on_demand" if choice == "gather" else "on_demand_matmul"
+    # selects this path. Flip to matmul only on a committed 1080p TPU
+    # measurement (ROADMAP S5); ``on_demand_matmul`` asks for it per run.
+    return "on_demand"
 
 # (name, cin, cout, kernel, stride, pad) for plain convs; residual layers described
 # structurally in _encoder below.
@@ -455,9 +444,9 @@ def raft_forward(params: Dict, image1: jnp.ndarray, image2: jnp.ndarray,
     outgrows HBM, see :func:`_build_f2_pyramid`; gather-bound, so it trades
     ~40× speed for that memory ceiling); ``on_demand_matmul`` keeps the
     memory ceiling but remats the volume slice per iteration on the MXU
-    instead of gathering (opt-in via ``VFT_RAFT_ON_DEMAND_IMPL=matmul``;
-    ``auto``'s big-frame choice is ``on_demand`` pending a 1080p TPU sweep —
-    see :func:`resolve_corr_impl` and :func:`_lookup_on_demand`).
+    instead of gathering (``auto``'s big-frame choice is ``on_demand``
+    pending a 1080p TPU sweep — see :func:`resolve_corr_impl` and
+    :func:`_lookup_on_demand`).
 
     ``taps``: debug-only dict filled with per-stage activations (fnet/cnet/corr/
     per-iteration flow) for the layer-diff parity harness (tools/layer_diff.py);

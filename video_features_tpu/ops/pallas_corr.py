@@ -37,6 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .warp import warp_backward
+
 CORR_RADIUS = 4
 CORR_CHANNELS = (2 * CORR_RADIUS + 1) ** 2  # 81
 
@@ -255,230 +257,17 @@ def _pallas_supported(h: int, w: int, c: int, itemsize: int = 4) -> bool:
 _KERNEL_DTYPES = (jnp.float32, jnp.bfloat16)
 
 
-# ---------------------------------------------------------------------------
-# Fused backward-warp + correlation (PWC decoder levels 5..2)
-#
-# The reference composes two CUDA stages: grid_sample-style backward warp of
-# fmap2 by the upsampled flow (pwc_net.py:23-41) then the 81-tap correlation
-# (correlation.py:44-112), materializing the warped fmap2 in HBM between them.
-# The XLA composition additionally lowers the warp's 4 corner gathers to
-# take_along_axis — scalar-unit bound on TPU (docs/architecture.md: the PWC
-# floor). This kernel does both in ONE VMEM pass per 16×16 output tile:
-#
-# - f2 (full image) and the zero-padded flow stay VMEM-resident per image;
-# - the 24×24 haloed warped tile is computed in-kernel: each bilinear corner
-#   is an EXACT one-hot selection matmul (rows have a single 1.0, so even a
-#   bf16 MXU pass reproduces the gathered value bit-for-bit) and the four
-#   fractional weights combine on the VPU — the TPU-native replacement for
-#   the gather (same trick as RAFT's measured 15.5× one-hot window lookup);
-# - the reference's partial-tap zeroing (warped ones-channel ≤ 0.999 → zero
-#   the pixel) falls out of the corner in-bounds weights, no extra pass;
-# - out-of-image halo positions get zero weights automatically, reproducing
-#   the correlation's zero padding;
-# - the 81 taps then run VMEM-resident exactly like _corr81_kernel_tiled.
-# ---------------------------------------------------------------------------
-
-
-def _halo_chunk_rows(hw: int) -> int:
-    """Halo rows per one-hot chunk: keep each (rows·24, H·W) fp32 selection
-    matrix under ~2 MB of VMEM; 24 = _TILE + 2·CORR_RADIUS halo rows total."""
-    halo = _TILE + 2 * CORR_RADIUS
-    for rows in (24, 12, 8, 6, 4, 3, 2, 1):
-        if rows * halo * hw * 4 <= 2 * 1024 * 1024:
-            return rows
-    return 1
-
-
-def _warp_corr81_kernel(f1_ref, f2_ref, flowp_ref, out_ref):
-    """Grid (b, nh, nw): one 16×16 output block per step.
-
-    f1 (1, T, T, C) block; f2 (1, H, W, C) full image (constant block index —
-    VMEM-resident); flowp (1, Hp+8, Wp+8, 2) full zero-padded scaled flow;
-    out (1, T, T, 81).
-    """
-    from jax.experimental import pallas as pl
-
-    j = pl.program_id(1)
-    k = pl.program_id(2)
-    r = CORR_RADIUS
-    halo = _TILE + 2 * r  # 24
-    _, h, w, c = f2_ref.shape
-    hw = h * w
-    f2_flat = f2_ref[0].reshape(hw, c)
-    exact = (jax.lax.Precision.HIGHEST if f2_flat.dtype == jnp.float32
-             else jax.lax.Precision.DEFAULT)  # bf16 selection is exact as-is
-    f1 = f1_ref[0].astype(jnp.float32)
-
-    hc = _halo_chunk_rows(hw)
-    chunks = []
-    for r0 in range(0, halo, hc):
-        rows = min(hc, halo - r0)
-        p = rows * halo
-        # global warped-image coordinates of this halo chunk (may be < 0 or
-        # ≥ H/W on the border tiles — those positions get zero weights below)
-        # int32 iota + cast: Mosaic's tpu.iota is integer-only
-        iy = jax.lax.broadcasted_iota(jnp.int32, (rows, halo), 0).astype(jnp.float32)
-        ix = jax.lax.broadcasted_iota(jnp.int32, (rows, halo), 1).astype(jnp.float32)
-        gy = (j * _TILE + r0 - r).astype(jnp.float32) + iy
-        gx = (k * _TILE - r).astype(jnp.float32) + ix
-        fl = flowp_ref[0, pl.dslice(j * _TILE + r0, rows),
-                       pl.dslice(k * _TILE, halo), :].astype(jnp.float32)
-        x = gx + fl[..., 0]
-        y = gy + fl[..., 1]
-        x0 = jnp.floor(x)
-        y0 = jnp.floor(y)
-        wx = x - x0
-        wy = y - y0
-        acc = jnp.zeros((rows, halo, c), jnp.float32)
-        ones_acc = jnp.zeros((rows, halo), jnp.float32)
-        # NB Mosaic reshape rule: only reshapes that PRESERVE the minor (lane)
-        # dim compile on this backend — (rows, halo, hw)→(p, hw) and
-        # (p, c)→(rows, halo, c) are fine, (rows, halo)→(p, 1) is not.
-        iota3 = jax.lax.broadcasted_iota(jnp.int32, (rows, halo, hw), 2)
-        for dy, dx, wgt in ((0, 0, (1 - wy) * (1 - wx)), (0, 1, (1 - wy) * wx),
-                            (1, 0, wy * (1 - wx)), (1, 1, wy * wx)):
-            xi = x0 + dx
-            yi = y0 + dy
-            inb = ((xi >= 0) & (xi <= w - 1) & (yi >= 0) & (yi <= h - 1))
-            idx = (jnp.clip(yi, 0, h - 1) * w + jnp.clip(xi, 0, w - 1)
-                   ).astype(jnp.int32)
-            onehot = (idx[:, :, None] == iota3).astype(f2_flat.dtype)
-            sel = jax.lax.dot_general(
-                onehot.reshape(p, hw), f2_flat, (((1,), (0,)), ((), ())),
-                precision=exact, preferred_element_type=jnp.float32)
-            wgt_eff = wgt * inb.astype(jnp.float32)
-            acc = acc + wgt_eff[:, :, None] * sel.reshape(rows, halo, c)
-            ones_acc = ones_acc + wgt_eff
-        # reference partial-tap zeroing: any out-of-bounds leakage (sampled
-        # ones ≤ 0.999) zeroes the whole pixel (pwc_net.py:36-40)
-        keep = (ones_acc > 0.999).astype(jnp.float32)
-        chunks.append(acc * keep[:, :, None])
-    warped = jnp.concatenate(chunks, axis=0)  # (24, 24, C) fp32
-
-    taps = []
-    for dy in range(2 * r + 1):
-        for dx in range(2 * r + 1):
-            shifted = warped[dy : dy + _TILE, dx : dx + _TILE, :]
-            taps.append(jnp.sum(f1 * shifted, axis=-1) * (1.0 / c))
-    out_ref[0] = jnp.stack(taps, axis=-1).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def warp_corr81_pallas(f1: jnp.ndarray, f2: jnp.ndarray, flow: jnp.ndarray,
-                       interpret: bool = False) -> jnp.ndarray:
-    """Fused ``corr81(f1, warp_backward(f2, flow))`` — flow already scaled.
-
-    Pads H/W to tile multiples (padded f1 rows produce sliced-off outputs;
-    padded flow/out-of-image warp targets get zero weights in-kernel, which
-    IS the correlation's zero padding + the warp's border zeroing).
-    """
-    from jax.experimental import pallas as pl
-
-    b, h, w, c = f1.shape
-    r = CORR_RADIUS
-    ph = (-h) % _TILE
-    pw = (-w) % _TILE
-    hp, wp = h + ph, w + pw
-    f1p = jnp.pad(f1, ((0, 0), (0, ph), (0, pw), (0, 0)))
-    flowp = jnp.pad(flow.astype(jnp.float32),
-                    ((0, 0), (r, r + ph), (r, r + pw), (0, 0)))
-    out = pl.pallas_call(
-        _warp_corr81_kernel,
-        out_shape=_out_struct((b, hp, wp, CORR_CHANNELS), f1, f2, flow),
-        grid=(b, hp // _TILE, wp // _TILE),
-        in_specs=[
-            pl.BlockSpec((1, _TILE, _TILE, c), lambda i, j, k: (i, j, k, 0)),
-            pl.BlockSpec((1, h, w, c), lambda i, j, k: (i, 0, 0, 0)),
-            pl.BlockSpec((1, hp + 2 * r, wp + 2 * r, 2),
-                         lambda i, j, k: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, _TILE, _TILE, CORR_CHANNELS),
-                               lambda i, j, k: (i, j, k, 0)),
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-        name="pwc_warp_corr81_fused",
-    )(f1p, f2, flowp)
-    return out[:, :h, :w, :]
-
-
-# One-hot width the fused kernel is admitted at. Every halo pixel selects among
-# all h·w source pixels, so the selection matmuls grow with (h·w)². Compiled for
-# v5e at the four coarse level shapes (4×6×196 … 32×48×64) in 2–12 s in both
-# dtypes; at the finest (64×96×32) the fp32 compile takes 76 s and overran the
-# default limit ("Scoped allocation with size 22.75M and limit 16.00M"), so
-# that level stays with the composition.
-_FUSED_MAX_HW = 2048
-
-
-def _warp_corr_supported(h: int, w: int, c: int, itemsize: int) -> bool:
-    """Gate for the fused kernel: the one-hot width cap, then VMEM — resident
-    f2 + padded flow + f1/out blocks (double-buffered by the pipeline), the
-    one-hot chunk with its int32 iota comparand, the warped halo tile, and
-    Mosaic's own scratch."""
-    if h * w > _FUSED_MAX_HW:
-        return False
-    if itemsize < 4 and w % 2:
-        # bf16 packs two rows per sublane, and the kernel's (h, w, c) →
-        # (h·w, c) collapse of f2 is refused at odd widths (level 6 of a
-        # 320-wide grid): "infer-vector-layout: unsupported shape cast …
-        # vector<1x5x5x196xbf16> -> vector<25x196xbf16>"
-        return False
-    r = CORR_RADIUS
-    hp = h + (-h) % _TILE
-    wp = w + (-w) % _TILE
-    halo = _TILE + 2 * r
-    blocks = (_vmem_bytes((h, w, c), itemsize)
-              + _vmem_bytes((hp + 2 * r, wp + 2 * r, 2), 4)
-              + _vmem_bytes((_TILE, _TILE, c), itemsize)
-              + _vmem_bytes((_TILE, _TILE, CORR_CHANNELS), itemsize))
-    onehot = (_halo_chunk_rows(h * w) * halo, h * w)
-    work = (_vmem_bytes(onehot, itemsize) + _vmem_bytes(onehot, 4)
-            + _vmem_bytes((halo, halo, c), 4))
-    return 2 * blocks + work + _VMEM_INTERNAL <= _VMEM_LIMIT
-
-
-def _fused_enabled() -> bool:
-    """The fused kernel runs only under ``VFT_FUSED_WARP_CORR=1``: no
-    measurement on this installation sets it against the composition with the
-    Pallas volume kernels, which is what ``auto`` takes (ROADMAP S4 decides;
-    the loser goes)."""
-    import os
-
-    return os.environ.get("VFT_FUSED_WARP_CORR") == "1"
-
-
 def warp_corr81(f1: jnp.ndarray, f2: jnp.ndarray, flow: jnp.ndarray,
-                impl: str = "xla", warp_impl: str = "auto",
-                level: str = "") -> jnp.ndarray:
-    """Backward-warp ``f2`` by ``flow`` (already level-scaled) and correlate.
-
-    ``impl`` — ``xla``: the two-stage composition (warp → fused-XLA volume).
-    ``auto``/``pallas``: the fused kernel where it is enabled and its gate
-    admits the shape; otherwise the composition with ``corr81(impl)`` — the
-    Pallas volume kernels where they run, and under ``pallas`` an error where
-    they do not. ``pallas_interpret``: fused kernel in the Pallas interpreter
-    (CPU tests).
-
-    ``warp_impl`` — the composition's warp lowering: ``gather`` | ``onehot``
-    (MXU selector matmuls, ops/warp.bilinear_sample_onehot) | ``auto``
-    (VFT_WARP_IMPL, unset → gather).
+                impl: str = "xla", level: str = "") -> jnp.ndarray:
+    """Backward-warp ``f2`` by ``flow`` (already level-scaled) and correlate:
+    :func:`~video_features_tpu.ops.warp.warp_backward`, then
+    :func:`corr81` under ``impl``.
 
     ``level`` names the device scopes a profiler trace shows the work under:
-    ``pwc/warp<level>`` and ``pwc/corr<level>`` (the fused kernel is both, and
-    sits under the second).
+    ``pwc/warp<level>`` and ``pwc/corr<level>``.
     """
-    from .warp import warp_backward
-
-    with jax.named_scope(f"pwc/corr{level}"):
-        if impl == "pallas_interpret":
-            return warp_corr81_pallas(f1, f2, flow, interpret=True)
-        if impl in ("pallas", "auto") and _fused_enabled() \
-                and jax.default_backend() == "tpu" and f1.dtype in _KERNEL_DTYPES:
-            _, h, w, c = f1.shape
-            if _warp_corr_supported(h, w, c, jnp.dtype(f1.dtype).itemsize):
-                return warp_corr81_pallas(f1, f2, flow)
     with jax.named_scope(f"pwc/warp{level}"):
-        warped = warp_backward(f2, flow, warp_impl)
+        warped = warp_backward(f2, flow)
     with jax.named_scope(f"pwc/corr{level}"):
         return corr81(f1, warped, impl)
 

@@ -10,15 +10,12 @@ align_corners=True is the identity — keeps the math exact and avoids the (W−
 rescaling noise.
 
 All shapes static. What still gathers is what samples at data: the warp's
-corner taps (:func:`bilinear_sample`; on a TPU they run on the scalar unit,
-hence :func:`bilinear_sample_onehot`) and RAFT's lookup (models/raft.py).
+corner taps (:func:`bilinear_sample`; on a TPU they run on the scalar unit)
+and RAFT's lookup (models/raft.py).
 :func:`resize_bilinear_torch` samples at compile-time constants and does not.
 """
 
 from __future__ import annotations
-
-import math
-import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -68,135 +65,32 @@ def equalize_chunks(n: int, cap: int) -> tuple[int, int, int]:
     ``n_chunks · chunk = n + pad``. Equalized (vs bare ceil-capping) so an
     unlucky ``n``/``cap`` ratio cannot nearly double the padded tail's work
     (e.g. n=4096, cap=3787 → two 3787-chunks would be 45 % padding; this
-    yields two 2048-chunks). Shared by every budget-chunked query loop
-    (the one-hot warp here, RAFT's on-demand matmul lookup)."""
+    yields two 2048-chunks). RAFT's on-demand matmul lookup chunks its
+    queries by it."""
     cap = max(1, min(n, cap))
     n_chunks = -(-n // cap)
     chunk = -(-n // n_chunks)
     return n_chunks, chunk, n_chunks * chunk - n
 
 
-def bilinear_sample_onehot(img: jnp.ndarray, coords_xy: jnp.ndarray,
-                           chunk_budget: int = 8_000_000) -> jnp.ndarray:
-    """:func:`bilinear_sample` on the MXU — weighted one-hot selector matmuls
-    instead of corner gathers.
-
-    Bilinear interpolation is separable: with ``Sy[p, i] = (1−fy)·[i = y0] +
-    fy·[i = y0+1]`` (two adjacent nonzeros per row) and ``Sx`` likewise,
-    ``out[p] = Σ_j Sx[p, j] · (Σ_i Sy[p, i] · img[i, j])``. TPU gathers run
-    on the scalar unit (the measured PWC floor — docs/architecture.md
-    "Data-dependent addressing"); the selector formulation pays
-    O(P·H·W·C) MXU MACs instead, the same trade that won 15.5× on RAFT's
-    volume lookup (models/raft.py). Zero-padding semantics come for free:
-    an out-of-bounds tap index never matches the iota, so its selector row
-    weight is zero — identical to grid_sample padding_mode='zeros'
-    per-corner masking (the exact per-corner mask: corner (dy, dx) survives
-    iff BOTH its row and column are in range).
-
-    Numerics: products are exact (HIGHEST for fp32; bf16 inputs widen into
-    an fp32 accumulator); the 4-corner sum associates as
-    (vertical lerp) → (horizontal lerp) instead of the gather path's flat
-    Σ wᵢ·vᵢ — differences are ≤ 1 ulp of the gather result.
-
-    The (P, W, C) row intermediate is bounded by chunking the query axis to
-    ``chunk_budget`` elements per batch element (lax.map over chunks, so one
-    buffer is live at a time). Returns (N, P, Q, C) float32.
-    """
-    n, h, w, c = img.shape
-    p_shape = coords_xy.shape[1:-1]
-    q = int(math.prod(p_shape)) if p_shape else 1
-    x = coords_xy[..., 0].reshape(n, q).astype(jnp.float32)
-    y = coords_xy[..., 1].reshape(n, q).astype(jnp.float32)
-    y0f = jnp.floor(y)
-    x0f = jnp.floor(x)
-    fy = y - y0f
-    fx = x - x0f
-    # int32 tap indices; values far outside [−1, max] simply never match the
-    # iota (clip to a sentinel to keep the float→int cast defined for the
-    # padded/degenerate coords a static-shape pipeline can produce)
-    iy0 = jnp.clip(y0f, -2, h + 1).astype(jnp.int32)
-    ix0 = jnp.clip(x0f, -2, w + 1).astype(jnp.int32)
-
-    bf16 = img.dtype == jnp.bfloat16
-    sel_dtype = jnp.bfloat16 if bf16 else jnp.float32
-    imgf = img if bf16 else img.astype(jnp.float32)
-    prec = lax.Precision.DEFAULT if bf16 else lax.Precision.HIGHEST
-
-    # chunk the query axis: the (n, chunk, w, c) row intermediate is the
-    # peak buffer; hold it to ~chunk_budget elements per batch element
-    n_chunks, chunk, pad = equalize_chunks(q, chunk_budget // max(w * c, 1))
-
-    def prep(a):
-        a = jnp.pad(a, ((0, 0), (0, pad)))
-        return a.reshape(n, n_chunks, chunk).transpose(1, 0, 2)
-
-    iota_h = jnp.arange(h, dtype=jnp.int32)
-    iota_w = jnp.arange(w, dtype=jnp.int32)
-
-    def body(args):
-        iy0c, fyc, ix0c, fxc = args  # each (n, chunk)
-        sy = ((iy0c[..., None] == iota_h) * (1 - fyc)[..., None]
-              + ((iy0c + 1)[..., None] == iota_h) * fyc[..., None])
-        sx = ((ix0c[..., None] == iota_w) * (1 - fxc)[..., None]
-              + ((ix0c + 1)[..., None] == iota_w) * fxc[..., None])
-        rows = jnp.einsum("npi,nijc->npjc", sy.astype(sel_dtype), imgf,
-                          precision=prec, preferred_element_type=jnp.float32)
-        return jnp.einsum("npj,npjc->npc", sx.astype(sel_dtype), rows,
-                          precision=prec, preferred_element_type=jnp.float32)
-
-    out = lax.map(body, (prep(iy0), prep(fy), prep(ix0), prep(fx)))
-    out = out.transpose(1, 0, 2, 3).reshape(n, n_chunks * chunk, c)[:, :q]
-    return out.reshape((n,) + p_shape + (c,))
-
-
-def warp_backward(img: jnp.ndarray, flow: jnp.ndarray,
-                  impl: str | None = None) -> jnp.ndarray:
+def warp_backward(img: jnp.ndarray, flow: jnp.ndarray) -> jnp.ndarray:
     """PWC backward warp: sample ``img`` at ``base + flow``, zeroing partial taps.
 
     Reference semantics (``pwc_net.py:23-41``): a ones channel rides along; where its
     sampled value is ≤ 0.999 (any out-of-bounds leakage) the whole output pixel is
     zeroed, otherwise scaled by exactly 1.0.
 
-    ``impl``: ``gather`` (the take_along_axis corner taps) or ``onehot``
-    (:func:`bilinear_sample_onehot`, MXU selector matmuls). When None or
-    ``auto``, ``VFT_WARP_IMPL`` selects (unset → gather).
-
     ``img`` (N, H, W, C); ``flow`` (N, H, W, 2) in pixels (u, v). Returns (N, H, W, C).
     """
-    if impl is None or impl == "auto":
-        impl = os.environ.get("VFT_WARP_IMPL", "gather")
     n, h, w, _ = flow.shape
     ys, xs = jnp.meshgrid(jnp.arange(h, dtype=jnp.float32),
                           jnp.arange(w, dtype=jnp.float32), indexing="ij")
     base = jnp.stack([xs, ys], axis=-1)[None]
     coords = base + flow
-    if impl not in ("gather", "onehot"):
-        raise ValueError(f"warp impl must be gather|onehot, got {impl!r}")
-    if impl == "onehot":
-        # the mask is separable — Σ inb(corner)·w(corner) =
-        # (Σᵢ iny·wyᵢ)(Σⱼ inx·wxⱼ) — so compute it closed-form in fp32
-        # instead of riding a ones channel through the (possibly bf16)
-        # selector matmuls, where weight rounding (~2⁻⁹) straddles the
-        # 0.999 keep-threshold and randomly zeroes interior pixels
-        out = bilinear_sample_onehot(img, coords)
-        x = coords[..., 0].astype(jnp.float32)
-        y = coords[..., 1].astype(jnp.float32)
-        fy = y - jnp.floor(y)
-        fx = x - jnp.floor(x)
-        y0 = jnp.floor(y)
-        x0 = jnp.floor(x)
-
-        def axis_w(a0, fa, hi):
-            in0 = ((a0 >= 0) & (a0 <= hi - 1)).astype(jnp.float32)
-            in1 = ((a0 + 1 >= 0) & (a0 + 1 <= hi - 1)).astype(jnp.float32)
-            return in0 * (1 - fa) + in1 * fa
-
-        mask = (axis_w(y0, fy, h) * axis_w(x0, fx, w))[..., None]
-    else:
-        ones = jnp.ones(img.shape[:-1] + (1,), jnp.float32)
-        sampled = bilinear_sample(
-            jnp.concatenate([img.astype(jnp.float32), ones], -1), coords)
-        out, mask = sampled[..., :-1], sampled[..., -1:]
+    ones = jnp.ones(img.shape[:-1] + (1,), jnp.float32)
+    sampled = bilinear_sample(
+        jnp.concatenate([img.astype(jnp.float32), ones], -1), coords)
+    out, mask = sampled[..., :-1], sampled[..., -1:]
     keep = (mask > 0.999).astype(jnp.float32)
     return out * keep
 

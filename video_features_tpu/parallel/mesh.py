@@ -64,7 +64,7 @@ def sharded_apply(mesh: Mesh, fn: Callable, n_batch_args: int = 1,
 
     Each batch argument's leading axis must be divisible by the mesh size — callers
     round their batch size up via :meth:`MeshRunner.device_batch` and zero-pad the
-    tail (:func:`video_features_tpu.extractors.base.pad_batch`). Output shardings
+    tail (:func:`video_features_tpu.parallel.pipeline.pad_batch`). Output shardings
     are left to XLA (batch-preserving steps keep rows sharded; ``np.asarray``
     gathers them to host).
 

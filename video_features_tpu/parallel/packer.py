@@ -91,6 +91,7 @@ import numpy as np
 from ..io.output import FeatureAssembly
 from ..reliability.faults import fault_point
 from ..utils.metrics import span as bare_span
+from .pipeline import pad_batch
 from .pages import (TABLE_COLS, TOKEN_PLANES, build_row_table,
                     build_token_page, fit_documents)
 
@@ -344,7 +345,7 @@ class CorpusPacker:
         self.real_slots = 0  # clips dispatched
         self.dispatched_slots = 0  # clips + padding/boundary slots dispatched
         self.staged_bytes = 0  # host bytes staged per dispatched device batch
-        self.pages_dispatched = 0  # paged-mode dispatches (bench/stats)
+        self.pages_dispatched = 0  # paged-mode dispatches (stats)
         self.segments = 0  # token pages: table rows (segments) dispatched
         self.max_in_flight = 0  # deepest observed in-flight ring (any key)
         self.video_clips: Dict[str, int] = {}  # per finished video
@@ -632,8 +633,7 @@ class CorpusPacker:
                                     bucket=self._bucket_name(key))
             if paged:
                 # the page-level win (real rows / page rows, cumulative per
-                # bucket): pad waste beyond the final partial page shows up
-                # here before it shows up in the bench
+                # bucket): pad waste beyond the final partial page shows here
                 self._metrics.set_gauge("page_occupancy", occ,
                                         bucket=self._bucket_name(key))
 
@@ -670,8 +670,6 @@ class CorpusPacker:
         batch shape) into a reusable staging-ring buffer when a ring is
         wired, else the original fresh ``np.stack`` + ``pad_batch``. Dtype
         follows the clips — uint8 frame slots stay uint8 on the wire."""
-        from ..extractors.base import pad_batch  # runtime: avoids an import cycle
-
         if self._staging is None:
             return pad_batch(np.stack(clips), batch_size)
         return self._staging.stage(clips, batch_size)

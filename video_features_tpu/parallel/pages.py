@@ -45,7 +45,7 @@ attended to, routed or averaged, which only the model can see to).
 
 Host scatter never reads the table (slots carry their assembly references —
 slot-level fault attribution is unchanged); the table is the device-side
-contract plus the journal/bench's occupancy ground truth.
+contract plus the journal's and the benchmark's occupancy ground truth.
 """
 
 from __future__ import annotations

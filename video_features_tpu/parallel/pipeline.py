@@ -528,6 +528,18 @@ class DecodePrefetcher:
         self._handed.clear()
 
 
+def pad_batch(arr: np.ndarray, batch_size: int) -> np.ndarray:
+    """Zero-pad the leading axis to ``batch_size`` (static shapes: one XLA compile
+    per geometry instead of one per partial tail batch)."""
+    n = arr.shape[0]
+    if n == batch_size:
+        return arr
+    if n > batch_size:
+        raise ValueError(f"batch of {n} exceeds batch_size {batch_size}")
+    pad = np.zeros((batch_size - n,) + arr.shape[1:], arr.dtype)
+    return np.concatenate([arr, pad], axis=0)
+
+
 class HostStagingRing:
     """Reusable host staging buffers for ``device_put`` sources.
 
