@@ -196,9 +196,7 @@ def test_environment_reads_are_deployment_and_debugging_settings_only():
     the cache's pin, the multi-host switch, and the metrics and fault hooks.
     A lowering is chosen from what the code can observe (backend, dtype,
     shape) or by a flag — never from the environment, where no configuration,
-    cache key or benchmark cell can see it. One such switch is left of six:
-    ``VFT_I3D_TAP_FP32``, whose alternative read faster on the chip (PERF.md
-    §6, PR 36) and which goes when ROADMAP S4 makes that lowering the only one."""
+    cache key or benchmark cell can see it."""
     package = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "video_features_tpu")
     named = {}
@@ -222,5 +220,4 @@ def test_environment_reads_are_deployment_and_debugging_settings_only():
         "VFT_MULTIHOST": {"parallel/pipeline.py"},
         "VFT_METRICS": {"utils/metrics.py"},
         "VFT_FAULTS": {"reliability/faults.py"},
-        "VFT_I3D_TAP_FP32": {"models/layers.py"},
     }

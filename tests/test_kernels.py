@@ -2,7 +2,7 @@
 
 Covers the production paths a slow-marked file would hide from the default
 run: the spatially tiled Pallas cost-volume kernel (interpret mode) and the
-bf16 TapConv3D lowering every bf16 I3D conv takes.
+TapConv3D lowering every bias-free I3D conv takes, in both dtypes.
 """
 # fast-registry: default tier — kernel parity vs torch mirrors
 
@@ -28,91 +28,124 @@ def test_corr81_pallas_tiled_matches_xla():
         np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 
-def test_tap_conv3d_matches_direct_conv():
-    """The bf16 tap lowering must equal nn.Conv's conv3d (same TF-SAME pads);
-    checked in fp32 where equality is tight (bf16 only reassociates further)."""
+# I3D's real shape classes: the two stems (3 and 2 input channels), the widest
+# and the narrowest 3×3×3 of mixed_3b, a 1×1×1, and odd sizes for the pads
+@pytest.mark.parametrize("kernel,stride,cin,cout,thw", [
+    pytest.param((7, 7, 7), (2, 2, 2), 3, 64, (8, 16, 16), id="stem7x7x7-rgb"),
+    pytest.param((7, 7, 7), (2, 2, 2), 2, 64, (8, 16, 16), id="stem7x7x7-flow"),
+    pytest.param((3, 3, 3), (1, 1, 1), 96, 128, (4, 7, 7), id="3x3x3-96to128"),
+    pytest.param((3, 3, 3), (1, 1, 1), 16, 32, (4, 7, 7), id="3x3x3-16to32"),
+    pytest.param((1, 1, 1), (1, 1, 1), 192, 64, (4, 7, 7), id="1x1x1-192to64"),
+    pytest.param((7, 7, 7), (2, 2, 2), 4, 6, (8, 12, 12), id="7x7x7-small"),
+    pytest.param((3, 3, 3), (1, 1, 1), 4, 6, (7, 13, 13), id="3x3x3-odd-sizes"),
+])
+def test_tap_conv3d_matches_direct_conv(kernel, stride, cin, cout, thw):
+    """The tap lowering must equal nn.Conv's conv3d (same kernel, same
+    TF-SAME pads) in float32, where equality is tight (bfloat16 only
+    reassociates further)."""
     import flax.linen as fnn
 
     from video_features_tpu.models.layers import TapConv3D, tf_same_pads
 
     rng = np.random.default_rng(3)
-    for kernel, stride in (((7, 7, 7), (2, 2, 2)), ((3, 3, 3), (1, 1, 1)),
-                           ((1, 1, 1), (1, 1, 1))):
-        x = jnp.asarray(rng.standard_normal((2, 8, 12, 12, 4)).astype(np.float32))
-        tap = TapConv3D(6, kernel, stride, dtype=jnp.float32)
-        params = tap.init(jax.random.PRNGKey(0), x)
-        out = tap.apply(params, x)
-        kern = params["params"]["kernel"]
-        ref = fnn.Conv(6, kernel, strides=stride,
-                       padding=tf_same_pads(kernel, stride), use_bias=False,
-                       dtype=jnp.float32).apply({"params": {"kernel": kern}}, x)
-        assert out.shape == ref.shape
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
+    x = jnp.asarray(rng.standard_normal((2, *thw, cin)).astype(np.float32))
+    tap = TapConv3D(cout, kernel, stride, dtype=jnp.float32)
+    params = tap.init(jax.random.PRNGKey(0), x)
+    assert params["params"]["kernel"].shape == (*kernel, cin, cout)
+    out = tap.apply(params, x)
+    ref = fnn.Conv(cout, kernel, strides=stride,
+                   padding=tf_same_pads(kernel, stride), use_bias=False,
+                   dtype=jnp.float32).apply(params, x)
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
-def test_tap_fp32_flag_routes_joint_extent_only(monkeypatch):
-    """VFT_I3D_TAP_FP32=1: fp32 convs with joint spatio-temporal extent take
-    the tap lowering (same numerics to ~1e-6); factored kernels stay direct."""
-    import flax.linen as fnn
-
-    from video_features_tpu.models.layers import TapConv3D, conv3d_module
-
-    monkeypatch.setenv("VFT_I3D_TAP_FP32", "1")
-    pads = ((1, 1), (1, 1), (1, 1))
-    joint = conv3d_module(6, (3, 3, 3), (1, 1, 1), pads, jnp.float32, "c")
-    assert isinstance(joint, TapConv3D)
-    factored = conv3d_module(6, (3, 1, 1), (1, 1, 1),
-                             ((1, 1), (0, 0), (0, 0)), jnp.float32, "c")
-    assert isinstance(factored, fnn.Conv)
-    monkeypatch.delenv("VFT_I3D_TAP_FP32")
-    off = conv3d_module(6, (3, 3, 3), (1, 1, 1), pads, jnp.float32, "c")
-    assert isinstance(off, fnn.Conv)
-
-    # full-model numerics under the flag: same params, ~fp32-tight agreement
-    monkeypatch.setenv("VFT_I3D_TAP_FP32", "1")
-    from video_features_tpu.models.i3d import I3D
-
-    rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.uniform(-1, 1, (1, 16, 32, 32, 3)).astype(np.float32))
-    model = I3D(modality="rgb")
-    params = model.init(jax.random.PRNGKey(0), x, features=True)
-    tap_out = np.asarray(model.apply(params, x, features=True))
-    monkeypatch.delenv("VFT_I3D_TAP_FP32")
-    ref_out = np.asarray(model.apply(params, x, features=True))
-    np.testing.assert_allclose(tap_out, ref_out, rtol=1e-4, atol=1e-5)
+def _conv_ranks(fn, *args):
+    """Operand rank of every ``conv_general_dilated`` in ``fn``'s jaxpr."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "conv_general_dilated":
+                yield eqn.invars[0].aval.ndim
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
 
 
-def test_tap_conv3d_explicit_pads_match_direct_conv():
-    """The explicit-padding branch (torch-style R21D pads, incl. asymmetric)
-    at the tight kernel-level tolerance — the end-to-end 5% feature test could
-    absorb a boundary-only lo/hi swap."""
-    import flax.linen as fnn
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kernel,stride,cin", [
+    pytest.param((7, 7, 7), (2, 2, 2), 3, id="7x7x7s2"),
+    pytest.param((3, 3, 3), (1, 1, 1), 16, id="3x3x3"),
+    pytest.param((1, 1, 1), (1, 1, 1), 16, id="1x1x1"),
+])
+def test_unit3d_lowers_every_shape_class_as_temporal_taps(kernel, stride, cin, dtype):
+    """One lowering per shape class, chosen from nothing but the kernel's
+    shape, the same for float32 and bfloat16 and on every backend: a
+    bias-free I3D convolution is ``kt`` conv2ds over (N·T, H, W, C) and no
+    conv3d (PERF.md §6, PR 37: the chip preferred it for every class). The
+    parameter stays ``conv3d/kernel`` in nn.Conv's layout, so converted
+    checkpoints load as before."""
+    from video_features_tpu.models.i3d import Unit3D
 
-    from video_features_tpu.models.layers import TapConv3D
+    unit = Unit3D(8, kernel, stride, dtype=dtype)
+    x = jnp.ones((1, 8, 12, 12, cin), jnp.float32)
+    params = unit.init(jax.random.PRNGKey(0), x)
+    assert params["params"]["conv3d"]["kernel"].shape == (*kernel, cin, 8)
+    assert params["params"]["conv3d"]["kernel"].dtype == jnp.float32
+    assert _conv_ranks(lambda p, v: unit.apply(p, v), params, x) == [4] * kernel[0]
 
-    rng = np.random.default_rng(5)
-    cases = (
-        ((1, 7, 7), (1, 2, 2), ((0, 0), (3, 3), (3, 3))),  # r21d stem
-        ((3, 1, 1), (2, 1, 1), ((1, 1), (0, 0), (0, 0))),  # strided temporal
-        ((3, 3, 3), (1, 1, 1), ((0, 1), (1, 2), (2, 0))),  # asymmetric pads
-    )
-    for kernel, stride, pads in cases:
-        x = jnp.asarray(rng.standard_normal((2, 7, 13, 13, 4)).astype(np.float32))
-        tap = TapConv3D(6, kernel, stride, dtype=jnp.float32, padding=pads)
-        params = tap.init(jax.random.PRNGKey(1), x)
-        out = tap.apply(params, x)
-        kern = params["params"]["kernel"]
-        ref = fnn.Conv(6, kernel, strides=stride, padding=pads, use_bias=False,
-                       dtype=jnp.float32).apply({"params": {"kernel": kern}}, x)
-        assert out.shape == ref.shape, (kernel, stride, pads)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
+
+def test_i3d_biased_logits_head_keeps_the_direct_conv():
+    """The one biased convolution (the logits head, outside the feature
+    path) is nn.Conv's conv3d with its bias, as the checkpoint has it."""
+    from video_features_tpu.models.i3d import Unit3D
+
+    head = Unit3D(5, use_bn=False, use_bias=True, relu=False)
+    x = jnp.ones((1, 3, 1, 1, 16), jnp.float32)
+    params = head.init(jax.random.PRNGKey(0), x)
+    assert set(params["params"]["conv3d"]) == {"kernel", "bias"}
+    assert _conv_ranks(lambda p, v: head.apply(p, v), params, x) == [5]
+
+
+@pytest.mark.parametrize("modality,cin", [("rgb", 3), ("flow", 2)])
+def test_i3d_bf16_features_are_the_all_taps_composition(modality, cin):
+    """``--dtype bfloat16`` took the taps for every convolution before
+    float32 did, and PR 37 must not move its features: the model is 101
+    conv2ds and no conv3d, its parameter tree is the float32 model's, and the
+    stem unit is bit for bit TapConv3D → BatchNorm → ReLU."""
+    from video_features_tpu.models.i3d import I3D, Unit3D
+    from video_features_tpu.models.layers import TapConv3D, TorchBatchNorm
+
+    x = jnp.asarray(np.random.default_rng(4).uniform(-1, 1, (1, 16, 32, 32, cin))
+                    .astype(np.float32))
+    shapes = {}
+    for name, dtype in (("float32", jnp.float32), ("bfloat16", jnp.bfloat16)):
+        model = I3D(modality=modality, dtype=dtype)
+        tree = jax.eval_shape(lambda r, v: model.init(r, v, features=True),
+                              jax.random.PRNGKey(0), x)
+        shapes[name] = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), tree)
+        ranks = _conv_ranks(lambda p, v: model.apply(p, v, features=True), tree, x)
+        # 7 stem taps + 1 + 3 + nine blocks of four 1×1×1 and two 3×3×3
+        assert ranks == [4] * (7 + 1 + 3 + 9 * (4 + 2 * 3))
+    assert shapes["float32"] == shapes["bfloat16"]
+    assert shapes["bfloat16"]["params"]["conv3d_1a_7x7"]["conv3d"]["kernel"][0] == \
+        (7, 7, 7, cin, 64)
+
+    unit = Unit3D(64, (7, 7, 7), (2, 2, 2), dtype=jnp.bfloat16)
+    params = unit.init(jax.random.PRNGKey(1), x)["params"]
+    conv = TapConv3D(64, (7, 7, 7), (2, 2, 2), dtype=jnp.bfloat16).apply(
+        {"params": params["conv3d"]}, x)
+    by_hand = jax.nn.relu(TorchBatchNorm(dtype=jnp.bfloat16).apply(
+        {"params": params["batch3d"]}, conv))
+    out = unit.apply({"params": params}, x)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                  np.asarray(by_hand.astype(jnp.float32)))
 
 
 def test_i3d_bf16_tap_path_close_to_fp32():
-    """dtype=bfloat16 now routes convs through TapConv3D; features must stay
-    near the fp32 model (same params)."""
+    """dtype=bfloat16 through the same TapConv3D lowering as float32;
+    features must stay near the fp32 model (same params)."""
     from video_features_tpu.models.i3d import I3D
     from video_features_tpu.weights.store import random_params_like
 

@@ -1527,23 +1527,29 @@ def test_sources_parsed_once_per_run(monkeypatch):
     assert multi == {}, f"re-parsed per rule: {multi}"
 
 
-def test_full_run_wall_clock_budget():
-    """The full 13-rule suite stays within ~25% over the measured baseline
-    (~3.5 s on this class of machine after the dataflow rules landed) — the
-    budget guards against O(files x rules) parse regressions and against a
-    new interprocedural pass quietly re-deriving the shared analyses, not
-    against small constant cost. Best-of-3 so a loaded machine measures the
-    lint, not the contention."""
-    import time
+def test_full_run_work_budget():
+    """The full 13-rule suite stays within ~25% over the measured baseline of
+    its work, counted as Python function calls (12.3 M on the tree of PR 37)
+    — the budget guards against O(files x rules) parse regressions and against
+    a new interprocedural pass quietly re-deriving the shared analyses, not
+    against small constant cost. A count, not a clock: the same lint read
+    2.9–4.8 s on one otherwise idle machine, and the 4.5 s best-of-three pin
+    this replaces failed under the driver's six workers."""
+    calls = 0
 
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        run_lint(REPO)
-        best = min(best, time.perf_counter() - t0)
-        if best < 4.5:
-            break
-    assert best < 4.5
+    def count(_frame, event, _arg):
+        nonlocal calls
+        if event == "call":
+            calls += 1
+
+    previous = sys.getprofile()
+    sys.setprofile(count)
+    try:
+        findings = run_lint(REPO)
+    finally:
+        sys.setprofile(previous)
+    assert findings == []
+    assert calls < 15_500_000
 
 
 def test_changed_mode_single_file_is_fast(monkeypatch):

@@ -12,8 +12,9 @@ Behavioral spec — ``/root/reference/models/i3d/i3d_src/i3d_net.py``:
 - ``modality``: 'rgb' (3 input channels) or 'flow' (2) (``:170-176``).
 
 TPU design: channel-last NDHWC so every conv lands on the MXU with native tiling;
-the asymmetric SAME pads are explicit ``lax.conv_general_dilated`` padding (no
-separate pad op to fuse away); the architecture is one spec table walked by
+every bias-free conv3d is a sum of per-temporal-tap conv2ds (``layers.TapConv3D``:
+the asymmetric SAME pads are explicit ``lax.conv_general_dilated`` padding in
+space and one zero pad in time); the architecture is one spec table walked by
 ``nn.compact`` — module names match the reference state_dict so checkpoint
 conversion is a pure name/layout map.
 """
@@ -27,9 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from .layers import (
+    TapConv3D,
     TorchBatchNorm,
     avg_pool_valid,
-    conv3d_module,
     max_pool_tf_same,
     tf_same_pads,
 )
@@ -74,11 +75,11 @@ class Unit3D(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         if not self.use_bias:
-            # shared chooser: bf16 takes the TapConv3D lowering (conv3d-bf16
-            # backend pathology), fp32 the direct conv — same param tree
-            x = conv3d_module(self.features, self.kernel, self.stride,
-                              tf_same_pads(self.kernel, self.stride),
-                              self.dtype, "conv3d")(x)
+            # every bias-free convolution as temporal taps, in both dtypes:
+            # the whole tower is then conv2ds over (N·T, H, W, C), which read
+            # 11 % faster end to end than nn.Conv (TapConv3D's table)
+            x = TapConv3D(self.features, self.kernel, self.stride,
+                          dtype=self.dtype, name="conv3d")(x)
         else:
             x = nn.Conv(
                 self.features,

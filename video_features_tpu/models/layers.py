@@ -54,33 +54,48 @@ def tf_same_pads(kernel: Sequence[int], stride: Sequence[int]) -> Tuple[Tuple[in
 
 
 class TapConv3D(nn.Module):
-    """conv3d lowered as a sum of per-temporal-tap conv2ds (TF-SAME pads by
-    default; torch-style explicit per-axis pads via ``padding``).
+    """conv3d as a sum of per-temporal-tap conv2ds, with the reference's
+    TF-SAME pads: every bias-free I3D convolution, in both dtypes.
 
-    Why: on the v5e backend, XLA's conv3d lowering is PATHOLOGICAL in bf16 —
-    measured on the I3D stem (4 clips × 64 × 224², 7³/2³): conv3d fp32
-    13.5 ms, conv3d bf16 **21.7 ms** (slower than fp32!), while the same math
-    as 7 temporal taps of stride-2 conv2d runs **5.5 ms** in bf16 (2.4× the
-    fp32 conv3d). This is the root cause of round 2's "bf16 buys I3D nothing":
-    the stem is two-thirds of the step and its bf16 conv3d regression swallowed
-    every other layer's gain. fp32 keeps the direct conv3d (taps reassociate
-    the temporal accumulation — ~1e-6 drift — and fp32 is the bit-parity path).
-
-    Semantics: identical to ``nn.Conv(kernel, stride, pads)`` with ``pads`` =
-    the reference's TF-SAME amounts (default) or the explicit per-axis (lo, hi)
-    pads given via ``padding`` — the input is zero-padded on every axis, each
+    Semantics: identical to ``nn.Conv(kernel, stride, tf_same_pads(kernel,
+    stride), use_bias=False)`` — the input is zero-padded in time, each
     temporal kernel tap becomes a strided conv2d over the (N·T_out) frame
-    batch, and the taps are summed. Param tree matches ``nn.Conv`` (``kernel``
-    HWIO) so converted checkpoints load unchanged.
+    batch with the spatial pads, and the taps are summed in ``dtype`` (a 1×1×1
+    kernel is one reshape and one conv2d). The taps reorder the temporal
+    sum: ~1e-6 relative in float32 against the direct convolution. The
+    products take the caller's ``jax.default_matmul_precision``; no
+    ``precision=`` is passed here. Param tree matches ``nn.Conv`` (``kernel``
+    of shape (kt, kh, kw, in, out)) so converted checkpoints load unchanged.
+
+    Why, on the v5e (PERF.md §6, PR 37: the benchmark's I3D cell, float32 at
+    ``highest``, pages of 4 stacks × 64 × 224², videos/s cold and warm):
+
+    =====================================================  ===============
+    direct ``nn.Conv`` everywhere                          0.8576, 0.8594
+    taps for the two 7×7×7/2 stems only                    0.9335, 0.9360
+    taps for the 3×3×3 only                                0.8515, 0.8517
+    taps for both                                          0.9291, 0.9298
+    taps for the stems and the 1×1×1, 3×3×3 direct         0.9165, 0.9182
+    **taps for every kernel, the 1×1×1 too (this class)**  0.9528, 0.9542
+    =====================================================  ===============
+
+    The direct 7×7×7 over 3 and 2 input channels ran at 9 % of the six-pass
+    peak (74 + 46 ms a page; 21 + 16 ms as taps). The 3×3×3 and the 1×1×1
+    each LOSE when only one of them is lowered to 2-D, and win together: a
+    tower whose every convolution is a conv2d over (N·T, H, W, C) pays for no
+    relayout between 5-D and 4-D operands. So the lowering is one per tower,
+    not one per kernel shape, and it is the same on every backend. In
+    bfloat16 an earlier installation read the direct conv3d of the stem
+    *slower* than float32 (21.7 against 13.5 ms, the taps 5.5 ms), which is why
+    bfloat16 took this path first. R(2+1)D's factored (1,k,k)/(k,1,1)
+    kernels read slower under taps there and keep ``nn.Conv``
+    (``models/r21d.py``).
     """
 
     features: int
     kernel: Sequence[int]
     stride: Sequence[int]
     dtype: Any = jnp.float32
-    # explicit per-axis (lo, hi) pads (torch-style models, e.g. R(2+1)D);
-    # None = the I3D TF-SAME rule
-    padding: Any = None
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -92,9 +107,7 @@ class TapConv3D(nn.Module):
             (kt, kh, kw, c, self.features), jnp.float32,
         ).astype(self.dtype)
         x = x.astype(self.dtype)
-        pads = (tuple(self.padding) if self.padding is not None
-                else tf_same_pads(self.kernel, self.stride))
-        (pt0, pt1), sp_h, sp_w = pads
+        (pt0, pt1), sp_h, sp_w = tf_same_pads(self.kernel, self.stride)
         if pt0 or pt1:
             x = jnp.pad(x, ((0, 0), (pt0, pt1), (0, 0), (0, 0), (0, 0)))
         n, tp, h, w, _ = x.shape
@@ -109,32 +122,6 @@ class TapConv3D(nn.Module):
             )
             acc = y if acc is None else acc + y
         return acc.reshape((n, t_out) + acc.shape[1:])
-
-
-def conv3d_module(features: int, kernel: Sequence[int], stride: Sequence[int],
-                  padding: Sequence[Tuple[int, int]], dtype: Any, name: str):
-    """The one conv3d chooser (bias-free convs): bf16 routes through
-    :class:`TapConv3D` (XLA's conv3d lowering is pathological in bf16 on this
-    backend — see TapConv3D's measurements), fp32 keeps ``nn.Conv`` for bit
-    parity. ``VFT_I3D_TAP_FP32=1`` opts the fp32 path into the tap lowering
-    too, but only for kernels with JOINT spatio-temporal extent (kt>1 and
-    kh>1 — the pathological class; R(2+1)D's factored (k,1,1)/(1,k,k) convs
-    measured slower under taps and stay direct) — the taps reassociate the
-    temporal sum (~1e-6 drift), hence opt-in, not default. ``padding`` is
-    REQUIRED explicit per-axis (lo, hi) pads — Flax's string "SAME" pads
-    asymmetrically ((2,3) for 7/2) where torch models pad symmetrically, a
-    silent numerics trap no call site should be able to hit.
-    """
-    import os
-
-    padding = tuple(tuple(p) for p in padding)
-    joint_extent = kernel[0] > 1 and (kernel[1] > 1 or kernel[2] > 1)
-    tap_fp32 = os.environ.get("VFT_I3D_TAP_FP32") == "1" and joint_extent
-    if dtype == jnp.bfloat16 or tap_fp32:
-        return TapConv3D(features, tuple(kernel), tuple(stride), dtype=dtype,
-                         padding=padding, name=name)
-    return nn.Conv(features, tuple(kernel), strides=tuple(stride),
-                   padding=padding, use_bias=False, dtype=dtype, name=name)
 
 
 def max_pool_tf_same(

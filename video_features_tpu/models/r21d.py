@@ -29,12 +29,14 @@ from .layers import TorchBatchNorm
 def _conv3d(features, kernel, stride, padding, dtype, name):
     """Direct nn.Conv with explicit torch pads for ALL dtypes.
 
-    Unlike I3D's full-3D kernels, R(2+1)D's factored (1,k,k)/(k,1,1) convs are
-    NOT hit by the backend's conv3d-bf16 pathology — measured same-run on v5e:
-    plain conv3d bf16 91.4 clips/s vs fp32 70.5 (round 2), while routing them
-    through the TapConv3D lowering DROPPED bf16 to 72.8 (the strided temporal
-    slicing relayout costs more than it saves when kt·kh·kw is already
-    factored). I3D keeps conv3d_module; R21D keeps the direct conv.
+    R(2+1)D's kernels are already factored, (1,k,k) and (k,1,1): an earlier
+    installation read, same-run on v5e, plain conv3d bf16 91.4 clips/s vs fp32
+    70.5, while routing them through I3D's temporal-tap lowering
+    (``layers.TapConv3D``) DROPPED bf16 to 72.8 (the strided temporal slicing
+    relayout costs more than it saves when kt·kh·kw is already factored). I3D's
+    kernels (7×7×7, 3×3×3, 1×1×1) all take the taps since PR 37, where the
+    benchmark's I3D cell read 11 % faster for it; no cell runs R(2+1)D, so
+    it keeps the direct conv and nothing here calls ``TapConv3D``.
     """
     return nn.Conv(features, tuple(kernel), strides=tuple(stride),
                     padding=tuple(tuple(p) for p in padding), use_bias=False,
