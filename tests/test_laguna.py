@@ -2,13 +2,14 @@
 program against the benchmark's plain reference through ``Extractor.run``,
 packing, both attention kinds, both ropes, the expert share, the routing
 counters and the weight table. The two Pallas kernels (attention, the grouped
-product) are the chip's, run in the Pallas interpreter. Arithmetic is checked in float32 (``models.laguna.DTYPE``
+product) are the chip's, run in the Pallas interpreter. Arithmetic is checked in float32 (``models.text_layers.DTYPE``
 patched): at a width of 64 the program's bfloat16 would swamp a misplaced
 mask or a wrong rope; the bfloat16 path itself is run once and held loosely.
 """
 
 # fast-registry: page program compiles (both Pallas kernels in the interpreter)
 
+import functools
 import json
 import math
 import os
@@ -29,8 +30,9 @@ from weights import make_leaf, make_weights, unflatten, write_npz  # noqa: E402
 
 from video_features_tpu.config import ExtractionConfig  # noqa: E402
 from video_features_tpu.extractors import get_extractor  # noqa: E402
-from video_features_tpu.extractors import laguna as extractor_module  # noqa: E402
+from video_features_tpu.extractors import token_pages as extractor_module  # noqa: E402
 from video_features_tpu.models import laguna as model  # noqa: E402
+from video_features_tpu.models import text_layers  # noqa: E402
 from video_features_tpu.ops import moe  # noqa: E402
 from video_features_tpu.ops.segment_attention import first_key_block, segment_attention  # noqa: E402
 from video_features_tpu.parallel.pages import (DOC, IDS, POS, SEG, build_token_page,  # noqa: E402
@@ -76,7 +78,7 @@ def tiny(monkeypatch):
 
 @pytest.fixture
 def float32(monkeypatch):
-    monkeypatch.setattr(model, "DTYPE", jnp.float32)
+    monkeypatch.setattr(text_layers, "DTYPE", jnp.float32)
 
 
 @pytest.fixture(scope="module")
@@ -268,9 +270,10 @@ def test_four_shares_and_the_shared_expert_once_make_the_uncut_layer(float32):
         slot_of = np.full((TINY.num_experts,), -1, np.int32)
         slot_of[list(share.experts)] = np.arange(len(ids))
         p = params["layers"][0]
-        y, (routed_total, routed_held, rows) = model.expert_layer(
-            TINY, p, h, valid, jnp.asarray(slot_of), len(ids), interpret=True)
-        routed = y - model.gated_mlp(h, p["shared_gate_up"], p["shared_down"])
+        y, (routed_total, routed_held, rows) = text_layers.expert_layer(
+            p, h, valid, jnp.asarray(slot_of), len(ids), functools.partial(model.route, TINY),
+            interpret=True)
+        routed = y - text_layers.gated_mlp(h, p["shared_gate_up"], p["shared_down"])
         total = routed if total is None else total + routed
         held_rows += int(routed_held)
         assert int(routed_total) == tokens * TINY.num_experts_per_tok
@@ -404,7 +407,9 @@ def test_the_package_and_the_other_types_do_not_load_the_new_modules():
         "from video_features_tpu.extractors import get_extractor\n"
         "import video_features_tpu.extractors.base, video_features_tpu.parallel.packer\n"
         "bad = [m for m in sys.modules if m.startswith(('video_features_tpu.models.laguna',"
-        " 'video_features_tpu.extractors.laguna', 'video_features_tpu.ops.moe',"
+        " 'video_features_tpu.models.sarvam', 'video_features_tpu.models.text_layers',"
+        " 'video_features_tpu.extractors.laguna', 'video_features_tpu.extractors.sarvam',"
+        " 'video_features_tpu.extractors.token_pages', 'video_features_tpu.ops.moe',"
         " 'video_features_tpu.ops.segment_attention', 'jax.experimental.pallas'))]\n"
         "assert not bad, bad\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu")

@@ -34,6 +34,7 @@ DEFAULT_TIER: Dict[str, str] = {
     "test_flow_frames": "shared-frame flow forward parity (flow compiles)",
     "test_kernels": "kernel parity vs torch mirrors",
     "test_laguna": "page program compiles (both Pallas kernels in the interpreter)",
+    "test_sarvam": "page program compiles (both Pallas kernels in the interpreter)",
     "test_metrics": "stage-clock tests with real sleeps",
     "test_multihost": "loopback two-process jax.distributed init",
     "test_packer_models": "real-model packed parity (jit compiles)",

@@ -94,7 +94,7 @@ EXECUTION_FIELDS = (
     "paged_batching",          # dispatch mechanics; page outputs byte-match
                                # bucketed (pinned by tests/test_paged.py)
     "pages_in_flight",         # in-flight depth, not numerics
-    "page_tokens",             # laguna's page size: which transcripts share
+    "page_tokens",             # the text stream's page size: which transcripts share
                                # a page moves a row by float rounding only
                                # (attention's blocks fall elsewhere), as which
                                # neighbours it met already does at one size
@@ -144,6 +144,7 @@ _CHECKPOINT_NAMES = {
     "raft": ("raft-sintel",),
     "pwc": ("pwc-sintel",),
     "laguna": ("laguna",),
+    "sarvam": ("sarvam",),
 }
 
 

@@ -13,7 +13,9 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-FEATURE_TYPES = ("i3d", "vggish", "r21d_rgb", "resnet50", "raft", "pwc", "laguna")
+FEATURE_TYPES = ("i3d", "vggish", "r21d_rgb", "resnet50", "raft", "pwc", "laguna", "sarvam")
+# the text stream: token transcripts in, the packed path only, one chip's share
+TOKEN_TYPES = ("laguna", "sarvam")
 ON_EXTRACTION = ("print", "save_numpy")
 FLOW_TYPES = ("raft", "pwc")
 STREAMS = ("rgb", "flow")
@@ -130,7 +132,7 @@ class ExtractionConfig:
     # dispatch; page_rows = ceil(batch budget / depth), so total in-flight
     # rows stay at one bucketed batch regardless of depth).
     pages_in_flight: int = 2
-    # laguna (the text stream): token slots of one device page. A page holds
+    # laguna, sarvam (the text stream): token slots of one device page. A page holds
     # whole transcripts first-fit, so this is also the longest transcript the
     # type takes; a multiple of the attention kernel's block of 512. One
     # program per value; which transcripts share a page moves a row by
@@ -527,7 +529,7 @@ MODEL_DEFAULTS = {
     "vggish": dict(),
     # the text stream has one path, the packed one, and one page program on
     # one chip: the checkpoint is that chip's share of the experts
-    "laguna": dict(num_devices=1),
+    **{t: dict(num_devices=1) for t in TOKEN_TYPES},
 }
 
 
@@ -540,7 +542,7 @@ def resolve_model_defaults(cfg: ExtractionConfig) -> ExtractionConfig:
         streams = ("rgb", "flow")
     if streams is not None:
         updates["streams"] = tuple(streams)
-    if cfg.feature_type == "laguna" and not cfg.pack_corpus:
+    if cfg.feature_type in TOKEN_TYPES and not cfg.pack_corpus:
         updates["pack_corpus"] = True
     return cfg.replace(**updates) if updates else cfg
 

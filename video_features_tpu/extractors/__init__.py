@@ -28,4 +28,7 @@ def get_extractor(cfg):
     if ft == "laguna":
         from .laguna import ExtractLaguna
         return ExtractLaguna(cfg)
+    if ft == "sarvam":
+        from .sarvam import ExtractSarvam
+        return ExtractSarvam(cfg)
     raise ValueError(f"unknown feature_type: {ft}")

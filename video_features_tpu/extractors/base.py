@@ -1034,7 +1034,7 @@ class Extractor(abc.ABC):
             "pages_dispatched": packer.pages_dispatched,
             "max_in_flight": packer.max_in_flight,
             # token pages: table rows dispatched, and what the model counted
-            # on the device (laguna's routing counters)
+            # on the device (the text stream's routing counters)
             "segments": packer.segments,
             **self._extra_pack_stats(),
             # per-stage wall seconds for the whole corpus, the writer's

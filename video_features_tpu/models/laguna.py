@@ -28,7 +28,7 @@ published implementations of this config family do.
 
 from __future__ import annotations
 
-import math
+import functools
 from dataclasses import dataclass
 from typing import Dict, Sequence, Tuple
 
@@ -36,12 +36,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ..ops import moe
 from ..ops.segment_attention import segment_attention
-
-DTYPE = jnp.bfloat16
+from . import text_layers as tl
+from .text_layers import Share, apply_rope, share_of  # noqa: F401 — the model's interface
 
 
 @dataclass(frozen=True)
@@ -85,72 +84,23 @@ class LagunaConfig:
 PUBLISHED = LagunaConfig()
 
 
-@dataclass(frozen=True)
-class Share:
-    """What the checkpoint holds: layer ids in order, expert ids in the order
-    their weights are stacked."""
-    layers: Tuple[int, ...]
-    experts: Tuple[int, ...]
-
-
 # --- rope -------------------------------------------------------------------
 
 def rope_inv_freq(cfg: LagunaConfig, full: bool) -> Tuple[np.ndarray, float]:
     """(rot/2,) inverse frequencies in float64 and the factor cos and sin are
     scaled by. Sliding layers: plain rope over the whole head. Full layers:
-    YaRN over the first ``partial_rotary_factor`` of it — interpolated
-    (``/ factor``) frequencies blended into the unscaled ones by a linear ramp
-    between the dimensions that turn ``beta_fast`` and ``beta_slow`` times in
-    the original context."""
+    YaRN over the first ``partial_rotary_factor`` of it (``tl.yarn_inv_freq``)."""
     if not full:
         rot = cfg.head_dim
         return cfg.sliding_rope_theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot), 1.0
     rot = int(cfg.head_dim * cfg.full_partial_rotary_factor)
-    base, orig = cfg.full_rope_theta, cfg.yarn_original_max_position_embeddings
-    pos_freqs = base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
-
-    def correction_dim(turns):
-        return rot * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(cfg.yarn_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.yarn_beta_slow)), rot - 1)
-    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
-                   / ((high if high != low else high + 0.001) - low), 0.0, 1.0)
-    inv = (1.0 / (cfg.yarn_factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1.0 - ramp)
+    inv = tl.yarn_inv_freq(rot, cfg.full_rope_theta, cfg.yarn_factor,
+                           cfg.yarn_original_max_position_embeddings, cfg.yarn_beta_fast,
+                           cfg.yarn_beta_slow)
     return inv, cfg.yarn_attention_factor
 
 
-def apply_rope(x, pos, inv_freq: np.ndarray, factor: float, scale: float = 1.0):
-    """(tokens, heads, head_dim) → the same, its first ``2 * len(inv_freq)``
-    dimensions rotated by ``pos`` (float32 inside), all of it times ``scale``."""
-    half = len(inv_freq)
-    angle = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)
-    cos = (jnp.cos(angle) * (factor * scale))[:, None, :]
-    sin = (jnp.sin(angle) * (factor * scale))[:, None, :]
-    xf = x.astype(jnp.float32)
-    a, b, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest * scale],
-                           axis=-1).astype(x.dtype)
-
-
 # --- layers -----------------------------------------------------------------
-
-def rms_norm(x, scale, eps: float, out_dtype=None):
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(out_dtype or DTYPE)
-
-
-def dot(a, b):
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
-
-
-def gated_mlp(h, w_gate_up, w_down):
-    """``down(silu(gate(h)) · up(h))``; gate and up are one product."""
-    gate, up = jnp.split(dot(h, w_gate_up).astype(h.dtype), 2, axis=-1)
-    act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-    return dot(act.astype(h.dtype), w_down)
-
 
 def attention(cfg: LagunaConfig, layer: int, p: dict, x, doc, pos, block: int,
               interpret: bool = False):
@@ -158,8 +108,8 @@ def attention(cfg: LagunaConfig, layer: int, p: dict, x, doc, pos, block: int,
     full = cfg.is_full(layer)
     tokens = x.shape[0]
     with jax.named_scope("qkv"):
-        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        q, k, v, g = jnp.split(dot(h, p["wqkvg"]).astype(DTYPE),
+        h = tl.rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q, k, v, g = jnp.split(tl.dot(h, p["wqkvg"]).astype(tl.DTYPE),
                                np.cumsum([heads * d, kv * d, kv * d]), axis=-1)
     with jax.named_scope("rope"):
         inv_freq, factor = rope_inv_freq(cfg, full)
@@ -173,101 +123,30 @@ def attention(cfg: LagunaConfig, layer: int, p: dict, x, doc, pos, block: int,
     with jax.named_scope("gate"):
         gate = jax.nn.sigmoid(g[:, :heads].astype(jnp.float32))
         o = (o.reshape(tokens, heads, d).astype(jnp.float32) * gate[..., None]
-             ).astype(DTYPE).reshape(tokens, heads * d)
+             ).astype(tl.DTYPE).reshape(tokens, heads * d)
     with jax.named_scope("out"):
-        return (x.astype(jnp.float32) + dot(o, p["wo"])).astype(DTYPE)
+        return (x.astype(jnp.float32) + tl.dot(o, p["wo"])).astype(tl.DTYPE)
 
 
-def expert_layer(cfg: LagunaConfig, p: dict, h, valid, slot_of, num_held: int,
-                 interpret: bool = False):
-    """→ (routed + shared, float32), (routed_total, routed_held, rows per held expert)."""
-    with jax.named_scope("route"):
-        weights, experts = moe.route(h, p["router"], cfg.num_experts_per_tok,
-                                     cfg.moe_routed_scaling_factor)
-    with jax.named_scope("dispatch"):
-        d = moe.dispatch(experts, valid, slot_of, num_held)
-        rows = lax.optimization_barrier(h)[d.token_of_row]
-    with jax.named_scope("experts"):
-        gate, up = jnp.split(moe.grouped_matmul(rows, p["experts_gate_up"], d.group_sizes,
-                                               interpret), 2, axis=-1)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(DTYPE)
-        out = moe.grouped_matmul(act, p["experts_down"], d.group_sizes, interpret)
-    with jax.named_scope("shared"):
-        shared = gated_mlp(h, p["shared_gate_up"], p["shared_down"])
-    with jax.named_scope("combine"):
-        y = moe.combine(out, weights, d) + shared
-    routed_total = jnp.sum(valid).astype(jnp.int32) * cfg.num_experts_per_tok
-    return y, (routed_total, jnp.sum(d.group_sizes), d.group_sizes)
-
-
-def segment_mean(x, seg, page_rows: int):
-    """(tokens, width) float32 → (page_rows, width): the mean over each
-    segment's tokens; a row with no token is zero. ``seg`` is -1 on pads."""
-    onehot = (seg[None, :] == jnp.arange(page_rows, dtype=jnp.int32)[:, None])
-    # float32 sums on bfloat16 products: the 0/1 matrix is exact in bfloat16
-    # and three bfloat16 parts hold all of a float32
-    sums, rest = jnp.zeros((page_rows, x.shape[1]), jnp.float32), x
-    for _ in range(3):
-        part = rest.astype(DTYPE)
-        sums = sums + dot(onehot.astype(DTYPE), part)
-        rest = rest - part.astype(jnp.float32)
-    counts = jnp.sum(onehot, axis=1, dtype=jnp.int32)
-    return sums / jnp.maximum(counts, 1)[:, None].astype(jnp.float32)
+def route(cfg: LagunaConfig, p: dict, h):
+    """Softmax over all experts, the published top-k and scaling factor."""
+    return moe.route(h, p["router"], cfg.num_experts_per_tok, cfg.moe_routed_scaling_factor)
 
 
 def forward(cfg: LagunaConfig, share: Share, page_rows: int, block: int, params: dict, page,
             interpret: bool = False):
-    """The page program's body. ``page``: int32 (4, page_tokens) — token id,
-    document index in the page (-1 on pads), position in its document, row of
-    its segment in the page's table (-1 on pads). → ((page_rows, hidden)
-    float32 segment features, int32 counters: routed_total, routed_held, then
-    rows per held expert for every sparse layer). ``interpret``: the two Pallas
-    kernels in the interpreter (a backend that is not a TPU)."""
-    ids, doc, pos, seg = page[0], page[1], page[2], page[3]
-    valid = doc >= 0
-    slot_of = np.full((cfg.num_experts,), -1, np.int32)
-    slot_of[list(share.experts)] = np.arange(len(share.experts), dtype=np.int32)
-    slot_of = jnp.asarray(slot_of, jnp.int32)
-    with jax.named_scope("laguna/embed"):
-        x = params["embed"][ids]
-    counters = []
-    for p, layer in zip(params["layers"], share.layers):
-        with jax.named_scope(f"laguna/L{layer}/attn"):
-            x = attention(cfg, layer, p, x, doc, pos, block, interpret)
-        with jax.named_scope(f"laguna/L{layer}/{'mlp' if cfg.is_dense(layer) else 'moe'}"):
-            with jax.named_scope("norm"):
-                h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-            if cfg.is_dense(layer):
-                y = gated_mlp(h, p["w_gate_up"], p["w_down"])
-            else:
-                y, counts = expert_layer(cfg, p, h, valid, slot_of, len(share.experts),
-                                         interpret)
-                counters.append(counts)
-            x = (x.astype(jnp.float32) + y).astype(DTYPE)
-    with jax.named_scope("laguna/pool"):
-        rows = segment_mean(rms_norm(x, params["final_norm"], cfg.rms_norm_eps, jnp.float32),
-                            seg, page_rows)
-    zero = jnp.zeros((), jnp.int32)
-    totals = [sum((c[i] for c in counters), zero) for i in (0, 1)]
-    return rows, jnp.concatenate([jnp.stack(totals)] + [c[2] for c in counters])
+    """The page program's body (``tl.page_forward``: the planes of ``page``,
+    what it returns, the scopes). ``interpret``: the two Pallas kernels in
+    the interpreter (a backend that is not a TPU)."""
+    def attn(layer, p, x, doc, pos):
+        return attention(cfg, layer, p, x, doc, pos, block, interpret)
+
+    return tl.page_forward("laguna", share, cfg.num_experts, cfg.is_dense, attn,
+                           functools.partial(route, cfg), cfg.rms_norm_eps, page_rows, params,
+                           page, interpret)
 
 
 # --- checkpoint → the program's tree ------------------------------------------
-
-def share_of(names: Sequence[str]) -> Share:
-    """Which layers and experts a checkpoint's leaf names hold; every sparse
-    layer must hold the same experts."""
-    layers = sorted({int(n.split("/")[1]) for n in names if n.startswith("layers/")})
-    per_layer = {}
-    for n in names:
-        parts = n.split("/")
-        if len(parts) > 3 and parts[0] == "layers" and parts[2] == "experts":
-            per_layer.setdefault(int(parts[1]), set()).add(int(parts[3]))
-    shares = {tuple(sorted(s)) for s in per_layer.values()}
-    if len(shares) > 1:
-        raise ValueError(f"layers hold different experts: {sorted(shares)[:2]} …")
-    return Share(tuple(layers), shares.pop() if shares else ())
-
 
 def stack_checkpoint(cfg: LagunaConfig, names: Sequence[str], read) -> Tuple[dict, Share]:
     """The checkpoint's flat leaves (one matrix per projection and expert,
@@ -277,14 +156,7 @@ def stack_checkpoint(cfg: LagunaConfig, names: Sequence[str], read) -> Tuple[dic
     ``w_gate_up`` pairs, experts stacked on a leading axis in ``share.experts``
     order."""
     share = share_of(names)
-    cast = jax.jit(lambda a: a.astype(DTYPE))
-
-    def get(name):
-        return cast(read(name))
-
-    def side_by_side(prefix, leaves):
-        return jnp.concatenate([get(f"{prefix}/{leaf}") for leaf in leaves], axis=-1)
-
+    get, side_by_side = tl.leaf_reader(read)
     layers = []
     for layer in share.layers:
         pre = f"layers/{layer}"
@@ -295,18 +167,7 @@ def stack_checkpoint(cfg: LagunaConfig, names: Sequence[str], read) -> Tuple[dic
                  [get(f"{pre}/q_proj"), get(f"{pre}/k_proj"), get(f"{pre}/v_proj"),
                   jnp.pad(g, ((0, 0), (0, -g.shape[1] % 128)))], axis=-1),
              "wo": get(f"{pre}/o_proj")}
-        if cfg.is_dense(layer):
-            p["w_gate_up"] = side_by_side(f"{pre}/mlp", ("gate_proj", "up_proj"))
-            p["w_down"] = get(f"{pre}/mlp/down_proj")
-        else:
-            p["router"] = get(f"{pre}/router")
-            p["shared_gate_up"] = side_by_side(f"{pre}/shared", ("gate_proj", "up_proj"))
-            p["shared_down"] = get(f"{pre}/shared/down_proj")
-            p["experts_gate_up"] = jnp.stack(
-                [side_by_side(f"{pre}/experts/{e}", ("gate_proj", "up_proj"))
-                 for e in share.experts])
-            p["experts_down"] = jnp.stack(
-                [get(f"{pre}/experts/{e}/down_proj") for e in share.experts])
+        tl.stack_mlp(p, pre, cfg.is_dense(layer), share.experts, get, side_by_side)
         layers.append(p)
     params = {"embed": get("embed/embedding"), "final_norm": get("final_norm/scale"),
               "layers": layers}
@@ -320,36 +181,18 @@ def leaf_shapes(cfg: LagunaConfig, layers: Sequence[int], experts: Sequence[int]
     hid, kvw = cfg.hidden_size, cfg.num_key_value_heads * cfg.head_dim
     spec: Dict[str, Tuple[int, ...]] = {"embed/embedding": (cfg.vocab_size, hid),
                                         "final_norm/scale": (hid,)}
-
-    def mlp(prefix, width):
-        spec[f"{prefix}/gate_proj"] = spec[f"{prefix}/up_proj"] = (hid, width)
-        spec[f"{prefix}/down_proj"] = (width, hid)
-
     for layer in layers:
         pre, qw = f"layers/{layer}", cfg.heads(layer) * cfg.head_dim
         spec[f"{pre}/attn_norm/scale"] = spec[f"{pre}/mlp_norm/scale"] = (hid,)
         spec[f"{pre}/q_proj"], spec[f"{pre}/o_proj"] = (hid, qw), (qw, hid)
         spec[f"{pre}/k_proj"] = spec[f"{pre}/v_proj"] = (hid, kvw)
         spec[f"{pre}/g_proj"] = (hid, cfg.heads(layer))
-        if cfg.is_dense(layer):
-            mlp(f"{pre}/mlp", cfg.intermediate_size)
-        else:
-            spec[f"{pre}/router"] = (hid, cfg.num_experts)
-            mlp(f"{pre}/shared", cfg.shared_expert_intermediate_size)
-            for e in experts:
-                mlp(f"{pre}/experts/{e}", cfg.moe_intermediate_size)
+        tl.mlp_leaf_shapes(spec, pre, hid, cfg.intermediate_size if cfg.is_dense(layer) else None,
+                           cfg.num_experts, cfg.shared_expert_intermediate_size,
+                           cfg.moe_intermediate_size, experts)
     return spec
 
 
 def random_checkpoint(cfg: LagunaConfig, layers: Sequence[int], experts: Sequence[int],
                       seed: int = 0) -> Dict[str, np.ndarray]:
-    """He-scaled normals by each matrix's own fan-in, norm scales in 0.8–1.2."""
-    rng = np.random.default_rng(seed)
-    out = {}
-    for name, shape in leaf_shapes(cfg, layers, experts).items():
-        if name.endswith("/scale"):
-            out[name] = rng.uniform(0.8, 1.2, shape).astype(np.float32)
-        else:
-            out[name] = (rng.standard_normal(shape, dtype=np.float32)
-                         * np.float32((2.0 / shape[0]) ** 0.5))
-    return out
+    return tl.random_leaves(leaf_shapes(cfg, layers, experts), seed)

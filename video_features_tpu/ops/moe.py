@@ -33,12 +33,22 @@ class Dispatch(NamedTuple):
     group_sizes: jnp.ndarray   # (held experts,) int32 rows per held expert, in weight order
 
 
-def route(h, w_router, top_k: int, scale: float):
-    """Softmax over all experts in float32, the ``top_k`` largest renormalised
-    to sum 1, times the routed scaling factor → (weights float32, expert ids),
-    both (tokens, top_k)."""
+def route(h, w_router, top_k: int, scale: float, scoring: str = "softmax", bias=None):
+    """Scores over all experts in float32 by the model's ``scoring`` —
+    ``"softmax"`` of the logits, or ``"sigmoid"`` of each (the auxiliary-loss-
+    free scheme) — then the ``top_k`` largest, renormalised to sum 1, times
+    the routed scaling factor → (weights float32, expert ids), both (tokens,
+    top_k). ``bias`` (all experts, float32) is added to the scores for the
+    CHOICE only: the weights come from the unbiased scores."""
     logits = jnp.dot(h, w_router, preferred_element_type=jnp.float32)
-    top, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"route: scoring {scoring!r} is neither softmax nor sigmoid")
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top, experts = lax.top_k(scores, top_k)
+    else:
+        _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     return top / top.sum(-1, keepdims=True) * scale, experts.astype(jnp.int32)
 
 
