@@ -129,6 +129,8 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
     assert stats["routed_total"] == TINY.num_experts_per_tok * sum(LENGTHS) * sparse
     assert stats["routed_held"] == int(np.sum(stats["expert_rows"])) < stats["routed_total"]
     assert np.asarray(stats["expert_rows"]).shape == (sparse, len(HELD))
+    # one chunk a routed layer and page unless a page held more than a chunk's rows (ops/moe.py)
+    assert stats["expert_chunks"] >= stats["expert_chunk_calls"] == sparse * stats["pages_dispatched"]
 
     tree = {k: unflatten(v) for k, v in flat.items()}
     answer = ref.make_answer_fn(tree, REF_TINY)
@@ -390,7 +392,7 @@ def test_eight_shares_and_the_shared_expert_once_make_the_uncut_layer(float32):
         slot_of[list(share.experts)] = np.arange(len(ids))
         p = params["layers"][0]
         assert p["router_bias"].dtype == jnp.float32
-        y, (routed_total, routed_held, rows) = text_layers.expert_layer(
+        y, (routed_total, routed_held, rows, _chunks) = text_layers.expert_layer(
             p, h, valid, jnp.asarray(slot_of), len(ids), functools.partial(model.route, TINY),
             interpret=True)
         routed = y - text_layers.gated_mlp(h, p["shared_gate_up"], p["shared_down"])

@@ -163,14 +163,18 @@ class TokenPageExtractor(Extractor):
     def _extra_pack_stats(self) -> dict:
         """The routing counters of the run's pages: assignments made
         (``routed_total`` = top-k × real tokens), those to experts held here
-        (``routed_held``), and rows per held expert for each sparse layer
-        (``expert_rows``, layers × experts held)."""
+        (``routed_held``), rows per held expert for each sparse layer
+        (``expert_rows``, layers × experts held), and how many chunks the
+        routed layers ran (``expert_chunks``) in how many calls
+        (``expert_chunk_calls`` = sparse layers × pages): equal when no page
+        held more than one chunk's rows (``ops/moe.py``)."""
         counters = getattr(self, "_moe_counters", None)
         if counters is None:
             return {}
         c = np.asarray(counters)
         return {"routed_total": int(c[0]), "routed_held": int(c[1]),
-                "expert_rows": c[2:].reshape(-1, max(len(self.share.experts), 1)).tolist()}
+                "expert_chunks": int(c[2]), "expert_chunk_calls": int(c[3]),
+                "expert_rows": c[4:].reshape(-1, max(len(self.share.experts), 1)).tolist()}
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:
         raise NotImplementedError(

@@ -92,24 +92,36 @@ def gated_mlp(h, w_gate_up, w_down):
 
 def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bool = False):
     """→ (routed + shared, float32), (routed_total, routed_held, rows per held
-    expert). ``route(p, h)`` is the model's call of ``moe.route``: its top-k,
-    scaling factor and scoring."""
+    expert, chunks run). ``route(p, h)`` is the model's call of ``moe.route``:
+    its top-k, scaling factor and scoring. The held rows go through the
+    experts in chunks (``ops/moe.py``): one where the router is near even."""
     with jax.named_scope("route"):
         weights, experts = route(p, h)
     with jax.named_scope("dispatch"):
-        d = moe.dispatch(experts, valid, slot_of, num_held)
-        rows = lax.optimization_barrier(h)[d.token_of_row]
-    with jax.named_scope("experts"):
-        gate, up = jnp.split(moe.grouped_matmul(rows, p["experts_gate_up"], d.group_sizes,
-                                               interpret), 2, axis=-1)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(DTYPE)
-        out = moe.grouped_matmul(act, p["experts_down"], d.group_sizes, interpret)
+        d = moe.dispatch(experts, valid, slot_of, num_held, weights)
+        trips, part = moe.chunks(d, moe.chunk_rows(experts.size, num_held, slot_of.shape[0]), interpret)
     with jax.named_scope("shared"):
         shared = gated_mlp(h, p["shared_gate_up"], p["shared_down"])
-    with jax.named_scope("combine"):
-        y = moe.combine(out, weights, d) + shared
+
+    def one_chunk(state):
+        # a loop's body starts its own name stack: each scope opens in here,
+        # under the names the traces are read by (…/moe/dispatch and so on)
+        c, y = state
+        with jax.named_scope("moe/dispatch"):
+            chunk = part(c)
+            rows = h[chunk.token_of_row]
+        with jax.named_scope("moe/experts"):
+            gate, up = jnp.split(moe.grouped_matmul(rows, p["experts_gate_up"], chunk.group_sizes,
+                                                   interpret), 2, axis=-1)
+            act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(DTYPE)
+            out = moe.grouped_matmul(act, p["experts_down"], chunk.group_sizes, interpret)
+        with jax.named_scope("moe/combine"):
+            return c + 1, moe.combine(out, y, chunk)
+
+    _, y = lax.while_loop(lambda state: state[0] < trips, one_chunk,
+                          (jnp.zeros((), jnp.int32), shared))
     routed_total = jnp.sum(valid).astype(jnp.int32) * experts.shape[1]
-    return y, (routed_total, jnp.sum(d.group_sizes), d.group_sizes)
+    return y, (routed_total, jnp.sum(d.group_sizes), d.group_sizes, trips)
 
 
 def segment_mean(x, seg, page_rows: int):
@@ -135,8 +147,9 @@ def page_forward(name: str, share: Share, num_experts: int, is_dense, attention,
     → segment mean. ``page``: int32 (4, page_tokens) — token id, document
     index in the page (-1 on pads), position in its document, row of its
     segment in the page's table (-1 on pads). → ((page_rows, hidden) float32
-    segment features, int32 counters: routed_total, routed_held, then rows per
-    held expert for every sparse layer). Scopes are ``<name>/embed``, ``<name>/L<k>/attn/…``,
+    segment features, int32 counters: routed_total, routed_held, expert_chunks
+    (chunks run, over the sparse layers), expert_chunk_calls (the sparse
+    layers), then rows per held expert for every sparse layer). Scopes are ``<name>/embed``, ``<name>/L<k>/attn/…``,
     ``<name>/L<k>/{mlp,moe}/…``, ``<name>/pool``."""
     ids, doc, pos, seg = page[0], page[1], page[2], page[3]
     valid = doc >= 0
@@ -162,7 +175,7 @@ def page_forward(name: str, share: Share, num_experts: int, is_dense, attention,
     with jax.named_scope(f"{name}/pool"):
         rows = segment_mean(rms_norm(x, params["final_norm"], eps, jnp.float32), seg, page_rows)
     zero = jnp.zeros((), jnp.int32)
-    totals = [sum((c[i] for c in counters), zero) for i in (0, 1)]
+    totals = [sum((c[i] for c in counters), zero) for i in (0, 1, 3)] + [zero + len(counters)]
     return rows, jnp.concatenate([jnp.stack(totals)] + [c[2] for c in counters])
 
 
