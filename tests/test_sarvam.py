@@ -237,15 +237,17 @@ def pallas_calls(jaxpr):
                 yield from pallas_calls(value.jaxpr)
 
 
-@pytest.mark.parametrize("window,name", [(None, "segment_attention_full"),
-                                         (512, "segment_attention_window")], ids=["full", "window"])
-def test_a_laguna_call_lowers_to_the_parents_kernel(window, name):
+@pytest.mark.parametrize("window,name,heads,kv,d", [
+    (None, "segment_attention_full", 48, 8, 128), (512, "segment_attention_window", 72, 8, 128),
+    (None, "segment_attention_full", 16, 2, 256)], ids=["full", "window", "full_256_wide"])
+def test_a_laguna_call_lowers_to_the_parents_kernel(window, name, heads, kv, d):
     """What Laguna's layers call, at the published shapes, traces to the
     ``pallas_call`` the parent commit's kernel file made (read there: name,
     grid, operands, blocks); the latent call is another name with two more
-    operands."""
-    tokens, kv, d, block = 16384, 8, 128, 512
-    heads = 48 if window is None else 72
+    operands; Qwen3-Next's full layer (eight 256-wide query heads to one
+    key/value head) is the same kernel under the same name at another
+    ``head_dim`` and ``group``."""
+    tokens, block = 16384, 512
     q = jax.ShapeDtypeStruct((tokens, heads * d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((tokens, kv * d), jnp.bfloat16)
     doc = jax.ShapeDtypeStruct((tokens,), jnp.int32)
@@ -264,7 +266,7 @@ def test_a_laguna_call_lowers_to_the_parents_kernel(window, name):
                       (block, group * d)]
     assert [v.aval.shape for v in call.outvars] == [(tokens, heads * d)]
 
-    heads, r = 64, 64
+    heads, r, d = 64, 64, 128
     q = jax.ShapeDtypeStruct((tokens, heads * d), jnp.bfloat16)
     latent = jax.make_jaxpr(functools.partial(segment_attention, kv_heads=heads, head_dim=d,
                                               block=block))(

@@ -145,6 +145,7 @@ _CHECKPOINT_NAMES = {
     "pwc": ("pwc-sintel",),
     "laguna": ("laguna",),
     "sarvam": ("sarvam",),
+    "qwen3_next": ("qwen3_next",),
 }
 
 

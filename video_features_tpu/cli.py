@@ -193,7 +193,7 @@ def build_parser() -> argparse.ArgumentParser:
                              "batch; >= 2 overlaps host refill with device "
                              "compute)")
     parser.add_argument("--page_tokens", type=int, default=16384,
-                        help="laguna, sarvam: token slots of one device page (whole "
+                        help="laguna, sarvam, qwen3_next: token slots of one device page (whole "
                              "transcripts share a page first-fit; a longer "
                              "transcript is refused); a multiple of 512")
     parser.add_argument("--pack_flush_age", type=int, default=8,

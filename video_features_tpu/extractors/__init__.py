@@ -31,4 +31,7 @@ def get_extractor(cfg):
     if ft == "sarvam":
         from .sarvam import ExtractSarvam
         return ExtractSarvam(cfg)
+    if ft == "qwen3_next":
+        from .qwen3_next import ExtractQwen3Next
+        return ExtractQwen3Next(cfg)
     raise ValueError(f"unknown feature_type: {ft}")

@@ -1,7 +1,7 @@
 """The text stream's extractor: a video's tokenised, timed transcript → one
 contextual feature row per segment, whatever language model the type names
-(``extractors/laguna.py``, ``extractors/sarvam.py``: a model module, a
-checkpoint name, the share a random checkpoint holds).
+(``extractors/laguna.py``, ``extractors/sarvam.py``, ``extractors/qwen3_next.py``: a
+model module, a checkpoint name, the share a random checkpoint holds).
 
 A path is one video's transcript, ``<stem>.tokens.npz``
 (:mod:`..io.transcript`); outputs are ``<stem>_<type>.npy`` (segments × hidden,
@@ -167,14 +167,19 @@ class TokenPageExtractor(Extractor):
         (``expert_rows``, layers × experts held), and how many chunks the
         routed layers ran (``expert_chunks``) in how many calls
         (``expert_chunk_calls`` = sparse layers × pages): equal when no page
-        held more than one chunk's rows (``ops/moe.py``)."""
+        held more than one chunk's rows (``ops/moe.py``). Between them and the
+        rows, what the model's own ``PAGE_COUNTERS`` name (``qwen3_next``:
+        ``gdn_chunks``, ``gdn_boundary_chunks``)."""
         counters = getattr(self, "_moe_counters", None)
         if counters is None:
             return {}
         c = np.asarray(counters)
+        own = getattr(self.model, "PAGE_COUNTERS", ())
+        rows = c[4 + len(own):].reshape(-1, max(len(self.share.experts), 1))
         return {"routed_total": int(c[0]), "routed_held": int(c[1]),
                 "expert_chunks": int(c[2]), "expert_chunk_calls": int(c[3]),
-                "expert_rows": c[4:].reshape(-1, max(len(self.share.experts), 1)).tolist()}
+                **{name: int(v) for name, v in zip(own, c[4:])},
+                "expert_rows": rows.tolist()}
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:
         raise NotImplementedError(

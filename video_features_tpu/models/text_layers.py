@@ -1,4 +1,5 @@
-"""What the text stream's models share (``models/laguna.py``, ``models/sarvam.py``):
+"""What the text stream's models share (``models/laguna.py``, ``models/sarvam.py``,
+``models/qwen3_next.py``):
 the page program's arithmetic outside attention, and how a checkpoint's leaf
 names say what this chip holds.
 
@@ -94,7 +95,9 @@ def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bo
     """→ (routed + shared, float32), (routed_total, routed_held, rows per held
     expert, chunks run). ``route(p, h)`` is the model's call of ``moe.route``:
     its top-k, scaling factor and scoring. The held rows go through the
-    experts in chunks (``ops/moe.py``): one where the router is near even."""
+    experts in chunks (``ops/moe.py``): one where the router is near even.
+    Where the checkpoint has a ``shared_gate`` leaf the shared expert's output
+    is scaled token by token by ``sigmoid(h · shared_gate)``."""
     with jax.named_scope("route"):
         weights, experts = route(p, h)
     with jax.named_scope("dispatch"):
@@ -102,6 +105,8 @@ def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bo
         trips, part = moe.chunks(d, moe.chunk_rows(experts.size, num_held, slot_of.shape[0]), interpret)
     with jax.named_scope("shared"):
         shared = gated_mlp(h, p["shared_gate_up"], p["shared_down"])
+        if "shared_gate" in p:
+            shared = jax.nn.sigmoid(dot(h, p["shared_gate"])) * shared
 
     def one_chunk(state):
         # a loop's body starts its own name stack: each scope opens in here,
@@ -140,7 +145,8 @@ def segment_mean(x, seg, page_rows: int):
 
 
 def page_forward(name: str, share: Share, num_experts: int, is_dense, attention, route,
-                 eps: float, page_rows: int, params: dict, page, interpret: bool = False):
+                 eps: float, page_rows: int, params: dict, page, interpret: bool = False,
+                 page_counters=None):
     """The page program's body, the same for every model of the stream:
     embed → per held layer ``attention(layer, p, x, doc, pos)`` then the dense
     unit or the routed layer (``route``: :func:`expert_layer`'s) → final norm
@@ -149,7 +155,9 @@ def page_forward(name: str, share: Share, num_experts: int, is_dense, attention,
     segment in the page's table (-1 on pads). → ((page_rows, hidden) float32
     segment features, int32 counters: routed_total, routed_held, expert_chunks
     (chunks run, over the sparse layers), expert_chunk_calls (the sparse
-    layers), then rows per held expert for every sparse layer). Scopes are ``<name>/embed``, ``<name>/L<k>/attn/…``,
+    layers), then the model's own ``page_counters`` (int32, its module's
+    ``PAGE_COUNTERS`` names them), then rows per held expert for every sparse
+    layer). Scopes are ``<name>/embed``, ``<name>/L<k>/attn/…``,
     ``<name>/L<k>/{mlp,moe}/…``, ``<name>/pool``."""
     ids, doc, pos, seg = page[0], page[1], page[2], page[3]
     valid = doc >= 0
@@ -176,7 +184,8 @@ def page_forward(name: str, share: Share, num_experts: int, is_dense, attention,
         rows = segment_mean(rms_norm(x, params["final_norm"], eps, jnp.float32), seg, page_rows)
     zero = jnp.zeros((), jnp.int32)
     totals = [sum((c[i] for c in counters), zero) for i in (0, 1, 3)] + [zero + len(counters)]
-    return rows, jnp.concatenate([jnp.stack(totals)] + [c[2] for c in counters])
+    own = [] if page_counters is None else [page_counters.astype(jnp.int32)]
+    return rows, jnp.concatenate([jnp.stack(totals)] + own + [c[2] for c in counters])
 
 
 # --- checkpoint → the program's tree ------------------------------------------
@@ -211,8 +220,10 @@ def leaf_reader(read):
     return get, side_by_side
 
 
-def stack_mlp(p: dict, pre: str, dense: bool, experts: Sequence[int], get, side_by_side) -> None:
-    """Layer ``pre``'s dense unit, or its router, shared expert and held
+def stack_mlp(p: dict, pre: str, dense: bool, experts: Sequence[int], get, side_by_side,
+              gated_shared: bool = False) -> None:
+    """Layer ``pre``'s dense unit, or its router, shared expert (with its
+    per-token gate where the checkpoint has one: ``gated_shared``) and held
     experts (stacked on a leading axis in ``experts`` order), into ``p``:
     gate and up projections side by side, the products' own layout."""
     pair = ("gate_proj", "up_proj")
@@ -223,13 +234,15 @@ def stack_mlp(p: dict, pre: str, dense: bool, experts: Sequence[int], get, side_
     p["router"] = get(f"{pre}/router")
     p["shared_gate_up"] = side_by_side(f"{pre}/shared", pair)
     p["shared_down"] = get(f"{pre}/shared/down_proj")
+    if gated_shared:
+        p["shared_gate"] = get(f"{pre}/shared_gate")
     p["experts_gate_up"] = jnp.stack([side_by_side(f"{pre}/experts/{e}", pair) for e in experts])
     p["experts_down"] = jnp.stack([get(f"{pre}/experts/{e}/down_proj") for e in experts])
 
 
 def mlp_leaf_shapes(spec: Dict[str, Tuple[int, ...]], pre: str, hid: int, dense_width,
                     router_width: int, shared_width: int, expert_width: int,
-                    experts: Sequence[int]) -> None:
+                    experts: Sequence[int], gated_shared: bool = False) -> None:
     """The leaves :func:`stack_mlp` reads, into ``spec``; ``dense_width`` is
     None for a sparse layer."""
     def unit(prefix, width):
@@ -241,6 +254,8 @@ def mlp_leaf_shapes(spec: Dict[str, Tuple[int, ...]], pre: str, hid: int, dense_
         return
     spec[f"{pre}/router"] = (hid, router_width)
     unit(f"{pre}/shared", shared_width)
+    if gated_shared:
+        spec[f"{pre}/shared_gate"] = (hid, 1)
     for e in experts:
         unit(f"{pre}/experts/{e}", expert_width)
 
