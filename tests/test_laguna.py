@@ -10,10 +10,12 @@ mask or a wrong rope; the bfloat16 path itself is run once and held loosely.
 # fast-registry: page program compiles (both Pallas kernels in the interpreter)
 
 import functools
+import itertools
 import json
 import math
 import os
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -35,8 +37,9 @@ from video_features_tpu.models import laguna as model  # noqa: E402
 from video_features_tpu.models import text_layers  # noqa: E402
 from video_features_tpu.ops import moe  # noqa: E402
 from video_features_tpu.ops.segment_attention import first_key_block, segment_attention  # noqa: E402
-from video_features_tpu.parallel.pages import (DOC, IDS, POS, SEG, build_token_page,  # noqa: E402
-                                               fit_documents)
+from video_features_tpu.parallel.packer import CorpusPacker, PackSpec  # noqa: E402
+from video_features_tpu.parallel.pages import (DOC, IDS, PAGES_QUEUED, POS, SEG,  # noqa: E402
+                                               build_token_page, first_fit, fit_documents)
 from video_features_tpu.reliability import load_failures  # noqa: E402
 
 WIDTHS = dict(vocab_size=512, hidden_size=64, intermediate_size=128, num_key_value_heads=2,
@@ -49,7 +52,7 @@ REF_TINY["full_rope"] = dict(ref.PUBLISHED["full_rope"], original_max_position_e
 LAYERS = (0, 1, 2, 3, 4)
 HELD = (0, 1, 2, 3)  # a quarter of the 16 experts
 PAGE_TOKENS, BLOCK = 128, 16
-LENGTHS = (100, 37, 60, 120, 20)  # pages in arrival order, first fit: {100}, {37, 60}, {120}, {20}
+LENGTHS = (100, 37, 60, 120, 20)  # pages in this order: {100} once 256 tokens wait, then at the flush {37, 60, 20}, {120}
 
 
 def transcript(path, rng, tokens, lo=8, hi=14):
@@ -114,14 +117,16 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
                                                           corpus, monkeypatch):
     """Through ``Extractor.run`` on a corpus whose documents share pages, the
     ``.npy`` files against the plain reference; then the same documents one a
-    page (one run each on the same program): the same rows."""
+    page (one run each on the same program), and in the order that packs them
+    into other pages: the same rows."""
     directory, flat = checkpoint
     ex = extractor(tmp_path, "packed", directory, monkeypatch)
     assert ex.cfg.pack_corpus and ex.share == model.Share(LAYERS, HELD)
     assert ex.run(corpus) == len(corpus)
     stats = ex._pack_stats
-    assert stats["pages_dispatched"] == 4 and stats["real_slots"] == sum(LENGTHS)
-    assert stats["dispatched_slots"] == 4 * PAGE_TOKENS
+    assert stats["pages_dispatched"] == 3 and stats["real_slots"] == sum(LENGTHS)
+    assert stats["queued_documents"] == 4 + 4 + 1 and stats["pages_chosen"] == 0
+    assert stats["dispatched_slots"] == 3 * PAGE_TOKENS
     # routing counts: top-k assignments per REAL token and no pad token routed
     sparse = sum(1 for l in LAYERS if not TINY.is_dense(l))
     assert stats["routed_total"] == TINY.num_experts_per_tok * sum(LENGTHS) * sparse
@@ -146,6 +151,14 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
         assert ex._pack_stats["pages_dispatched"] == 1
         alone = read_out(str(tmp_path / "packed"), path)["laguna"]
         assert row_gaps(alone, packed[path]).max() < 2e-5
+
+    # the other way round the same documents pack as {20, 100}, {120}, {60, 37} (the first page
+    # passes 60 + 37 over for 100): a document's rows do not depend on the company it keeps
+    assert ex.run(corpus[::-1]) == len(corpus)
+    assert ex._pack_stats["pages_dispatched"] == 3 and ex._pack_stats["pages_chosen"] == 1
+    for path in corpus:
+        turned = read_out(str(tmp_path / "packed"), path)["laguna"]
+        assert row_gaps(turned, packed[path]).max() < 2e-5
 
 
 def test_bfloat16_path_and_a_transcript_too_long(tmp_path, tiny, checkpoint, corpus, monkeypatch):
@@ -331,12 +344,18 @@ def test_weight_specs_give_every_expert_matrix_its_true_fan_in():
     assert 0.8 <= scale.min() and scale.max() <= 1.2
 
 
-def test_token_pages_first_fit_and_planes():
+def test_token_pages_fit_and_planes():
     sizes = [(100, 9), (37, 4), (60, 6), (20, 2), (8, 1)]
-    assert fit_documents(sizes, 128, 16) == [0, 3, 4]       # 100 + 20 + 8: whole documents, first fit
+    assert fit_documents(sizes, 128, 16) == [0, 3, 4]       # the oldest, then 20 + 8: a full page
     assert fit_documents(sizes[1:], 128, 16) == [0, 1, 2, 3]
     assert fit_documents(sizes, 128, 10) == [0, 4]          # the table's rows bound a page too
     assert fit_documents([(129, 3)], 128, 16) == []
+    # where arrival order would take 37 + 20 + 8 beside the oldest, the best fit is 60 + 8
+    late = [(60, 6), (37, 4), (20, 2), (8, 1), (60, 2)]
+    assert first_fit(late, 128, 16) == [0, 1, 2, 3] and fit_documents(late, 128, 16) == [0, 3, 4]
+    assert fit_documents([(10, 1), (5, 1), (5, 1), (5, 1)], 20, 9) == [0, 1, 2]  # a tie: the newest waits
+    # 6 + 4 fills the page with fewer documents and one row too many
+    assert fit_documents([(10, 1), (6, 3), (4, 1), (3, 1), (3, 1)], 20, 4) == [0, 2, 3, 4]
     page, table = np.empty((4, 32), np.int32), np.empty((6, 3), np.int32)
     docs = [(7, np.arange(10, 22, dtype=np.int32), np.array([5, 12], np.int32)),
             (9, np.arange(3, dtype=np.int32), np.array([3], np.int32))]
@@ -348,6 +367,83 @@ def test_token_pages_first_fit_and_planes():
     np.testing.assert_array_equal(page[SEG, :16], [0] * 5 + [1] * 7 + [2] * 3 + [-1])
     assert (page[:, 15:] == np.array([[0], [-1], [0], [-1]])).all()
     np.testing.assert_array_equal(table, [[7, 0, 1], [7, 1, 1], [9, 0, 1]] + [[-1, -1, 0]] * 3)
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_fit_documents_against_every_subset(seed):
+    """Seeded queues small enough to try every subset that holds the first
+    document: the rule takes the first, keeps both bounds, no subset fits
+    fuller, and of the fullest it takes the one whose latest documents are
+    the earliest."""
+    rng = np.random.default_rng([seed, 0xF17])
+    for _ in range(20):
+        tokens, rows = int(rng.integers(16, 200)), int(rng.integers(3, 12))
+        few_rows = bool(rng.integers(2))  # half the queues: rows that bind
+        sizes = [(int(rng.integers(0, tokens + 8)), int(rng.integers(0, rows + 2 if few_rows else 3)))
+                 for _ in range(int(rng.integers(1, 10)))]
+        take = fit_documents(sizes, tokens, rows)
+        if sizes[0][0] > tokens or sizes[0][1] > rows:
+            assert take == []
+            continue
+        assert take[0] == 0 and take == sorted(set(take))
+        fits = {}  # every subset with the first document, within both bounds: its fill
+        for k in range(len(sizes)):
+            for others in itertools.combinations(range(1, len(sizes)), k):
+                subset = (0,) + others
+                fill = sum(sizes[i][0] for i in subset)
+                if fill <= tokens and sum(sizes[i][1] for i in subset) <= rows:
+                    fits[subset] = fill
+        assert tuple(take) in fits
+        fullest = [s for s, fill in fits.items() if fill == max(fits.values())]
+        assert tuple(take) == min(fullest, key=lambda s: s[::-1])
+
+
+def test_packer_fills_token_pages_from_two_pages_of_documents():
+    """A ``CorpusPacker`` with a token spec over the transcript corpus' 16
+    lengths, five seeded orders cycled three times each: every document goes
+    out once, no page goes before two pages' worth is queued unless ``flush()``
+    sends it, and a pass makes 6.2 pages at most in the mean (arrival order
+    alone makes 7 to 8; 5.62 pages hold the tokens)."""
+    page_tokens, page_rows, cycles = 16384, 2048, 3
+    lengths = [int(round(1024 * 16 ** (i / 15))) for i in range(16)]
+    pages_made, chosen = [], []
+    for seed in range(5):
+        order = [lengths[i] for i in np.random.default_rng([seed, 0xD0C5]).permutation(16)] * cycles
+        sent, flushing = [], [False]
+
+        def paged_step(page, table):
+            sent.append((page.copy(), table.copy(), flushing[0],
+                         sum(len(s.clip.ids) for s in packer._pending[key])))
+            return table[:, :1].astype(np.float32), table
+
+        packer = CorpusPacker(PackSpec(
+            batch_size=page_rows, empty_row_shape=(1,), open_clips=None, step=None, finalize=None,
+            paged_step=paged_step, page_rows=page_rows, page_tokens=page_tokens), wait=np.asarray)
+        key = (None, ("tokens", page_tokens))
+        for v, n in enumerate(order):
+            ends = np.arange(32, n + 32, 32).clip(max=n).astype(np.int32)
+            packer.begin(f"v{v}", {})
+            packer.add(f"v{v}", types.SimpleNamespace(ids=np.full(n, v, np.int32), segment_ends=ends))
+            packer.finish(f"v{v}")
+        flushing[0] = True
+        packer.flush()
+
+        done = {a.video: a for a in packer.pop_completed()}
+        assert sorted(done) == sorted(f"v{v}" for v in range(len(order)))
+        slots = np.concatenate([page[IDS][page[DOC] >= 0] for page, *_ in sent])
+        np.testing.assert_array_equal(np.bincount(slots), order)  # each document's tokens, once
+        for v, n in enumerate(order):  # and its rows came back from the page that held it
+            rows = done[f"v{v}"].stacked((1,))[0]
+            assert rows.shape == (-(-n // 32), 1) and (rows == packer._video_ids[f"v{v}"]).all()
+        for page, _table, flushed, left in sent:
+            real = int((page[DOC] >= 0).sum())
+            assert flushed or real + left >= PAGES_QUEUED * page_tokens
+        assert packer.pages_dispatched == len(sent) and packer.real_slots == sum(order)
+        assert packer.queued_documents >= sum(len(np.unique(page[IDS][page[DOC] >= 0])) for page, *_ in sent)
+        pages_made.append(len(sent))
+        chosen.append(packer.pages_chosen)  # some orders need no choice: two pages' worth in order is enough
+    assert sum(pages_made) / (5 * cycles) <= 6.2, pages_made
+    assert all(c <= n for c, n in zip(chosen, pages_made)) and sum(chosen) > 5
 
 
 def test_daemon_serves_the_type(tmp_path, tiny, checkpoint, corpus, monkeypatch):

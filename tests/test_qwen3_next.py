@@ -53,8 +53,9 @@ REF_TINY = dict(ref.PUBLISHED, **WIDTHS)
 LAYERS = (0, 1, 2, 3)
 HELD = (0, 1, 2, 3)  # a quarter of the 16 experts
 PAGE_TOKENS, BLOCK = 128, 16
-# pages in arrival order, first fit: {100}, {37, 60}, {120}, {20}: with chunks
-# of 64, documents that cross a chunk's edge and one that starts inside a chunk
+# pages in this order: {100} once 256 tokens wait, then at the flush {37, 60, 20},
+# {120}: with chunks of 64, a document that crosses a chunk's edge and one that
+# starts inside a chunk
 LENGTHS = (100, 37, 60, 120, 20)
 
 
@@ -125,8 +126,8 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
                                                           corpus, monkeypatch):
     """Through ``Extractor.run`` on a corpus whose documents share pages, the
     ``.npy`` files against the plain reference; then the same documents one a
-    page: the same rows (a document packed mid-page equals the document
-    alone). Two planted faults are held apart: the reference with its state
+    page, and in the order that packs them into other pages: the same rows (a
+    document packed mid-page equals the document alone). Two planted faults are held apart: the reference with its state
     dropped every 16 tokens, and with ``δ = βv``, are each far from the
     program."""
     directory, flat = checkpoint
@@ -135,16 +136,17 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
     assert ex.share == model.Share(LAYERS, HELD)
     assert ex.run(corpus) == len(corpus)
     stats = ex._pack_stats
-    assert stats["pages_dispatched"] == 4 and stats["real_slots"] == sum(LENGTHS)
+    assert stats["pages_dispatched"] == 3 and stats["real_slots"] == sum(LENGTHS)
+    assert stats["queued_documents"] == 4 + 4 + 1 and stats["pages_chosen"] == 0
     assert stats["routed_total"] == TINY.num_experts_per_tok * sum(LENGTHS) * len(LAYERS)
     assert stats["routed_held"] == int(np.sum(stats["expert_rows"])) < stats["routed_total"]
     assert np.asarray(stats["expert_rows"]).shape == (len(LAYERS), len(HELD))
-    assert stats["expert_chunks"] >= stats["expert_chunk_calls"] == len(LAYERS) * 4
-    # three linear layers walk two chunks of 64 in each of four pages; every
-    # chunk but page {120}'s first... holds a start or pads: {100}: both (the
-    # start, the pads), {37, 60}: both, {120}: both, {20}: both
-    assert stats["gdn_chunks"] == 3 * 2 * 4
-    assert stats["gdn_boundary_chunks"] == 3 * (2 + 2 + 2 + 2)
+    assert stats["expert_chunks"] >= stats["expert_chunk_calls"] == len(LAYERS) * 3
+    # three linear layers walk two chunks of 64 in each of three pages; every
+    # chunk holds a start or pads: {100}: both (the start, the pads),
+    # {37, 60, 20}: both (two starts; a start and the pads), {120}: both
+    assert stats["gdn_chunks"] == 3 * 2 * 3
+    assert stats["gdn_boundary_chunks"] == 3 * (2 + 2 + 2)
 
     tree = {k: unflatten(v) for k, v in flat.items()}
     answer = ref.make_answer_fn(tree, REF_TINY)
@@ -170,6 +172,14 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
         assert ex._pack_stats["pages_dispatched"] == 1
         alone = read_out(str(tmp_path / "packed"), path)["qwen3_next"]
         assert row_gaps(alone, packed[path]).max() < 2e-5
+
+    # the other way round the same documents pack as {20, 100}, {120}, {60, 37} (the first page
+    # passes 60 + 37 over for 100): a document's rows do not depend on the company it keeps
+    assert ex.run(corpus[::-1]) == len(corpus)
+    assert ex._pack_stats["pages_dispatched"] == 3 and ex._pack_stats["pages_chosen"] == 1
+    for path in corpus:
+        turned = read_out(str(tmp_path / "packed"), path)["qwen3_next"]
+        assert row_gaps(turned, packed[path]).max() < 2e-5
 
 
 def test_bfloat16_path(tmp_path, tiny, checkpoint, corpus, monkeypatch):

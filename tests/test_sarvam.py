@@ -50,7 +50,7 @@ REF_TINY["rope_scaling"] = dict(ref.PUBLISHED["rope_scaling"], original_max_posi
 LAYERS = (0, 1, 2, 3, 4)
 HELD = (0, 1)  # an eighth of the 16 experts
 PAGE_TOKENS, BLOCK = 128, 16
-LENGTHS = (100, 37, 60, 120, 20)  # pages in arrival order, first fit: {100}, {37, 60}, {120}, {20}
+LENGTHS = (100, 37, 60, 120, 20)  # pages in this order: {100} once 256 tokens wait, then at the flush {37, 60, 20}, {120}
 
 
 def transcript(path, rng, tokens, lo=8, hi=14):
@@ -116,15 +116,17 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
                                                           corpus, monkeypatch):
     """Through ``Extractor.run`` on a corpus whose documents share pages, the
     ``.npy`` files against the plain reference; then the same documents one a
-    page: the same rows. The reference WITHOUT the shared rope term is far
-    from both: the comparison sees MLA's own fault."""
+    page, and in the order that packs them into other pages: the same rows.
+    The reference WITHOUT the shared rope term is far from both: the
+    comparison sees MLA's own fault."""
     directory, flat = checkpoint
     ex = extractor(tmp_path, "packed", directory, monkeypatch)
     assert ex.cfg.pack_corpus and ex.cfg.num_devices == 1
     assert ex.share == model.Share(LAYERS, HELD)
     assert ex.run(corpus) == len(corpus)
     stats = ex._pack_stats
-    assert stats["pages_dispatched"] == 4 and stats["real_slots"] == sum(LENGTHS)
+    assert stats["pages_dispatched"] == 3 and stats["real_slots"] == sum(LENGTHS)
+    assert stats["queued_documents"] == 4 + 4 + 1 and stats["pages_chosen"] == 0
     sparse = sum(1 for l in LAYERS if not TINY.is_dense(l))
     assert stats["routed_total"] == TINY.num_experts_per_tok * sum(LENGTHS) * sparse
     assert stats["routed_held"] == int(np.sum(stats["expert_rows"])) < stats["routed_total"]
@@ -153,6 +155,14 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
         assert ex._pack_stats["pages_dispatched"] == 1
         alone = read_out(str(tmp_path / "packed"), path)["sarvam"]
         assert row_gaps(alone, packed[path]).max() < 2e-5
+
+    # the other way round the same documents pack as {20, 100}, {120}, {60, 37} (the first page
+    # passes 60 + 37 over for 100): a document's rows do not depend on the company it keeps
+    assert ex.run(corpus[::-1]) == len(corpus)
+    assert ex._pack_stats["pages_dispatched"] == 3 and ex._pack_stats["pages_chosen"] == 1
+    for path in corpus:
+        turned = read_out(str(tmp_path / "packed"), path)["sarvam"]
+        assert row_gaps(turned, packed[path]).max() < 2e-5
 
 
 def test_bfloat16_path(tmp_path, tiny, checkpoint, corpus, monkeypatch):

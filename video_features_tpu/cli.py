@@ -194,8 +194,10 @@ def build_parser() -> argparse.ArgumentParser:
                              "compute)")
     parser.add_argument("--page_tokens", type=int, default=16384,
                         help="laguna, sarvam, qwen3_next: token slots of one device page (whole "
-                             "transcripts share a page first-fit; a longer "
-                             "transcript is refused); a multiple of 512")
+                             "transcripts share a page: the oldest queued and, "
+                             "of two pages' worth, the others that fill it "
+                             "best; a longer transcript is refused); a "
+                             "multiple of 512")
     parser.add_argument("--pack_flush_age", type=int, default=8,
                         help="--pack_corpus anti-starvation flush: dispatch a "
                              "bucket's partial queue once this many videos "

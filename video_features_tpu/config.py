@@ -134,7 +134,8 @@ class ExtractionConfig:
     # rows stay at one bucketed batch regardless of depth).
     pages_in_flight: int = 2
     # laguna, sarvam, qwen3_next (the text stream): token slots of one device page. A page holds
-    # whole transcripts first-fit, so this is also the longest transcript the
+    # whole transcripts (the oldest queued, and of two pages' worth the others
+    # that fill it best), so this is also the longest transcript the
     # type takes; a multiple of the attention kernel's block of 512. One
     # program per value; which transcripts share a page moves a row by
     # rounding only (docs/models/laguna.md).

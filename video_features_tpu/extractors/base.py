@@ -1033,9 +1033,14 @@ class Extractor(abc.ABC):
             # observed in-flight ring (tests/test_paged.py)
             "pages_dispatched": packer.pages_dispatched,
             "max_in_flight": packer.max_in_flight,
-            # token pages: table rows dispatched, and what the model counted
-            # on the device (the text stream's routing counters)
+            # token pages: table rows dispatched, the documents each page had
+            # to choose from (summed) and the pages that chose other than
+            # arrival order would have (parallel/pages.py::fit_documents),
+            # and what the model counted on the device (the text stream's
+            # routing counters)
             "segments": packer.segments,
+            "queued_documents": packer.queued_documents,
+            "pages_chosen": packer.pages_chosen,
             **self._extra_pack_stats(),
             # per-stage wall seconds for the whole corpus, the writer's
             # counters and — with recording on — the span records

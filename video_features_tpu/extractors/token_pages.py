@@ -12,7 +12,8 @@ it reads no frames; transcripts are read in the decode pool's place, under the
 same ``decode``/``pull`` spans.
 
 The only path is the packed one: a page of ``page_tokens`` token slots holds
-whole transcripts first-fit (``parallel/packer.py``), one compiled page program
+whole transcripts, the oldest queued and the others that fill it best, chosen
+from two pages' worth (``parallel/pages.py::fit_documents``), one compiled page program
 (the model's ``forward``: embed → the layers the checkpoint holds → final
 norm → segment mean) takes the weights as arguments, and a transcript longer
 than a page is a permanent error of its video. The checkpoint's leaf names say

@@ -92,8 +92,8 @@ from ..io.output import FeatureAssembly
 from ..reliability.faults import fault_point
 from ..utils.metrics import span as bare_span
 from .pipeline import pad_batch
-from .pages import (TABLE_COLS, TOKEN_PLANES, build_row_table,
-                    build_token_page, fit_documents)
+from .pages import (PAGES_QUEUED, TABLE_COLS, TOKEN_PLANES, build_row_table,
+                    build_token_page, first_fit, fit_documents)
 
 
 @dataclass
@@ -144,12 +144,14 @@ class PackSpec:
     ``page_tokens``, when set (with ``paged_step``), makes the model's pages
     **token pages**: a slot is one whole *document* (``open_clips`` yields
     one object with ``ids`` and cumulative ``segment_ends`` per video) instead
-    of one fixed-shape array, a page holds as many whole documents as fit its
-    ``page_tokens`` token slots and ``page_rows`` table rows (first-fit over
-    the queue), and a document's output is its block of segment rows. One
-    queue and one compiled program whatever the lengths; ``real_slots`` and
-    ``dispatched_slots`` count tokens. A document that cannot fit an empty
-    page is the model's to refuse in ``open_clips``.
+    of one fixed-shape array, a page goes once two pages' worth of documents
+    are queued (``pages.PAGES_QUEUED``; a flush sends what there is) and holds
+    the oldest of them and the others that fill its ``page_tokens`` token
+    slots best within its ``page_rows`` table rows
+    (:func:`.pages.fit_documents`), and a document's output is its block of
+    segment rows. One queue and one compiled program whatever the lengths;
+    ``real_slots`` and ``dispatched_slots`` count tokens. A document that
+    cannot fit an empty page is the model's to refuse in ``open_clips``.
     """
 
     batch_size: int
@@ -347,6 +349,11 @@ class CorpusPacker:
         self.staged_bytes = 0  # host bytes staged per dispatched device batch
         self.pages_dispatched = 0  # paged-mode dispatches (stats)
         self.segments = 0  # token pages: table rows (segments) dispatched
+        # token pages: documents queued at each dispatch, summed (what the
+        # pages had to choose from), and pages whose documents are not the
+        # ones arrival order alone would have put there (pages.first_fit)
+        self.queued_documents = 0
+        self.pages_chosen = 0
         self.max_in_flight = 0  # deepest observed in-flight ring (any key)
         self.video_clips: Dict[str, int] = {}  # per finished video
         # per shape key: {"real_slots", "dispatched_slots", "stale_flushes"}
@@ -437,11 +444,14 @@ class CorpusPacker:
             return False
         spec = self._spec_for(key)
         if spec.page_tokens:
-            # a token page goes when the queued documents fill it, or
-            # overflow it (the ones that do not fit wait for the next page)
-            return (sum(len(s.clip.ids) for s in queue) >= spec.page_tokens
+            # a token page goes when the queued documents would fill
+            # PAGES_QUEUED pages: fit_documents then has that much to choose
+            # from (what it leaves waits for the next page). A function of
+            # the arrival order alone, whatever the ring holds
+            return (sum(len(s.clip.ids) for s in queue)
+                    >= PAGES_QUEUED * spec.page_tokens
                     or sum(len(s.clip.segment_ends) for s in queue)
-                    >= self._batch_rows(spec))
+                    >= PAGES_QUEUED * self._batch_rows(spec))
         return len(queue) >= self._batch_rows(spec)
 
     def _pump(self) -> None:
@@ -545,14 +555,17 @@ class CorpusPacker:
                 slots = candidates[:n_used]
                 del queue[:n_used]  # in place: flush() iterates this same list
             elif spec.page_tokens:
-                take = fit_documents(
-                    [(len(s.clip.ids), len(s.clip.segment_ends)) for s in queue],
-                    spec.page_tokens, batch_size)
+                sizes = [(len(s.clip.ids), len(s.clip.segment_ends))
+                         for s in queue]
+                take = fit_documents(sizes, spec.page_tokens, batch_size)
                 if not take:
                     raise ValueError(
                         f"a document of {len(queue[0].clip.ids)} tokens and "
                         f"{len(queue[0].clip.segment_ends)} segments fits no "
                         f"page of {spec.page_tokens} tokens and {batch_size} rows")
+                self.queued_documents += len(queue)
+                self.pages_chosen += take != first_fit(
+                    sizes, spec.page_tokens, batch_size)
                 slots = [queue[i] for i in take]
                 taken = set(take)
                 queue[:] = [s for i, s in enumerate(queue) if i not in taken]

@@ -38,10 +38,12 @@ Three pieces live here; the dispatch mechanics stay in
 down: the page is ``page_tokens`` token slots filled with WHOLE documents of
 different lengths, each token carrying its document, its position in it and
 the table row of its segment, and the row table names the page's output rows:
-``(video id, segment idx, valid)``. :func:`fit_documents` chooses the
-documents first-fit, :func:`build_token_page` fills page and table, and the
-model's own forward masks pads (no epilogue multiply: a pad token must not be
-attended to, routed or averaged, which only the model can see to).
+``(video id, segment idx, valid)``. A page goes once ``PAGES_QUEUED`` pages'
+worth of documents wait (:meth:`.packer.CorpusPacker._full`);
+:func:`fit_documents` takes the oldest of them and the subset of the others
+that fills the page best, :func:`build_token_page` fills page and table, and
+the model's own forward masks pads (no epilogue multiply: a pad token must not
+be attended to, routed or averaged, which only the model can see to).
 
 Host scatter never reads the table (slots carry their assembly references —
 slot-level fault attribution is unchanged); the table is the device-side
@@ -92,21 +94,71 @@ def build_row_table(entries: Sequence[Tuple[int, int]], page_rows: int,
 # token-page planes: page[plane, slot], int32
 TOKEN_PLANES = 4
 IDS, DOC, POS, SEG = range(TOKEN_PLANES)
+# a token page goes once this many pages' worth of documents (tokens or table
+# rows) are queued: what fit_documents has to choose from. The ring's default
+# depth (PackSpec.pages_in_flight): in a device-bound loop the device holds
+# that much work already, so the wait is the host's to spend
+PAGES_QUEUED = 2
 
 
 def fit_documents(sizes: Sequence[Tuple[int, int]], page_tokens: int,
                   page_rows: int) -> list:
-    """First-fit: indices (in queue order) of the ``(tokens, segments)``
-    documents that go into the next page — each one that still fits what the
-    ones before it left, in both tokens and table rows."""
+    """Indices (in queue order) of the ``(tokens, segments)`` documents that
+    go into the next page: always the first (the oldest never waits for a
+    better page; ``[]`` when it fits no empty page), then the subset of the
+    others that leaves the fewest free token slots within both bounds, tokens
+    and table rows; among equal fills the one of the earliest documents (its
+    latest document is the earliest, then its latest but one, …: the newest
+    wait).
+
+    An exact subset sum over the fills that can be reached, one pass over the
+    free slots a queued document: ``least[j][t]`` is the fewest table rows
+    with which the first ``j`` candidates fill exactly ``t`` slots (fewer
+    rows at one fill never shut out a later document, so the least is all a
+    fill has to remember), and the pass stops at a candidate that can
+    complete a full page. On the chip machine's host 0.04–0.06 ms a page in
+    the mean and 0.43 at most for the five to nine documents a 16,384-token
+    page of the transcript corpus chooses from (PERF.md §6, PR 41), inside
+    the page's ``stage`` span; the cost grows with candidates × free slots (a
+    page of a thousand 15-token documents: 160 ms and 70 MB of tables on a
+    sandbox CPU, a seventh of what reading and writing that many files
+    takes)."""
+    if not sizes or sizes[0][0] > page_tokens or sizes[0][1] > page_rows:
+        return []
+    free, rows = page_tokens - sizes[0][0], page_rows - sizes[0][1]
+    rest = [i for i in range(1, len(sizes))
+            if sizes[i][0] <= free and sizes[i][1] <= rows]
+    least = [np.full(free + 1, rows + 1, np.int32)]  # rows + 1: no such fill
+    least[0][0] = 0
+    for i in rest:
+        n, s = sizes[i]
+        with_i = least[-1].copy()
+        np.minimum(with_i[n:], least[-1][:free + 1 - n] + s, out=with_i[n:])
+        least.append(with_i)
+        if with_i[free] <= rows:
+            break  # a full page: no later document betters it
+    fill = int(np.flatnonzero(least[-1] <= rows)[-1])
+    take = []
+    for j in reversed(range(len(least) - 1)):
+        if least[j][fill] > rows:  # no such fill without candidate j
+            n, s = sizes[rest[j]]
+            take.append(rest[j])
+            fill -= n
+            rows -= s
+    return [0] + take[::-1]
+
+
+def first_fit(sizes: Sequence[Tuple[int, int]], page_tokens: int,
+              page_rows: int) -> list:
+    """What arrival order alone would put in the page: each document that
+    still fits what the ones before it left. Only counted against
+    (``pages_chosen``: pages whose take differs from this)."""
     take, tokens, rows = [], 0, 0
     for i, (n, s) in enumerate(sizes):
         if tokens + n <= page_tokens and rows + s <= page_rows:
             take.append(i)
             tokens += n
             rows += s
-            if tokens == page_tokens or rows == page_rows:
-                break
     return take
 
 
