@@ -74,6 +74,7 @@ _FAST_MODULES = {
     "test_resample",
     "test_resnet_extractor",
     "test_service",
+    "test_setup_metrics",
     "test_spatial",
     "test_vftlint",
     "test_video_decode",

@@ -39,7 +39,7 @@ from video_features_tpu.ops import moe  # noqa: E402
 from video_features_tpu.ops.segment_attention import first_key_block, segment_attention  # noqa: E402
 from video_features_tpu.parallel.packer import CorpusPacker, PackSpec  # noqa: E402
 from video_features_tpu.parallel.pages import (DOC, IDS, PAGES_QUEUED, POS, SEG,  # noqa: E402
-                                               build_token_page, first_fit, fit_documents)
+                                               build_token_page, fit_documents)
 from video_features_tpu.reliability import load_failures  # noqa: E402
 
 WIDTHS = dict(vocab_size=512, hidden_size=64, intermediate_size=128, num_key_value_heads=2,
@@ -106,6 +106,12 @@ def corpus(tmp_path_factory):
     return [transcript(str(d / f"v{i}.tokens.npz"), rng, n) for i, n in enumerate(LENGTHS)]
 
 
+def page_documents(stats):
+    """Each token page's documents (their lengths), page by page: what its
+    ``stage`` span recorded."""
+    return [r["ids"]["documents"] for r in stats["spans"]["records"] if "documents" in r["ids"]]
+
+
 def extractor(tmp_path, sub, checkpoint_dir, monkeypatch, **kw):
     monkeypatch.setenv("VFT_CHECKPOINT_DIR", checkpoint_dir)
     return get_extractor(ExtractionConfig(
@@ -120,12 +126,13 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
     page (one run each on the same program), and in the order that packs them
     into other pages: the same rows."""
     directory, flat = checkpoint
+    monkeypatch.setenv("VFT_METRICS", "1")  # the stage records say what each page held
     ex = extractor(tmp_path, "packed", directory, monkeypatch)
     assert ex.cfg.pack_corpus and ex.share == model.Share(LAYERS, HELD)
     assert ex.run(corpus) == len(corpus)
     stats = ex._pack_stats
     assert stats["pages_dispatched"] == 3 and stats["real_slots"] == sum(LENGTHS)
-    assert stats["queued_documents"] == 4 + 4 + 1 and stats["pages_chosen"] == 0
+    assert page_documents(stats) == [[100], [37, 60, 20], [120]]
     assert stats["dispatched_slots"] == 3 * PAGE_TOKENS
     # routing counts: top-k assignments per REAL token and no pad token routed
     sparse = sum(1 for l in LAYERS if not TINY.is_dense(l))
@@ -155,7 +162,7 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
     # the other way round the same documents pack as {20, 100}, {120}, {60, 37} (the first page
     # passes 60 + 37 over for 100): a document's rows do not depend on the company it keeps
     assert ex.run(corpus[::-1]) == len(corpus)
-    assert ex._pack_stats["pages_dispatched"] == 3 and ex._pack_stats["pages_chosen"] == 1
+    assert page_documents(ex._pack_stats) == [[20, 100], [120], [60, 37]]
     for path in corpus:
         turned = read_out(str(tmp_path / "packed"), path)["laguna"]
         assert row_gaps(turned, packed[path]).max() < 2e-5
@@ -344,6 +351,18 @@ def test_weight_specs_give_every_expert_matrix_its_true_fan_in():
     assert 0.8 <= scale.min() and scale.max() <= 1.2
 
 
+def first_fit(sizes, page_tokens, page_rows):
+    """The oracle of arrival order alone: each document that still fits what
+    the ones before it left (the packer's rule before PR 41)."""
+    take, tokens, rows = [], 0, 0
+    for i, (n, s) in enumerate(sizes):
+        if tokens + n <= page_tokens and rows + s <= page_rows:
+            take.append(i)
+            tokens += n
+            rows += s
+    return take
+
+
 def test_token_pages_fit_and_planes():
     sizes = [(100, 9), (37, 4), (60, 6), (20, 2), (8, 1)]
     assert fit_documents(sizes, 128, 16) == [0, 3, 4]       # the oldest, then 20 + 8: a full page
@@ -412,8 +431,8 @@ def test_packer_fills_token_pages_from_two_pages_of_documents():
         sent, flushing = [], [False]
 
         def paged_step(page, table):
-            sent.append((page.copy(), table.copy(), flushing[0],
-                         sum(len(s.clip.ids) for s in packer._pending[key])))
+            left = [int(s.clip.ids[0]) for s in packer._pending[key]]  # the documents still queued
+            sent.append((page.copy(), table.copy(), flushing[0], left))
             return table[:, :1].astype(np.float32), table
 
         packer = CorpusPacker(PackSpec(
@@ -435,13 +454,20 @@ def test_packer_fills_token_pages_from_two_pages_of_documents():
         for v, n in enumerate(order):  # and its rows came back from the page that held it
             rows = done[f"v{v}"].stacked((1,))[0]
             assert rows.shape == (-(-n // 32), 1) and (rows == packer._video_ids[f"v{v}"]).all()
+        n_chosen = 0
         for page, _table, flushed, left in sent:
             real = int((page[DOC] >= 0).sum())
-            assert flushed or real + left >= PAGES_QUEUED * page_tokens
+            assert flushed or real + sum(order[v] for v in left) >= PAGES_QUEUED * page_tokens
+            # the queue at the dispatch, in arrival order: the page's documents and those left
+            held = sorted(int(v) for v in np.unique(page[IDS][page[DOC] >= 0]))
+            queue = sorted(held + left)
+            sizes = [(order[v], -(-order[v] // 32)) for v in queue]
+            take = [queue.index(v) for v in held]
+            assert take == fit_documents(sizes, page_tokens, page_rows)
+            n_chosen += take != first_fit(sizes, page_tokens, page_rows)
         assert packer.pages_dispatched == len(sent) and packer.real_slots == sum(order)
-        assert packer.queued_documents >= sum(len(np.unique(page[IDS][page[DOC] >= 0])) for page, *_ in sent)
         pages_made.append(len(sent))
-        chosen.append(packer.pages_chosen)  # some orders need no choice: two pages' worth in order is enough
+        chosen.append(n_chosen)  # some orders need no choice: two pages' worth in order is enough
     assert sum(pages_made) / (5 * cycles) <= 6.2, pages_made
     assert all(c <= n for c, n in zip(chosen, pages_made)) and sum(chosen) > 5
 

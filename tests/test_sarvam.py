@@ -105,6 +105,12 @@ def corpus(tmp_path_factory):
     return [transcript(str(d / f"v{i}.tokens.npz"), rng, n) for i, n in enumerate(LENGTHS)]
 
 
+def page_documents(stats):
+    """Each token page's documents (their lengths), page by page: what its
+    ``stage`` span recorded."""
+    return [r["ids"]["documents"] for r in stats["spans"]["records"] if "documents" in r["ids"]]
+
+
 def extractor(tmp_path, sub, checkpoint_dir, monkeypatch, **kw):
     monkeypatch.setenv("VFT_CHECKPOINT_DIR", checkpoint_dir)
     return get_extractor(ExtractionConfig(
@@ -120,13 +126,14 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
     The reference WITHOUT the shared rope term is far from both: the
     comparison sees MLA's own fault."""
     directory, flat = checkpoint
+    monkeypatch.setenv("VFT_METRICS", "1")  # the stage records say what each page held
     ex = extractor(tmp_path, "packed", directory, monkeypatch)
     assert ex.cfg.pack_corpus and ex.cfg.num_devices == 1
     assert ex.share == model.Share(LAYERS, HELD)
     assert ex.run(corpus) == len(corpus)
     stats = ex._pack_stats
     assert stats["pages_dispatched"] == 3 and stats["real_slots"] == sum(LENGTHS)
-    assert stats["queued_documents"] == 4 + 4 + 1 and stats["pages_chosen"] == 0
+    assert page_documents(stats) == [[100], [37, 60, 20], [120]]
     sparse = sum(1 for l in LAYERS if not TINY.is_dense(l))
     assert stats["routed_total"] == TINY.num_experts_per_tok * sum(LENGTHS) * sparse
     assert stats["routed_held"] == int(np.sum(stats["expert_rows"])) < stats["routed_total"]
@@ -159,7 +166,7 @@ def test_program_matches_reference_and_packing_keeps_rows(tmp_path, tiny, float3
     # the other way round the same documents pack as {20, 100}, {120}, {60, 37} (the first page
     # passes 60 + 37 over for 100): a document's rows do not depend on the company it keeps
     assert ex.run(corpus[::-1]) == len(corpus)
-    assert ex._pack_stats["pages_dispatched"] == 3 and ex._pack_stats["pages_chosen"] == 1
+    assert page_documents(ex._pack_stats) == [[20, 100], [120], [60, 37]]
     for path in corpus:
         turned = read_out(str(tmp_path / "packed"), path)["sarvam"]
         assert row_gaps(turned, packed[path]).max() < 2e-5

@@ -44,6 +44,7 @@ DEFAULT_TIER: Dict[str, str] = {
     "test_pwc": "the decoder's dense block by source; whole-model parity stays slow, test by test",
     "test_resnet": "resnet50 forward parity (heavy compile)",
     "test_segmented_decode": "real-sleep pool concurrency + e2e parity runs",
+    "test_setup_spans": "page program compiles (Pallas kernels in the interpreter)",
     "test_vggish": "vggish DSP + forward parity",
     "test_weights_store": "checkpoint store roundtrips",
     "test_windows": "pre-dates the fast registry; re-tier on the next sweep",

@@ -60,8 +60,12 @@ from ..utils.metrics import (
     decode_starvation_warning,
     maybe_profiler,
     metrics_enabled,
+    setup_recorder,
+    setup_report,
+    setup_span,
     span,
 )
+from ..weights.store import load_weights, resolve_params
 
 
 # Active only while the multi-model serving layer (MultiModelSessions)
@@ -83,7 +87,17 @@ def _shared_construction(**resources):
         _CONSTRUCTION_SHARING.clear()
 
 
-class Extractor(abc.ABC):
+class _Constructed(abc.ABCMeta):
+    """An extractor's whole construction, its subclass's included, is one
+    ``construct`` set-up span: the mesh, the checkpoint and the jitted
+    wrappers are built inside it (docs/observability.md "Set-up")."""
+
+    def __call__(cls, cfg, *args, **kwargs):
+        with setup_span("construct", model=cfg.feature_type):
+            return super().__call__(cfg, *args, **kwargs)
+
+
+class Extractor(abc.ABC, metaclass=_Constructed):
     """Base class for all per-model pipelines."""
 
     # True for models that consume the open_video frame stream (resnet50, flow,
@@ -124,6 +138,7 @@ class Extractor(abc.ABC):
         # open with the run resources
         self.clock = StageClock()
         self._recorder: Optional[SpanRecorder] = None
+        self._setup_reported = False  # the stage report's one set-up line
         # telemetry (docs/observability.md): the span/event journal
         # (--telemetry_dir) and the metrics registry. Opened by
         # _open_telemetry (run resources); a co-loaded serving model shares
@@ -429,6 +444,14 @@ class Extractor(abc.ABC):
         with self._span("put", stage="transfer", nbytes=int(arr.nbytes)):
             return self.runner.put(arr)
 
+    def _load_params(self, name: str, **resolve):
+        """A checkpoint's param tree (:func:`..weights.store.resolve_params`)
+        placed on the mesh, replicated, ONCE, under its ``load_weights``
+        set-up span."""
+        with load_weights(name) as load:
+            tree = load.read(resolve_params, name, **resolve)
+            return load.place(self.runner.put_replicated(tree))
+
     def _put_replicated(self, arr):
         """Replicated transfer with the same 'transfer' attribution. Bytes
         count the HOST payload once (the replication fan-out across devices
@@ -491,10 +514,28 @@ class Extractor(abc.ABC):
         self._open_run_resources()
         try:
             if pack is not None:
-                return self._run_packed(pack, paths, done, with_metrics, progress)
-            return self._run_loop(paths, done, with_metrics, progress)
+                ok = self._run_packed(pack, paths, done, with_metrics, progress)
+            else:
+                ok = self._run_loop(paths, done, with_metrics, progress)
         finally:
             self._close_run_resources()
+        if with_metrics and not self._setup_reported:
+            self._setup_reported = True
+            print(setup_report(self._pack_stats["setup"]))
+        return ok
+
+    @contextlib.contextmanager
+    def _run_span(self):
+        """The ``run`` span, and its one record in the set-up recorder: the
+        last ``run`` there is the window's, and what ended before it began
+        is set-up."""
+        recorder = setup_recorder()
+        index = recorder.begin("run", {"model": self.feature_type})
+        try:
+            with self._span("run") as handle:
+                yield handle
+        finally:
+            recorder.end(index)
 
     def _resolve_decode_workers(self) -> int:
         """``--decode_workers 0`` = auto (ROADMAP item 4, first step).
@@ -873,7 +914,7 @@ class Extractor(abc.ABC):
         t_run = time.perf_counter()
         stage_seconds: Dict[str, float] = {}  # summed over the per-video clocks
 
-        with maybe_profiler(self.cfg.profile_dir), self._span("run"):
+        with maybe_profiler(self.cfg.profile_dir), self._run_span():
             for n, path in enumerate(paths, start=1):
                 if os.path.abspath(path) in done:
                     self._ok += 1
@@ -984,7 +1025,7 @@ class Extractor(abc.ABC):
         self._pending_writes.clear()
         t_run = time.perf_counter()
 
-        with maybe_profiler(self.cfg.profile_dir), self._span("run"):
+        with maybe_profiler(self.cfg.profile_dir), self._run_span():
             for n, path in enumerate(paths, start=1):
                 if os.path.abspath(path) in done:
                     self._ok += 1
@@ -1033,14 +1074,9 @@ class Extractor(abc.ABC):
             # observed in-flight ring (tests/test_paged.py)
             "pages_dispatched": packer.pages_dispatched,
             "max_in_flight": packer.max_in_flight,
-            # token pages: table rows dispatched, the documents each page had
-            # to choose from (summed) and the pages that chose other than
-            # arrival order would have (parallel/pages.py::fit_documents),
-            # and what the model counted on the device (the text stream's
-            # routing counters)
+            # token pages: table rows dispatched, and what the model counted
+            # on the device (the text stream's routing counters)
             "segments": packer.segments,
-            "queued_documents": packer.queued_documents,
-            "pages_chosen": packer.pages_chosen,
             **self._extra_pack_stats(),
             # per-stage wall seconds for the whole corpus, the writer's
             # counters and — with recording on — the span records
@@ -1075,12 +1111,14 @@ class Extractor(abc.ABC):
 
     def _run_stats(self, stage_seconds: Dict[str, float]) -> Dict:
         """What every run leaves in ``_pack_stats`` whatever its loop: the
-        stage clock's seconds, the writer's counters and — with recording on
-        — ``spans``: ``{"clock": "time_ns", "records", "self_seconds",
+        stage clock's seconds, the writer's counters, the process's set-up
+        records (``setup``, whatever the switch says) and — with recording on
+        — ``spans``; both ``{"clock": "time_ns", "records", "self_seconds",
         "dropped"}`` (:meth:`..utils.metrics.SpanRecorder.export`)."""
         stats = {"stage_seconds": {k: round(v, 4)
                                    for k, v in stage_seconds.items()},
-                 **self._write_counters()}
+                 **self._write_counters(),
+                 "setup": setup_recorder().export()}
         if self._recorder is not None:
             stats["spans"] = self._recorder.export()
         return stats

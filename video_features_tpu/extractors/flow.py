@@ -63,7 +63,6 @@ from ..models.raft import (
 )
 from ..ops.image import edge_resize_size, pil_edge_resize
 from ..weights.convert_torch import convert_raft
-from ..weights.store import resolve_params
 from .base import Extractor
 
 
@@ -121,13 +120,9 @@ class ExtractFlow(Extractor):
         # host-side cast at 4× the staged bytes (an escape hatch)
         self._wire = np.float32 if cfg.float32_wire else np.uint8
         if self.feature_type == "raft":
-            self.params = self.runner.put_replicated(
-                resolve_params(
-                    "raft-sintel",
-                    convert_torch_fn=convert_raft,
-                    init_fn=lambda: raft_init_params(seed=0),
-                )
-            )
+            self.params = self._load_params(
+                "raft-sintel", convert_torch_fn=convert_raft,
+                init_fn=lambda: raft_init_params(seed=0))
             self._forward = functools.partial(
                 raft_forward, corr_impl=cfg.raft_corr, dtype=flow_dtype,
                 n_devices=self.runner.num_devices)
@@ -147,13 +142,9 @@ class ExtractFlow(Extractor):
             )
             from ..weights.convert_torch import convert_pwc
 
-            self.params = self.runner.put_replicated(
-                resolve_params(
-                    "pwc-sintel",
-                    convert_torch_fn=convert_pwc,
-                    init_fn=lambda: pwc_init_params(seed=0),
-                )
-            )
+            self.params = self._load_params(
+                "pwc-sintel", convert_torch_fn=convert_pwc,
+                init_fn=lambda: pwc_init_params(seed=0))
             self._forward = functools.partial(
                 pwc_forward, corr_impl=cfg.pwc_corr, dtype=flow_dtype)
             self._forward_frames = functools.partial(

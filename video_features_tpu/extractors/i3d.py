@@ -50,7 +50,6 @@ from ..parallel import DATA_AXIS, prefetch_to_device
 from ..parallel.pipeline import pad_batch
 from ..utils.labels import show_predictions_on_dataset
 from ..weights.convert_torch import convert_i3d, convert_pwc, convert_raft
-from ..weights.store import resolve_params
 from .base import Extractor
 
 # Reference geometry (256-edge resize, 224 center crop — extract_i3d.py:25 +
@@ -118,13 +117,8 @@ class ExtractI3D(Extractor):
 
         self.i3d = {s: I3D(modality=s, dtype=self.dtype) for s in self.streams}
         self.i3d_params = {
-            s: self.runner.put_replicated(
-                resolve_params(
-                    f"i3d_{s}",
-                    convert_torch_fn=convert_i3d,
-                    init_fn=functools.partial(self._random_i3d, s),
-                )
-            )
+            s: self._load_params(f"i3d_{s}", convert_torch_fn=convert_i3d,
+                                 init_fn=functools.partial(self._random_i3d, s))
             for s in self.streams
         }
         if "flow" in self.streams:
@@ -132,19 +126,18 @@ class ExtractI3D(Extractor):
                 print("--flow_pair_chunk is PWC-only and ignored with "
                       "--flow_type raft (RAFT bounds flow memory via "
                       "--raft_corr auto)")
+            # closed over by the jitted flow step (trace-time constants) —
+            # pinned replicated so tracing doesn't re-transfer per compile
             if self.flow_type == "raft":
-                self.flow_params = resolve_params(
+                self.flow_params = self._load_params(
                     "raft-sintel", convert_torch_fn=convert_raft,
                     init_fn=lambda: raft_init_params(seed=0))
             elif self.flow_type == "pwc":
-                self.flow_params = resolve_params(
+                self.flow_params = self._load_params(
                     "pwc-sintel", convert_torch_fn=convert_pwc,
                     init_fn=lambda: pwc_init_params(seed=0))
             else:
                 raise ValueError(f"unknown flow_type {self.flow_type!r}")
-            # closed over by the jitted flow step (trace-time constants) — pin
-            # them replicated so tracing doesn't re-transfer per compile
-            self.flow_params = self.runner.put_replicated(self.flow_params)
         else:
             self.flow_params = None
 

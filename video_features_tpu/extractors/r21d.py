@@ -31,7 +31,6 @@ from ..models.r21d import NUM_FEATURES, R2Plus1D18, r21d_preprocess
 from ..utils.labels import show_predictions_on_dataset
 from ..utils.windows import form_slices
 from ..weights.convert_torch import convert_r21d
-from ..weights.store import resolve_params
 from .base import Extractor
 
 
@@ -52,13 +51,8 @@ class ExtractR21D(Extractor):
         self.clips_per_batch = self.runner.device_batch(cfg.clips_per_batch)
         self.dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.model = R2Plus1D18(dtype=self.dtype)
-        self.params = self.runner.put_replicated(
-            resolve_params(
-                "r2plus1d_18",
-                convert_torch_fn=convert_r21d,
-                init_fn=self._random_init,
-            )
-        )
+        self.params = self._load_params(
+            "r2plus1d_18", convert_torch_fn=convert_r21d, init_fn=self._random_init)
         if cfg.show_pred and "fc" not in self.params:
             raise ValueError(
                 "--show_pred needs the classifier head, but the resolved r2plus1d_18 "

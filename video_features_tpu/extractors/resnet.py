@@ -30,7 +30,6 @@ from ..parallel import prefetch_to_device
 from ..ops.image import device_resize_crop_hwc, np_center_crop_hwc, pil_edge_resize
 from ..utils.labels import show_predictions_on_dataset
 from ..weights.convert_torch import convert_resnet50
-from ..weights.store import resolve_params
 from .base import Extractor
 
 RESIZE_SIZE = 256
@@ -57,13 +56,8 @@ class ExtractResNet50(Extractor):
         self.batch_size = self.runner.device_batch(cfg.batch_size)
         self.dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.model = ResNet50(dtype=self.dtype)
-        self.params = self.runner.put_replicated(
-            resolve_params(
-                "resnet50",
-                convert_torch_fn=convert_resnet50,
-                init_fn=self._random_init,
-            )
-        )
+        self.params = self._load_params(
+            "resnet50", convert_torch_fn=convert_resnet50, init_fn=self._random_init)
         if cfg.show_pred and "fc" not in self.params:
             raise ValueError(
                 "--show_pred needs the classifier head, but the resolved resnet50 "

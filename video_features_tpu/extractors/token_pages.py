@@ -28,7 +28,7 @@ import numpy as np
 
 from ..io.transcript import Transcript, read_transcript
 from ..reliability.errors import DecodeError
-from ..weights.store import open_checkpoint
+from ..weights.store import load_weights, open_checkpoint
 from .base import Extractor
 
 # table rows (segments) per page, as a share of its token slots: a page of
@@ -75,8 +75,11 @@ class TokenPageExtractor(Extractor):
         if self.runner.num_devices != 1:
             raise ValueError(f"{self.model_name} runs one page program on one chip (its share "
                              "of the experts is this chip's); use --num_devices 1")
-        with open_checkpoint(self.model_name, init_fn=self._random_checkpoint) as (names, read):
-            self.params, self.share = model.stack_checkpoint(self.model_cfg, names, read)
+        with (load_weights(self.model_name) as load,
+              open_checkpoint(self.model_name, init_fn=self._random_checkpoint) as (names, read)):
+            self.params, self.share = model.stack_checkpoint(self.model_cfg, names,
+                                                             load.leaves(read))
+            load.place(self.params)
 
     def _random_checkpoint(self) -> Dict[str, np.ndarray]:
         """VFT_ALLOW_RANDOM_WEIGHTS smoke runs: the type's first layers and

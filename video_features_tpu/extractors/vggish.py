@@ -43,7 +43,6 @@ from ..models.vggish import (
     convert_tf_vggish,
     vggish_init_params,
 )
-from ..weights.store import resolve_params
 from .base import Extractor
 
 # examples per jitted call; audio shorter than this pads, longer chunks
@@ -62,13 +61,10 @@ class ExtractVGGish(Extractor):
         # examples per device step, rounded to a multiple of the mesh size
         self.example_batch = self.runner.device_batch(EXAMPLE_BATCH)
         self.model = VGGish()
-        self.params = self.runner.put_replicated(
-            resolve_params(
-                "vggish",
-                convert_tf_fn=convert_tf_vggish,  # reference ships a TF-slim checkpoint
-                init_fn=lambda: vggish_init_params(seed=0),
-            )
-        )
+        self.params = self._load_params(
+            "vggish",
+            convert_tf_fn=convert_tf_vggish,  # reference ships a TF-slim checkpoint
+            init_fn=lambda: vggish_init_params(seed=0))
         # reference parity: processor constructed, applied only on request —
         # --vggish_postprocess (vendored AudioSet params) or an explicit
         # VFT_VGGISH_PCA_PARAMS path (env var implies opt-in, as before)

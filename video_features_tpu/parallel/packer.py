@@ -93,7 +93,7 @@ from ..reliability.faults import fault_point
 from ..utils.metrics import span as bare_span
 from .pipeline import pad_batch
 from .pages import (PAGES_QUEUED, TABLE_COLS, TOKEN_PLANES, build_row_table,
-                    build_token_page, first_fit, fit_documents)
+                    build_token_page, fit_documents)
 
 
 @dataclass
@@ -349,11 +349,6 @@ class CorpusPacker:
         self.staged_bytes = 0  # host bytes staged per dispatched device batch
         self.pages_dispatched = 0  # paged-mode dispatches (stats)
         self.segments = 0  # token pages: table rows (segments) dispatched
-        # token pages: documents queued at each dispatch, summed (what the
-        # pages had to choose from), and pages whose documents are not the
-        # ones arrival order alone would have put there (pages.first_fit)
-        self.queued_documents = 0
-        self.pages_chosen = 0
         self.max_in_flight = 0  # deepest observed in-flight ring (any key)
         self.video_clips: Dict[str, int] = {}  # per finished video
         # per shape key: {"real_slots", "dispatched_slots", "stale_flushes"}
@@ -563,9 +558,6 @@ class CorpusPacker:
                         f"a document of {len(queue[0].clip.ids)} tokens and "
                         f"{len(queue[0].clip.segment_ends)} segments fits no "
                         f"page of {spec.page_tokens} tokens and {batch_size} rows")
-                self.queued_documents += len(queue)
-                self.pages_chosen += take != first_fit(
-                    sizes, spec.page_tokens, batch_size)
                 slots = [queue[i] for i in take]
                 taken = set(take)
                 queue[:] = [s for i, s in enumerate(queue) if i not in taken]

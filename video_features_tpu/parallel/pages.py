@@ -148,20 +148,6 @@ def fit_documents(sizes: Sequence[Tuple[int, int]], page_tokens: int,
     return [0] + take[::-1]
 
 
-def first_fit(sizes: Sequence[Tuple[int, int]], page_tokens: int,
-              page_rows: int) -> list:
-    """What arrival order alone would put in the page: each document that
-    still fits what the ones before it left. Only counted against
-    (``pages_chosen``: pages whose take differs from this)."""
-    take, tokens, rows = [], 0, 0
-    for i, (n, s) in enumerate(sizes):
-        if tokens + n <= page_tokens and rows + s <= page_rows:
-            take.append(i)
-            tokens += n
-            rows += s
-    return take
-
-
 def build_token_page(docs: Sequence[Tuple[int, np.ndarray, np.ndarray]],
                      page: np.ndarray, table: np.ndarray) -> list:
     """Fill one token page and its row table in place (staging-ring buffers:

@@ -91,7 +91,7 @@ from ..reliability import (
     record_failure,
 )
 from ..reliability.faults import fault_point
-from ..utils.metrics import StageClock
+from ..utils.metrics import StageClock, metrics_enabled, setup_recorder, setup_report
 from .autoscale import DecodeAutoscaler
 from .ingest import SPOOL_TENANTS_FILE, SocketAPI, SpoolWatcher, accepted_path
 from .request import RequestRejected, ServiceRequest, VideoJob, parse_request
@@ -1278,6 +1278,9 @@ def serve(cfg) -> int:
         api.start()
         print(f"[serve] socket API at {sock_path}")
     print(f"[serve] {describe_devices(extractor.runner.mesh)}")
+    if metrics_enabled(cfg.profile_dir, cfg.telemetry_dir):
+        # what the start-up cost: construction, checkpoints, compiles so far
+        print(f"[serve] {setup_report(setup_recorder().export())}")
     print(f"[serve] watching {cfg.spool_dir} "
           f"(results → {service.notify_dir}); SIGTERM drains, SIGHUP "
           "reloads")

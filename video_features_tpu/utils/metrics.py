@@ -330,7 +330,12 @@ class SpanRecorder:
         fact with its real start and end (a blocked ``next()``, a wait for a
         pending transfer)."""
         end = time.time_ns()
-        self._append(name, end - int(seconds * 1e9), end, self._stack(), ids)
+        self.interval(name, end - int(seconds * 1e9), end, **ids)
+
+    def interval(self, name: str, start: int, end: int, **ids) -> None:
+        """A span that ended, given its start and end in ``time.time_ns()``
+        (JAX's compile events say both)."""
+        self._append(name, start, end, self._stack(), ids)
 
     def self_seconds(self) -> Dict[str, float]:
         """Per span name, duration less what child spans on the same thread
@@ -406,6 +411,116 @@ def span(name: str, clock: Optional[StageClock] = None,
             journal.end(name, sid, **{**ids, **handle.ids})
         if recorder is not None:
             recorder.end(index, handle.ids)
+
+
+# --- set-up ------------------------------------------------------------------
+#
+# What a process does before its first run — an extractor's construction, the
+# checkpoint read and placed, every compile or persistent-cache load — is
+# recorded whatever the switch says, in ONE process-wide recorder (compiles
+# are process-wide): spans that happen a few times a process, never per page
+# or video. Each run's ``_pack_stats["setup"]`` is its export
+# (docs/observability.md "Set-up").
+
+SETUP_LIMIT = 4096
+_SETUP = SpanRecorder(SETUP_LIMIT)
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# after-the-fact records of what precedes a compile: Python to a jaxpr, the
+# jaxpr to an MLIR module
+LOWERING_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower"}
+
+_listening = False
+_listen_lock = threading.Lock()
+# per thread: whether a persistent-cache hit was announced inside the compile
+# event that has not ended yet (JAX records the hit first, on the same
+# thread), and how deep it is in lowering events that have begun and not
+# ended (a jit traced inside another's trace reports its own event: only the
+# outermost is kept, so the records neither count a second twice nor grow
+# with every jnp function a model's trace calls)
+_thread = threading.local()
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == CACHE_HIT_EVENT:
+        _thread.cache_hit = True
+
+
+def _on_scalar(event: str, _value, **_kw) -> None:
+    # JAX marks the start of each timed compile-path event with a scalar
+    if event in LOWERING_EVENTS:
+        _thread.depth = getattr(_thread, "depth", 0) + 1
+
+
+def _on_time_span(event: str, start: float, end: float, fun_name: str = "", **_kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    hit = getattr(_thread, "cache_hit", False)
+    _thread.cache_hit = False
+    _SETUP.interval("compile", int(start * 1e9), int(end * 1e9), program=fun_name,
+                    cache="hit" if hit else "miss")
+
+
+def _on_duration(event: str, seconds: float, fun_name: str = "", **_kw) -> None:
+    kind = LOWERING_EVENTS.get(event)
+    if kind is None:
+        return
+    _thread.depth = depth = max(getattr(_thread, "depth", 1) - 1, 0)
+    if depth == 0:
+        _SETUP.add(kind, seconds, program=fun_name)
+
+
+def setup_recorder() -> SpanRecorder:
+    """The process-wide set-up recorder; the first call registers the
+    ``jax.monitoring`` listeners that fill it with ``compile``, ``trace`` and
+    ``lower`` records (once a process)."""
+    global _listening
+    with _listen_lock:
+        if not _listening:
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_event)
+            monitoring.register_scalar_listener(_on_scalar)
+            monitoring.register_event_time_span_listener(_on_time_span)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            _listening = True
+    return _SETUP
+
+
+def setup_span(name: str, **ids):
+    """THE span call (:func:`span`) into the set-up recorder, and no other
+    sink: no clock stage, no journal (the name goes by keyword, so vftlint's
+    telemetry-schema rule does not take this for a journal wrapper)."""
+    return span(name=name, recorder=setup_recorder(), **ids)
+
+
+def setup_report(setup: Dict) -> str:
+    """One line for the stage report from ``_pack_stats["setup"]``: the last
+    construction, the checkpoints it read and placed, and the compile records
+    since it began (each a load from the persistent cache or a compile)."""
+    records = [r for r in setup["records"] if r["end"] is not None]
+    constructs = [r for r in records if r["name"] == "construct"]
+    if not constructs:
+        return "set-up: no construction recorded"
+    c = constructs[-1]
+    since = [r for r in records if r["start"] >= c["start"]]
+
+    def seconds(name):
+        return sum(r["end"] - r["start"] for r in since if r["name"] == name) / 1e9
+
+    loads = [r["ids"] for r in since if r["name"] == "load_weights"]
+    compiles = [r["ids"] for r in since if r["name"] == "compile"]
+    hits = sum(ids.get("cache") == "hit" for ids in compiles)
+    return (f"set-up: construct {(c['end'] - c['start']) / 1e9:.2f}s | "
+            f"weights {len(loads)} checkpoint(s) {seconds('load_weights'):.2f}s: "
+            f"read {sum(ids.get('read_s', 0.0) for ids in loads):.2f}s, "
+            f"place wait {seconds('place_wait'):.2f}s, "
+            f"{sum(ids.get('bytes_read', 0) for ids in loads) / 1e9:.2f} GB read, "
+            f"{sum(ids.get('bytes_placed', 0) for ids in loads) / 1e9:.2f} GB placed | "
+            f"compile {seconds('compile'):.2f}s: "
+            f"{hits} program(s) loaded, {len(compiles) - hits} compiled")
 
 
 @contextlib.contextmanager

@@ -19,9 +19,12 @@ from __future__ import annotations
 
 import contextlib
 import os
+import time
 from typing import Callable, Dict, Optional
 
 import numpy as np
+
+from ..utils.metrics import setup_span
 
 ENV_DIR = "VFT_CHECKPOINT_DIR"
 ENV_ALLOW_RANDOM = "VFT_ALLOW_RANDOM_WEIGHTS"
@@ -146,6 +149,63 @@ def open_checkpoint(name: str, init_fn: Optional[Callable[[], Dict[str, np.ndarr
     raise FileNotFoundError(
         f"no checkpoint found for {name!r}: place its leaves at "
         f"${ENV_DIR}/{name}.npz, or set {ENV_ALLOW_RANDOM}=1 for random weights")
+
+
+class WeightLoad:
+    """What :func:`load_weights` yields. Its record's ids divide the span's
+    time: ``read_s`` the checkpoint's arrays being read (from disk, or drawn
+    where random weights stand in), ``bytes_read`` their bytes as stored; the
+    child ``place_wait`` the one wait for the device copies; the rest is the
+    host's own work (casts, stacks, dispatching the copies)."""
+
+    def __init__(self, ids: Dict):
+        self.ids = ids
+        ids.update(read_s=0.0, bytes_read=0)
+
+    def _count(self, t0: float, tree) -> None:
+        import jax
+
+        self.ids["read_s"] += time.perf_counter() - t0
+        self.ids["bytes_read"] += sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
+    def read(self, fn: Callable, *args, **kwargs):
+        """``fn(*args, **kwargs)``: a whole host tree read at once
+        (:func:`resolve_params`)."""
+        t0 = time.perf_counter()
+        tree = fn(*args, **kwargs)
+        self._count(t0, tree)
+        return tree
+
+    def leaves(self, read: Callable) -> Callable:
+        """``read(name)`` → one leaf, each read counted as it comes
+        (:func:`open_checkpoint`'s reader)."""
+        def timed(name):
+            t0 = time.perf_counter()
+            leaf = read(name)
+            self._count(t0, leaf)
+            return leaf
+
+        return timed
+
+    def place(self, tree):
+        """``tree`` once every device copy has landed: ONE wait for the whole
+        tree (a wait per leaf would serialise the copies), then its ``leaves``
+        and ``bytes_placed`` (one copy, as the device holds it)."""
+        import jax
+
+        with setup_span("place_wait"):
+            jax.block_until_ready(tree)
+        leaves = jax.tree.leaves(tree)
+        self.ids.update(leaves=len(leaves), bytes_placed=sum(int(x.nbytes) for x in leaves))
+        return tree
+
+
+@contextlib.contextmanager
+def load_weights(checkpoint: str):
+    """The ``load_weights`` set-up span of one checkpoint, read, placed and
+    waited for inside it: yields its :class:`WeightLoad`."""
+    with setup_span("load_weights", checkpoint=checkpoint) as handle:
+        yield WeightLoad(handle.ids)
 
 
 def resolve_params(
