@@ -106,7 +106,7 @@ def test_chunked_layer_against_a_plain_loop(case, monkeypatch):
     if case == "nan_past_the_groups":
         sound = run_layer(p, h, weights, experts, valid, slot_of, held)[0]
         monkeypatch.setattr(moe, "grouped_matmul", nan_past_the_groups(moe.grouped_matmul))
-    y, (routed_total, routed_held, rows, chunks) = run_layer(p, h, weights, experts, valid, slot_of, held)
+    y, (routed_total, routed_held, rows, chunks, runs) = run_layer(p, h, weights, experts, valid, slot_of, held)
 
     want = plain_loop(p, h, weights, experts, valid, slot_of)
     assert np.isfinite(y).all()
@@ -123,6 +123,8 @@ def test_chunked_layer_against_a_plain_loop(case, monkeypatch):
     assert chunk == (TOKENS * TOP_K if held == EXPERTS else
                      -(-int(1.5 * TOKENS * TOP_K * held / EXPERTS) // ROW_TILE) * ROW_TILE)
     assert int(chunks) == -(-int(routed_held) // chunk)
+    # the combine reads each (token tile, expert) run once a chunk: one at least a non-empty expert, a row at most
+    assert np.count_nonzero(rows) <= int(runs) <= int(routed_held)
     if chunks_wanted is not None:
         assert int(chunks) == chunks_wanted
     if case == "an_expert_across_a_chunks_edge":
@@ -162,25 +164,68 @@ def test_chunks_cut_the_groups_exactly():
     np.testing.assert_array_equal(np.concatenate(router), weights.reshape(-1)[by_expert])
 
 
-def test_combine_sums_bfloat16_rows_in_float32(monkeypatch):
-    """The combine alone on bfloat16 rows, as the chip's configurations have
-    them: the router's float32 weight meets every row whole (a weight rounded
-    to the rows' type would miss by 2**-9) and the sums are float32, whatever
-    the rows past the groups hold."""
-    rng = np.random.default_rng(9)
-    rows, tokens, width, inside = 64, 32, 128, 41
+COMBINE_CASES = {
+    # name: (tokens, width, token tile, rows of the chunk, rows per held expert
+    # in the chunk, tokens an expert draws from: None all, or a (lo, hi) range)
+    "a_token_tile_that_owns_no_row": (64, 128, 16, 64, (20, 0, 21), "skip_tile_1"),
+    "a_run_longer_than_one_slab": (64, 128, 32, 96, (40, 9, 14), None),
+    "a_run_cut_by_the_chunks_edge": (64, 128, 16, 48, (11, 7, 30), None),
+    "tokens_not_a_multiple_of_the_tile": (40, 128, 16, 64, (25, 6, 10), None),
+    "a_width_not_a_multiple_of_128": (48, 200, 16, 64, (30, 0, 11), None),
+}
+
+
+@pytest.mark.parametrize("case", list(COMBINE_CASES))
+def test_combine_sums_bfloat16_rows_in_float32(case, monkeypatch):
+    """The combine alone (the ``moe_combine`` kernel in the interpreter) on
+    bfloat16 rows, as the chip's configurations have them, against a float64
+    sum: the router's float32 weight meets every row whole (a weight rounded
+    to the rows' type would miss by 2**-9), the sums are float32, whatever the
+    rows past the groups hold (NaN here), and each edge of the token tiles and
+    the slabs of rows is met."""
+    tokens, width, tile, rows, sizes, draw = COMBINE_CASES[case]
+    monkeypatch.setattr(moe, "COMBINE_VMEM", 20 * tile * width)  # five float32 rows a token of ``tile``
+    monkeypatch.setattr(moe, "COMBINE_VREGS", 8)  # groups of 8 rows: a slab holds two or more
+    seen = {}
+    real = moe._combine
+
+    def spy(expert_out, into, chunk, tile, slab, align, group):
+        seen.update(tile=tile, slab=slab, group=group)
+        return real(expert_out, into, chunk, tile, slab, align, group)
+
+    monkeypatch.setattr(moe, "_combine", spy)
+    rng = np.random.default_rng(sorted(COMBINE_CASES).index(case))
+    among = np.arange(tokens)
+    if draw == "skip_tile_1":
+        among = among[(among < tile) | (among >= 2 * tile)]
+    # each held expert's rows: distinct tokens in token order (the dispatch sort is stable)
+    token = [np.sort(rng.choice(among, n, replace=False)) for n in sizes]
+    inside = sum(sizes)
+    token = np.concatenate(token + [rng.integers(0, tokens, rows - inside)]).astype(np.int32)
     out = jnp.asarray(rng.standard_normal((rows, width)), jnp.bfloat16)
     out = jnp.where(jnp.arange(rows)[:, None] < inside, out, jnp.nan)
-    token = np.sort(rng.integers(0, tokens, rows)).astype(np.int32)[rng.permutation(rows)]
     weight = rng.uniform(0.05, 1.0, rows).astype(np.float32)
     into = rng.standard_normal((tokens, width)).astype(np.float32)
-    chunk = moe.Chunk(jnp.asarray(token), jnp.asarray(weight), jnp.asarray([30, 0, inside - 30], jnp.int32), True)
-    got = np.asarray(jax.jit(lambda o, i: moe.combine(o, i, chunk))(out, jnp.asarray(into)), np.float64)
+    chunk = moe.Chunk(jnp.asarray(token), jnp.asarray(weight), jnp.asarray(sizes, jnp.int32), True)
+    got, runs = jax.jit(lambda o, i: moe.combine(o, i, chunk))(out, jnp.asarray(into))
+    got = np.asarray(got, np.float64)
     want = into.astype(np.float64)
     np.add.at(want, token[:inside], np.asarray(out[:inside].astype(jnp.float32), np.float64)
               * weight[:inside, None].astype(np.float64))
     assert np.isfinite(got).all()
     assert (np.linalg.norm(got - want, axis=1) / np.linalg.norm(want, axis=1)).max() < 1e-6
+
+    # the runs read: the non-empty (token tile, expert) pairs, and each case's edge is there
+    assert seen["tile"] == tile and seen["group"] == 8 and seen["slab"] % 8 == 0
+    expert = np.repeat(np.arange(len(sizes)), sizes)
+    pairs = {(t // tile, e) for t, e in zip(token[:inside], expert)}
+    assert int(runs) == len(pairs)
+    longest = max(np.sum((token[:inside] // tile == t) & (expert == e)) for t, e in pairs)
+    assert {"a_token_tile_that_owns_no_row": not any(t == 1 for t, _ in pairs),
+            "a_run_longer_than_one_slab": longest > seen["slab"],
+            "a_run_cut_by_the_chunks_edge": inside == rows,
+            "tokens_not_a_multiple_of_the_tile": tokens % tile != 0,
+            "a_width_not_a_multiple_of_128": width % 128 != 0}[case]
 
 
 def tiny_page_program(held_ids, sparse_layers: int = 2):
@@ -216,19 +261,22 @@ def tiny_page_program(held_ids, sparse_layers: int = 2):
 
 
 def test_page_counters_layout():
-    """routed_total, routed_held, expert_chunks, expert_chunk_calls, then the
-    rows per held expert of every sparse layer (``expert_rows``: sparse layers
-    × experts held, as the extractor's stats reshape them)."""
+    """routed_total, routed_held, expert_chunks, expert_chunk_calls,
+    combine_runs, then the rows per held expert of every sparse layer
+    (``expert_rows``: sparse layers × experts held, as the extractor's stats
+    reshape them)."""
     program, params, page, real = tiny_page_program(ALL[:4], sparse_layers=2)
     rows, counters = program(params, page)
     counters = np.asarray(counters)
-    assert rows.shape == (4, HIDDEN) and counters.shape == (4 + 2 * 4,) and counters.dtype == np.int32
-    routed_total, routed_held, chunks, calls = counters[:4]
+    assert rows.shape == (4, HIDDEN) and counters.shape == (5 + 2 * 4,) and counters.dtype == np.int32
+    routed_total, routed_held, chunks, calls, runs = counters[:5]
     assert routed_total == 2 * TOP_K * real and calls == 2
-    expert_rows = counters[4:].reshape(-1, 4)
+    expert_rows = counters[5:].reshape(-1, 4)
     assert expert_rows.shape == (2, 4) and expert_rows.sum() == routed_held
     chunk = moe.chunk_rows(TOKENS * TOP_K, 4, EXPERTS)
     assert chunks == sum(-(-int(n) // chunk) for n in expert_rows.sum(axis=1)) >= calls
+    # a non-empty expert is read in one run at least, a run holds a row at least
+    assert np.count_nonzero(expert_rows) <= runs <= routed_held
 
 
 def test_the_readers_scopes_survive_the_loop():
@@ -245,3 +293,7 @@ def test_the_readers_scopes_survive_the_loop():
     in_the_loop = [name for name in names if "/while/body/" in name]
     for scope in ("/moe/dispatch", "/moe/experts", "/moe/combine"):
         assert any(scope in name.split("/while/body", 1)[1] for name in in_the_loop), scope
+    # the combine is the ``moe_combine`` kernel: the by-token sort and the transposed grouped product are gone
+    combine = [name.split("/moe/combine", 1)[1].split("/") for name in in_the_loop if "/moe/combine" in name]
+    assert any("moe_combine" in part for parts in combine for part in parts)
+    assert not any(part == "sort" or "tgmm" in part for parts in combine for part in parts)

@@ -537,7 +537,7 @@ def test_the_router_and_the_gated_shared_expert(float32, rng):
 
     assert p["shared_gate"].shape == (TINY.hidden_size, 1)
     nobody = jnp.full((TINY.num_experts,), -1, jnp.int32)  # no expert held: the shared part alone
-    y, (_total, held, _rows, _trips) = text_layers.expert_layer(
+    y, (_total, held, _rows, _trips, _runs) = text_layers.expert_layer(
         p, h, jnp.ones((24,), bool), nobody.at[0].set(0), 1, functools.partial(model.route, TINY),
         interpret=True)
     ungated = {k: v for k, v in p.items() if k != "shared_gate"}
@@ -577,7 +577,7 @@ def test_four_shares_and_the_gated_shared_expert_once_make_the_uncut_layer(float
         slot_of = np.full((TINY.num_experts,), -1, np.int32)
         slot_of[list(share.experts)] = np.arange(len(ids))
         p = params["layers"][0]
-        y, (routed_total, routed_held, rows, _chunks) = text_layers.expert_layer(
+        y, (routed_total, routed_held, rows, _chunks, _runs) = text_layers.expert_layer(
             p, h, valid, jnp.asarray(slot_of), len(ids), functools.partial(model.route, TINY),
             interpret=True)
         shared = (jax.nn.sigmoid(text_layers.dot(h, p["shared_gate"]))
@@ -630,13 +630,15 @@ def test_an_expert_layer_without_a_shared_gate_traces_as_it_did_at_the_parent():
     assert not plain - gated
     equations, digest = PARENT_EXPERT_LAYER
     assert sum(plain.values()) == equations
-    assert plain["pallas_call"] == 3 and plain["while"] == 1  # two grouped products, the combine's; one loop
+    # two grouped products and the combine's kernel; the chunk loop and the combine's two loops over slabs and groups
+    assert plain["pallas_call"] == 3 and plain["while"] == 3
     assert hashlib.sha256(str(traced).encode()).hexdigest()[:16] == digest
 
 
-# the same trace at the parent commit (5874d32), read there: equations with the
-# kernels' own counted in, and the first 16 hex digits of sha256(str(jaxpr))
-PARENT_EXPERT_LAYER = (1279, "53af1bbd5b1d63b0")
+# the same trace where the combine became the ``moe_combine`` kernel (PR 43;
+# 1279, "53af1bbd5b1d63b0" at 5874d32): equations with the kernels' own
+# counted in, and the first 16 hex digits of sha256(str(jaxpr))
+PARENT_EXPERT_LAYER = (1643, "c5e8d57c686ded9b")
 
 
 # --- the weight table and the configuration's file -------------------------------

@@ -411,7 +411,7 @@ def test_eight_shares_and_the_shared_expert_once_make_the_uncut_layer(float32):
         slot_of[list(share.experts)] = np.arange(len(ids))
         p = params["layers"][0]
         assert p["router_bias"].dtype == jnp.float32
-        y, (routed_total, routed_held, rows, _chunks) = text_layers.expert_layer(
+        y, (routed_total, routed_held, rows, _chunks, _runs) = text_layers.expert_layer(
             p, h, valid, jnp.asarray(slot_of), len(ids), functools.partial(model.route, TINY),
             interpret=True)
         routed = y - text_layers.gated_mlp(h, p["shared_gate_up"], p["shared_down"])

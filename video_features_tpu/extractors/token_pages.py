@@ -171,19 +171,21 @@ class TokenPageExtractor(Extractor):
         (``expert_rows``, layers × experts held), and how many chunks the
         routed layers ran (``expert_chunks``) in how many calls
         (``expert_chunk_calls`` = sparse layers × pages): equal when no page
-        held more than one chunk's rows (``ops/moe.py``). Between them and the
-        rows, what the model's own ``PAGE_COUNTERS`` name (``qwen3_next``:
+        held more than one chunk's rows (``ops/moe.py``); the (token tile,
+        expert) runs the combine read (``combine_runs``: ``routed_held`` over
+        it is the mean rows a run). Between them and the rows, what the model's
+        own ``PAGE_COUNTERS`` name (``qwen3_next``:
         ``gdn_chunks``, ``gdn_boundary_chunks``)."""
         counters = getattr(self, "_moe_counters", None)
         if counters is None:
             return {}
         c = np.asarray(counters)
         own = getattr(self.model, "PAGE_COUNTERS", ())
-        rows = c[4 + len(own):].reshape(-1, max(len(self.share.experts), 1))
+        rows = c[5 + len(own):].reshape(-1, max(len(self.share.experts), 1))
         return {"routed_total": int(c[0]), "routed_held": int(c[1]),
                 "expert_chunks": int(c[2]), "expert_chunk_calls": int(c[3]),
-                **{name: int(v) for name, v in zip(own, c[4:])},
-                "expert_rows": rows.tolist()}
+                **{name: int(v) for name, v in zip(own, c[5:])},
+                "combine_runs": int(c[4]), "expert_rows": rows.tolist()}
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:
         raise NotImplementedError(

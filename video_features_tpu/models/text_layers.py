@@ -93,9 +93,10 @@ def gated_mlp(h, w_gate_up, w_down):
 
 def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bool = False):
     """→ (routed + shared, float32), (routed_total, routed_held, rows per held
-    expert, chunks run). ``route(p, h)`` is the model's call of ``moe.route``:
-    its top-k, scaling factor and scoring. The held rows go through the
-    experts in chunks (``ops/moe.py``): one where the router is near even.
+    expert, chunks run, runs the combine read). ``route(p, h)`` is the model's
+    call of ``moe.route``: its top-k, scaling factor and scoring. The held
+    rows go through the experts in chunks (``ops/moe.py``): one where the
+    router is near even.
     Where the checkpoint has a ``shared_gate`` leaf the shared expert's output
     is scaled token by token by ``sigmoid(h · shared_gate)``."""
     with jax.named_scope("route"):
@@ -111,7 +112,7 @@ def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bo
     def one_chunk(state):
         # a loop's body starts its own name stack: each scope opens in here,
         # under the names the traces are read by (…/moe/dispatch and so on)
-        c, y = state
+        c, y, runs = state
         with jax.named_scope("moe/dispatch"):
             chunk = part(c)
             rows = h[chunk.token_of_row]
@@ -121,12 +122,13 @@ def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bo
             act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(DTYPE)
             out = moe.grouped_matmul(act, p["experts_down"], chunk.group_sizes, interpret)
         with jax.named_scope("moe/combine"):
-            return c + 1, moe.combine(out, y, chunk)
+            y, read = moe.combine(out, y, chunk)
+        return c + 1, y, runs + read
 
-    _, y = lax.while_loop(lambda state: state[0] < trips, one_chunk,
-                          (jnp.zeros((), jnp.int32), shared))
+    zero = jnp.zeros((), jnp.int32)
+    _, y, runs = lax.while_loop(lambda state: state[0] < trips, one_chunk, (zero, shared, zero))
     routed_total = jnp.sum(valid).astype(jnp.int32) * experts.shape[1]
-    return y, (routed_total, jnp.sum(d.group_sizes), d.group_sizes, trips)
+    return y, (routed_total, jnp.sum(d.group_sizes), d.group_sizes, trips, runs)
 
 
 def segment_mean(x, seg, page_rows: int):
@@ -155,7 +157,8 @@ def page_forward(name: str, share: Share, num_experts: int, is_dense, attention,
     segment in the page's table (-1 on pads). → ((page_rows, hidden) float32
     segment features, int32 counters: routed_total, routed_held, expert_chunks
     (chunks run, over the sparse layers), expert_chunk_calls (the sparse
-    layers), then the model's own ``page_counters`` (int32, its module's
+    layers), combine_runs (the (token tile, expert) runs the combine read, over
+    the sparse layers), then the model's own ``page_counters`` (int32, its module's
     ``PAGE_COUNTERS`` names them), then rows per held expert for every sparse
     layer). Scopes are ``<name>/embed``, ``<name>/L<k>/attn/…``,
     ``<name>/L<k>/{mlp,moe}/…``, ``<name>/pool``."""
@@ -183,7 +186,8 @@ def page_forward(name: str, share: Share, num_experts: int, is_dense, attention,
     with jax.named_scope(f"{name}/pool"):
         rows = segment_mean(rms_norm(x, params["final_norm"], eps, jnp.float32), seg, page_rows)
     zero = jnp.zeros((), jnp.int32)
-    totals = [sum((c[i] for c in counters), zero) for i in (0, 1, 3)] + [zero + len(counters)]
+    totals = ([sum((c[i] for c in counters), zero) for i in (0, 1, 3)] + [zero + len(counters)]
+              + [sum((c[4] for c in counters), zero)])
     own = [] if page_counters is None else [page_counters.astype(jnp.int32)]
     return rows, jnp.concatenate([jnp.stack(totals)] + own + [c[2] for c in counters])
 

@@ -33,7 +33,9 @@ from jax import lax
 
 GMM_TILING = (512, 1024, 1024)  # rows, contraction, columns of one grouped-product tile
 CHUNK_ROOM = 1.5  # a chunk's rows over what an even router sends to the held experts
-TOKEN_TILE = 128  # tokens whose rows one group of the combine's product sums
+COMBINE_VMEM = 80 << 20  # bytes of VMEM the combine's tiles of the carry may take
+COMBINE_DEPTH = 8  # slabs of rows the combine has in flight
+COMBINE_VREGS = 16  # float32 vector registers of rows the combine loads before it stores
 
 
 class Dispatch(NamedTuple):
@@ -133,36 +135,135 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret: bool = False):
 
 
 def combine(expert_out, into, chunk: Chunk):
-    """``into`` (tokens, width) float32 plus a chunk's part of the routed sum:
-    each of ``expert_out``'s rows (the chunk's, in sorted order) times its
-    router weight, added to its token's row in float32. Nothing is gathered
-    back to ``tokens * top_k`` rows and nothing is scattered: the chunk's rows
-    are ordered by token, so a tile of ``TOKEN_TILE`` tokens owns a run of
-    them, and one transposed grouped product (a group a tile) multiplies each
-    run by its weighted one-hot matrix (tile's tokens × rows of the run). The
-    float32 weight goes in as the parts the products' type holds exactly
-    (three for bfloat16), stacked on the product's rows: the products are
-    exact and their sums float32. A scatter-add of the same rows took 12.8 ms
-    where this takes 8.1, a gather to every assignment 15.1 (PERF.md section
-    6, PR 39)."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm  # the package exports gmm alone
+    """→ (``into`` (tokens, width) float32 plus a chunk's part of the routed
+    sum, the runs read). Each of ``expert_out``'s rows (the chunk's, in sorted
+    order) times its float32 router weight, added in float32 to its token's row:
+    one Pallas kernel, ``moe_combine``, over tiles of tokens, adding into the
+    carry in place. Nothing is sorted again and nothing is gathered: the
+    dispatch sort is stable, so inside an expert's group the rows are in token
+    order and a tile of tokens owns ONE contiguous run of each group (its bounds:
+    one ``searchsorted`` of ``slot · tokens + token`` over the chunk). The
+    kernel copies each run from HBM in slabs of rows; a row outside its run
+    (past the groups it may hold anything, NaN included) meets no sum. The runs
+    read, the non-empty (tile, expert) pairs, are the second result. The sizes
+    follow the shapes: the tile from what VMEM holds of the carry's width, the
+    slab from what an even router sends a tile from one expert, the rows summed
+    between stores from what the vector registers hold of a row."""
+    (tokens, width), rows = into.shape, expert_out.shape[0]
+    held = chunk.group_sizes.shape[0]
+    # a token of a tile is held five times: the carry in and out (two buffers each) and its sums
+    tile = 1 << int(math.log2(COMBINE_VMEM // (20 * width)))
+    tile = tokens if tokens <= tile else tile
+    # rows loaded before they are stored: a float32 row fills ceil(width / 1024) registers
+    group = 1 << int(math.log2(max(1, COMBINE_VREGS // -(-width // 1024))))
+    # a slab holds what an even router sends a tile from one expert, in whole
+    # sublane tiles (the copies stay aligned) and whole groups
+    align = 8 * 4 // expert_out.dtype.itemsize
+    step = max(align, group)
+    slab = min(rows, -(-max(1, math.ceil(tile * rows / (tokens * held))) // step) * step)
+    return _combine(expert_out, into, chunk, tile, slab, align, math.gcd(group, slab))
 
-    (tokens, width), rows, dtype = into.shape, expert_out.shape[0], expert_out.dtype
-    tile = math.gcd(tokens, TOKEN_TILE)
-    # rows past the groups hold anything, NaN included: sorted past every
-    # token they are in no group, and the product reads its groups' rows alone
-    covered = jnp.arange(rows, dtype=jnp.int32) < jnp.sum(chunk.group_sizes)
-    token, weight, order = lax.sort((jnp.where(covered, chunk.token_of_row, tokens), chunk.weight_of_row,
-                                     jnp.arange(rows, dtype=jnp.int32)), num_keys=1)
-    sizes = jnp.diff(jnp.searchsorted(token, jnp.arange(0, tokens + 1, tile, dtype=jnp.int32)))
-    lane = jnp.arange(tile, dtype=jnp.int32)[:, None] == (token % tile)[None, :]
-    kind, parts, rest = jnp.finfo(dtype), [], weight
-    for _ in range(-(-24 // (kind.nmant + 1))):
-        # not ``astype`` there and back: the compiler may keep the excess precision, and the rest would be 0
-        part = lax.reduce_precision(rest, kind.nexp, kind.nmant)
-        parts.append(jnp.where(lane, part[None, :], 0.0).astype(dtype))
-        rest = rest - part
-    sums = tgmm(jnp.concatenate(parts), expert_out[order], sizes,
-                preferred_element_type=jnp.float32, interpret=chunk.interpret,
-                tiling=(min(GMM_TILING[0], rows), len(parts) * tile, min(GMM_TILING[2], width)))
-    return into + sums.reshape(tokens // tile, len(parts), tile, width).sum(axis=1).reshape(tokens, width)
+
+def _runs(chunk: Chunk, tokens: int, rows: int, tile: int):
+    """(tiles, held) int32 first and past-last chunk row of each (token tile,
+    held expert) run."""
+    held = chunk.group_sizes.shape[0]
+    ends = jnp.cumsum(chunk.group_sizes)
+    row = jnp.arange(rows, dtype=jnp.int32)
+    # every search by comparison: XLA's binary search is a loop of gathers, 3.9 ms
+    # for a chunk of Qwen3-Next (PERF.md section 6, PR 43)
+    slot = jnp.searchsorted(ends, row, side="right", method="compare_all").astype(jnp.int32)
+    key = jnp.where(row < ends[-1], slot * tokens + chunk.token_of_row, held * tokens)
+    edges = jnp.minimum(jnp.arange(0, tokens + tile, tile, dtype=jnp.int32), tokens)
+    bounds = jnp.searchsorted(key, (jnp.arange(held, dtype=jnp.int32)[:, None] * tokens
+                                    + edges[None, :]).reshape(-1), method="compare_all").astype(jnp.int32)
+    bounds = bounds.reshape(held, -1)
+    return bounds[:, :-1].T, bounds[:, 1:].T
+
+
+def _combine(expert_out, into, chunk: Chunk, tile: int, slab: int, align: int, group: int):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (tokens, width), rows = into.shape, expert_out.shape[0]
+    held = chunk.group_sizes.shape[0]
+    tiles = -(-tokens // tile)
+    first, last = (a.reshape(-1) for a in _runs(chunk, tokens, rows, tile))
+    # the slabs ("jobs"), flattened tile-major: a run from its row rounded
+    # down to a sublane tile, ``slab`` rows at a time, the last slab of the
+    # chunk moved back inside it; each job's rows of its run, as a span
+    start = (first // align) * align
+    count = jnp.where(last > first, -(-(last - start) // slab), 0)
+    upto = jnp.cumsum(count)
+    job = jnp.arange(rows // slab + 2 * tiles * held + 1, dtype=jnp.int32)
+    run = jnp.minimum(jnp.searchsorted(upto, job, side="right", method="compare_all"), tiles * held - 1)
+    want = start[run] + (job - (upto - count)[run]) * slab
+    at = jnp.minimum(want, rows - slab)
+    lo = jnp.maximum(first[run], want) - at
+    hi = jnp.minimum(last[run], want + slab) - at
+    span = (lo | (hi << 16)).astype(jnp.int32)
+    job_of_tile = jnp.concatenate([jnp.zeros((1,), jnp.int32), upto.reshape(tiles, held)[:, -1]])
+    depth = COMBINE_DEPTH
+
+    def kernel(job_of_tile, at, span, token, weight, into_ref, rows_ref, out_ref, buf, wide, sums, sem):
+        i = pl.program_id(0)
+        j0, j1 = job_of_tile[i], job_of_tile[i + 1]
+        # the tile's sums, and past them a row that takes what lies outside the runs
+        sums[:tile] = into_ref[...]
+
+        def copy(j):
+            return pltpu.make_async_copy(rows_ref.at[pl.ds(pl.multiple_of(at[j], align), slab)],
+                                         buf.at[j % depth], sem.at[j % depth])
+
+        for p in range(depth):
+            @pl.when(j0 + p < j1)
+            def _():
+                copy(j0 + p).start()
+
+        def one_slab(j, carry):
+            copy(j).wait()
+            # float32 rows load a row whole from any sublane: bfloat16's
+            # packed pairs would be shuffled row by row
+            wide[...] = buf[j % depth].astype(jnp.float32)
+            base, lo, hi = at[j], span[j] & 0xFFFF, span[j] >> 16
+
+            def one_group(g, carry):
+                # a run's rows go to distinct tokens: all loads, then all stores
+                r = [g * group + k for k in range(group)]
+                t = [jnp.where((lo <= r[k]) & (r[k] < hi), token[base + r[k]] - i * tile, tile)
+                     for k in range(group)]
+                new = [sums[pl.ds(t[k], 1), :] + weight[base + r[k]] * wide[pl.ds(r[k], 1), :]
+                       for k in range(group)]
+                for k in range(group):
+                    sums[pl.ds(t[k], 1), :] = new[k]
+                return carry
+
+            lax.fori_loop(lo // group, (hi + group - 1) // group, one_group, 0)
+
+            @pl.when(j + depth < j1)
+            def _():
+                copy(j + depth).start()
+            return carry
+
+        lax.fori_loop(j0, j1, one_slab, 0)
+        out_ref[...] = sums[:tile]
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(tiles,),
+            in_specs=[pl.BlockSpec((tile, width), lambda i, *_: (i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, width), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((depth, slab, width), expert_out.dtype),
+                            pltpu.VMEM((slab, width), jnp.float32),
+                            pltpu.VMEM((tile + 8, width), jnp.float32),
+                            pltpu.SemaphoreType.DMA((depth,))]),
+        out_shape=jax.ShapeDtypeStruct(into.shape, jnp.float32),
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",),
+                                             vmem_limit_bytes=COMBINE_VMEM + (16 << 20)),
+        name="moe_combine",
+        interpret=chunk.interpret,
+    )(job_of_tile, at, span, chunk.token_of_row, chunk.weight_of_row, into, expert_out)
+    return out, jnp.sum(last > first).astype(jnp.int32)
