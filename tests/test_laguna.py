@@ -533,6 +533,8 @@ def test_the_package_and_the_other_types_do_not_load_the_new_modules():
         "bad = [m for m in sys.modules if m.startswith(('video_features_tpu.models.laguna',"
         " 'video_features_tpu.models.sarvam', 'video_features_tpu.models.text_layers',"
         " 'video_features_tpu.models.qwen3_next', 'video_features_tpu.extractors.qwen3_next',"
+        " 'video_features_tpu.models.jamba', 'video_features_tpu.extractors.jamba',"
+        " 'video_features_tpu.ops.selective_scan',"
         " 'video_features_tpu.extractors.laguna', 'video_features_tpu.extractors.sarvam',"
         " 'video_features_tpu.extractors.token_pages', 'video_features_tpu.ops.moe',"
         " 'video_features_tpu.ops.gated_delta',"

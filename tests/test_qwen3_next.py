@@ -386,29 +386,34 @@ def test_chunk_edges_and_page_counters():
 
 # --- the layers around it -------------------------------------------------------
 
-def test_the_convolution_does_not_cross_a_documents_start(rng):
+@pytest.mark.parametrize("with_bias", [False, True], ids=["no_bias", "bias"])
+def test_the_convolution_does_not_cross_a_documents_start(with_bias, rng):
+    """``text_layers.causal_conv``, the stream's one convolution: Qwen3-Next
+    calls it with no bias, Jamba with one added to every token."""
     lengths, channels = (5, 1, 2, 40, 3), 24
     tokens = 64
     u = rng.standard_normal((tokens, channels)).astype(np.float32)
     w = rng.standard_normal((4, channels)).astype(np.float32)
+    bias = rng.standard_normal(channels).astype(np.float32) if with_bias else np.zeros(channels, np.float32)
     pos = np.zeros(tokens, np.int32)
     at = 0
     for n in lengths:
         pos[at:at + n] = np.arange(n)
         at += n
     pos[at:] = np.arange(tokens - at)  # pads count on from 0: anything finite
-    got = np.asarray(model.causal_conv(jnp.asarray(u), jnp.asarray(w), jnp.asarray(pos)))
+    got = np.asarray(text_layers.causal_conv(jnp.asarray(u), jnp.asarray(w), jnp.asarray(pos),
+                                             jnp.asarray(bias) if with_bias else None))
     at = 0
     for n in lengths:
-        alone = np.asarray(ref.causal_conv(jnp.asarray(u[at:at + n]), jnp.asarray(w)))
+        alone = np.asarray(ref.causal_conv(jnp.asarray(u[at:at + n]), jnp.asarray(w))) + bias
         np.testing.assert_allclose(got[at:at + n], alone, atol=1e-5)
         by_hand = sum(w[3 - s] * (u[at + n - 1 - s] if n - 1 - s >= 0 else 0) for s in range(4))
-        np.testing.assert_allclose(got[at + n - 1], by_hand, atol=1e-5)
+        np.testing.assert_allclose(got[at + n - 1], by_hand + bias, atol=1e-5)
         at += n
     # token 10 is its document's third: three taps, the fourth would be the neighbour's
-    np.testing.assert_allclose(got[10], u[10] * w[3] + u[9] * w[2] + u[8] * w[1], atol=1e-5)
+    np.testing.assert_allclose(got[10], u[10] * w[3] + u[9] * w[2] + u[8] * w[1] + bias, atol=1e-5)
     # and it does reach back inside a document: the first tap matters
-    assert np.abs(got[20] - (u[20] * w[3] + u[19] * w[2] + u[18] * w[1])).max() > 1e-3
+    assert np.abs(got[20] - (u[20] * w[3] + u[19] * w[2] + u[18] * w[1] + bias)).max() > 1e-3
 
 
 def test_partial_rope_against_float64_formula():

@@ -146,6 +146,7 @@ _CHECKPOINT_NAMES = {
     "laguna": ("laguna",),
     "sarvam": ("sarvam",),
     "qwen3_next": ("qwen3_next",),
+    "jamba": ("jamba",),
 }
 
 

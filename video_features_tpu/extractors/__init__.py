@@ -34,4 +34,7 @@ def get_extractor(cfg):
     if ft == "qwen3_next":
         from .qwen3_next import ExtractQwen3Next
         return ExtractQwen3Next(cfg)
+    if ft == "jamba":
+        from .jamba import ExtractJamba
+        return ExtractJamba(cfg)
     raise ValueError(f"unknown feature_type: {ft}")

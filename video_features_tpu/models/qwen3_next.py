@@ -97,21 +97,6 @@ def rope_inv_freq(cfg: Qwen3NextConfig) -> np.ndarray:
 
 # --- layers -----------------------------------------------------------------
 
-def causal_conv(u, w, pos):
-    """``y_t = Σ_j w[j] · u_{t-(K-1)+j}`` per channel, with ``u`` zero before
-    the document's first token: a tap ``s`` tokens back counts where ``pos_t
-    ≥ s``. u (tokens, channels), w (K, channels) → float32 (the sums; the
-    shifted rows are read in ``u``'s own type, so nothing wider than ``u`` is
-    written on the way)."""
-    taps = w.shape[0]
-    wf = w.astype(jnp.float32)
-    y = u.astype(jnp.float32) * wf[taps - 1]
-    for back in range(1, taps):
-        shifted = jnp.pad(u, ((back, 0), (0, 0)))[:-back].astype(jnp.float32)
-        y = y + jnp.where((pos >= back)[:, None], shifted, 0.0) * wf[taps - 1 - back]
-    return y
-
-
 def gated_delta_net(cfg: Qwen3NextConfig, p: dict, x, doc, pos, interpret: bool = False):
     kh, vh = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     mixed = 2 * cfg.key_width + cfg.value_width  # the columns the convolution mixes: q, k, v
@@ -120,7 +105,7 @@ def gated_delta_net(cfg: Qwen3NextConfig, p: dict, x, doc, pos, interpret: bool 
         qkvz = tl.dot(h, p["wqkvz"]).astype(tl.DTYPE)
         b, a = jnp.split(tl.dot(h, p["wba"]), 2, axis=-1)  # float32: they make the decay
     with jax.named_scope("conv"):
-        qkv = jax.nn.silu(causal_conv(qkvz[:, :mixed], p["conv"], pos)).astype(tl.DTYPE)
+        qkv = jax.nn.silu(tl.causal_conv(qkvz[:, :mixed], p["conv"], pos)).astype(tl.DTYPE)
     with jax.named_scope("gates"):
         beta = jax.nn.sigmoid(b)
         g = -jnp.exp(p["a_log"]) * jax.nn.softplus(a + p["dt_bias"])

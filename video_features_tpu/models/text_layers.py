@@ -84,6 +84,23 @@ def dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
+def causal_conv(u, w, pos, bias=None):
+    """``y_t = Σ_j w[j] · u_{t-(K-1)+j}`` (``+ bias``) per channel, with ``u``
+    zero before the document's first token: a tap ``s`` tokens back counts
+    where ``pos_t ≥ s``. u (tokens, channels), w (K, channels), bias
+    (channels,) or None → float32 (the sums; the shifted rows are read in
+    ``u``'s own type, so nothing wider than ``u`` is written on the way)."""
+    taps = w.shape[0]
+    wf = w.astype(jnp.float32)
+    y = u.astype(jnp.float32) * wf[taps - 1]
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    for back in range(1, taps):
+        shifted = jnp.pad(u, ((back, 0), (0, 0)))[:-back].astype(jnp.float32)
+        y = y + jnp.where((pos >= back)[:, None], shifted, 0.0) * wf[taps - 1 - back]
+    return y
+
+
 def gated_mlp(h, w_gate_up, w_down):
     """``down(silu(gate(h)) · up(h))``; gate and up are one product."""
     gate, up = jnp.split(dot(h, w_gate_up).astype(h.dtype), 2, axis=-1)
