@@ -36,7 +36,7 @@ from video_features_tpu.extractors import get_extractor  # noqa: E402
 from video_features_tpu.extractors import token_pages as extractor_module  # noqa: E402
 from video_features_tpu.models import jamba as model  # noqa: E402
 from video_features_tpu.models import text_layers  # noqa: E402
-from video_features_tpu.ops.selective_scan import GROUP, selective_scan  # noqa: E402
+from video_features_tpu.ops.selective_scan import EXCHANGE, GROUP, selective_scan  # noqa: E402
 
 # 4 query heads of 16 over one key/value head, 128 inner channels of 16
 # states, rank 8; layers 0-3 with attention at layer 2 (period 4, offset 2)
@@ -221,16 +221,24 @@ def plain_loop(u, dt, b, c, a, d, z, pos):
     return y * z / (1 + np.exp(-z))
 
 
-@pytest.mark.parametrize("chunk,channels", [(32, 32), (16, 64), (128, 128)],
-                         ids=["four_chunks_four_blocks", "eight_chunks_two_blocks", "one_step"])
-@pytest.mark.parametrize("lengths", [(128,), (5, 40, 1, 50), (16, 17, 15, 32, 30)],
-                         ids=["whole_page", "mid_chunk_starts_and_pads", "starts_at_and_beside_edges"])
-def test_selective_scan_against_a_plain_loop(lengths, chunk, channels, rng):
+@pytest.mark.parametrize("chunk,channels,width", [(32, 32, 128), (16, 64, 128), (128, 128, 128),
+                                                 (32, 1024, 3072)],
+                         ids=["four_chunks_four_blocks", "eight_chunks_two_blocks", "one_step",
+                              "three_blocks_of_1024"])
+@pytest.mark.parametrize("lengths", [(128,), (5, 40, 1, 50), (16, 17, 15, 32, 30), (24, 104),
+                                     (12, 116), (15, 113)],
+                         ids=["whole_page", "mid_chunk_starts_and_pads", "starts_at_and_beside_edges",
+                              "start_first_of_exchange", "start_middle_of_exchange",
+                              "start_last_of_exchange"])
+def test_selective_scan_against_a_plain_loop(lengths, chunk, channels, width, rng):
     """Across chunk edges and channel blocks, a document that starts
     mid-chunk or at an edge, a document of one token, trailing pads (``pos``
     0: they restart at every token and stay finite); ``z`` read from inside a
-    wider array at a column offset."""
-    tokens, width = 128, 128
+    wider array at a column offset of whole blocks. A document starts at the
+    first, the middle and the last token of the kernel's in-VMEM exchange of
+    ``EXCHANGE`` tokens (24 is also mid-way through a group of ``GROUP``), and
+    one width is three blocks of 1,024 channels (eight rows of 128 a token)."""
+    tokens = 128
     pos = np.zeros(tokens, np.int32)
     at = 0
     for n in lengths:
@@ -257,14 +265,19 @@ def test_selective_scan_refuses_what_it_cannot_tile(rng):
         selective_scan(*args, chunk=40, interpret=True)
     with pytest.raises(ValueError, match="from column 8"):
         selective_scan(*args, gate_column=8, channels=32, interpret=True)
-    assert GROUP == 16
+    assert (GROUP, EXCHANGE) == (16, 8)  # what the plain-loop test's document starts are placed for
 
 
 def test_the_scan_kernel_lowers_for_tpu_at_the_published_shape():
     """jaxpr → Mosaic MLIR at the page's own shape (16,384 tokens, 5,120
     channels, 16 states, bfloat16 ``u`` and ``z``) with no TPU present; the
     Mosaic compile itself is the chip's (``benchmark/sizing_token_pages.py``
-    makes it here by hand for a described v5e)."""
+    makes it here by hand for a described v5e). The exchange between tiles of
+    tokens and a token's rows of channels stays inside the kernel: outside the
+    ``tpu_custom_call`` no operation transposes anything, and none takes or
+    makes an array of the page's ``u``, ``Δ`` or ``z`` (``(16384, 5120)``, the
+    gate's ``(16384, 10240)``, or either cut into rows of 128): on the TPU such
+    a relayout is a pass over the array, not a bitcast."""
     tokens, width, state = 16384, 5120, 16
     s = jax.ShapeDtypeStruct
     scan = jax.export.export(jax.jit(functools.partial(selective_scan, gate_column=width)),
@@ -273,7 +286,14 @@ def test_the_scan_kernel_lowers_for_tpu_at_the_published_shape():
         s((tokens, state), jnp.float32), s((tokens, state), jnp.float32),
         s((state, width), jnp.float32), s((width,), jnp.bfloat16),
         s((tokens, 2 * width), jnp.bfloat16), s((tokens,), jnp.int32))
-    assert "tpu_custom_call" in scan.mlir_module() and "selective_scan" in scan.mlir_module()
+    text = scan.mlir_module()
+    assert "tpu_custom_call" in text and "selective_scan" in text
+    outside = [line for line in text.splitlines()
+               if "= stablehlo." in line and "stablehlo.custom_call" not in line]
+    assert outside and not [line for line in outside if "stablehlo.transpose" in line]
+    page = (f"tensor<{tokens}x{width}x", f"tensor<{tokens}x{2 * width}x",
+            f"tensor<{tokens}x{width // 128}x128x", f"tensor<{tokens}x{2 * width // 128}x128x")
+    assert not [line for line in outside if any(p in line for p in page)]
 
 
 # --- the layers around it -------------------------------------------------------
