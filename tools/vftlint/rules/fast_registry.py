@@ -37,6 +37,7 @@ DEFAULT_TIER: Dict[str, str] = {
     "test_sarvam": "page program compiles (both Pallas kernels in the interpreter)",
     "test_qwen3_next": "page program compiles (three Pallas kernels in the interpreter)",
     "test_jamba": "page program compiles (both Pallas kernels in the interpreter)",
+    "test_lfm2": "page program compiles (three Pallas kernels in the interpreter)",
     "test_moe_chunks": "jitted routed layers (the grouped product in the Pallas interpreter)",
     "test_metrics": "stage-clock tests with real sleeps",
     "test_multihost": "loopback two-process jax.distributed init",

@@ -147,6 +147,7 @@ _CHECKPOINT_NAMES = {
     "sarvam": ("sarvam",),
     "qwen3_next": ("qwen3_next",),
     "jamba": ("jamba",),
+    "lfm2": ("lfm2",),
 }
 
 

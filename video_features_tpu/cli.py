@@ -193,7 +193,7 @@ def build_parser() -> argparse.ArgumentParser:
                              "batch; >= 2 overlaps host refill with device "
                              "compute)")
     parser.add_argument("--page_tokens", type=int, default=16384,
-                        help="laguna, sarvam, qwen3_next, jamba: token slots of one device page (whole "
+                        help="laguna, sarvam, qwen3_next, jamba, lfm2: token slots of one device page (whole "
                              "transcripts share a page: the oldest queued and, "
                              "of two pages' worth, the others that fill it "
                              "best; a longer transcript is refused); a "

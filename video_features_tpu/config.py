@@ -14,9 +14,9 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 FEATURE_TYPES = ("i3d", "vggish", "r21d_rgb", "resnet50", "raft", "pwc", "laguna", "sarvam",
-                 "qwen3_next", "jamba")
+                 "qwen3_next", "jamba", "lfm2")
 # the text stream: token transcripts in, the packed path only, one chip's share
-TOKEN_TYPES = ("laguna", "sarvam", "qwen3_next", "jamba")
+TOKEN_TYPES = ("laguna", "sarvam", "qwen3_next", "jamba", "lfm2")
 ON_EXTRACTION = ("print", "save_numpy")
 FLOW_TYPES = ("raft", "pwc")
 STREAMS = ("rgb", "flow")
@@ -133,7 +133,7 @@ class ExtractionConfig:
     # dispatch; page_rows = ceil(batch budget / depth), so total in-flight
     # rows stay at one bucketed batch regardless of depth).
     pages_in_flight: int = 2
-    # laguna, sarvam, qwen3_next, jamba (the text stream): token slots of one device page. A page holds
+    # laguna, sarvam, qwen3_next, jamba, lfm2 (the text stream): token slots of one device page. A page holds
     # whole transcripts (the oldest queued, and of two pages' worth the others
     # that fill it best), so this is also the longest transcript the
     # type takes; a multiple of the attention kernel's block of 512. One

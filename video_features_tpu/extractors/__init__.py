@@ -37,4 +37,7 @@ def get_extractor(cfg):
     if ft == "jamba":
         from .jamba import ExtractJamba
         return ExtractJamba(cfg)
+    if ft == "lfm2":
+        from .lfm2 import ExtractLfm2
+        return ExtractLfm2(cfg)
     raise ValueError(f"unknown feature_type: {ft}")

@@ -1,5 +1,5 @@
 """What the text stream's models share (``models/laguna.py``, ``models/sarvam.py``,
-``models/qwen3_next.py``):
+``models/qwen3_next.py``, ``models/jamba.py``, ``models/lfm2.py``):
 the page program's arithmetic outside attention, and how a checkpoint's leaf
 names say what this chip holds.
 
@@ -115,16 +115,20 @@ def expert_layer(p: dict, h, valid, slot_of, num_held: int, route, interpret: bo
     rows go through the experts in chunks (``ops/moe.py``): one where the
     router is near even.
     Where the checkpoint has a ``shared_gate`` leaf the shared expert's output
-    is scaled token by token by ``sigmoid(h · shared_gate)``."""
+    is scaled token by token by ``sigmoid(h · shared_gate)``; where it has no
+    shared expert (no ``shared_gate_up``) the routed sum starts at zero."""
     with jax.named_scope("route"):
         weights, experts = route(p, h)
     with jax.named_scope("dispatch"):
         d = moe.dispatch(experts, valid, slot_of, num_held, weights)
         trips, part = moe.chunks(d, moe.chunk_rows(experts.size, num_held, slot_of.shape[0]), interpret)
-    with jax.named_scope("shared"):
-        shared = gated_mlp(h, p["shared_gate_up"], p["shared_down"])
-        if "shared_gate" in p:
-            shared = jax.nn.sigmoid(dot(h, p["shared_gate"])) * shared
+    if "shared_gate_up" in p:
+        with jax.named_scope("shared"):
+            shared = gated_mlp(h, p["shared_gate_up"], p["shared_down"])
+            if "shared_gate" in p:
+                shared = jax.nn.sigmoid(dot(h, p["shared_gate"])) * shared
+    else:
+        shared = jnp.zeros(h.shape, jnp.float32)
 
     def one_chunk(state):
         # a loop's body starts its own name stack: each scope opens in here,
@@ -242,19 +246,21 @@ def leaf_reader(read):
 
 
 def stack_mlp(p: dict, pre: str, dense: bool, experts: Sequence[int], get, side_by_side,
-              gated_shared: bool = False) -> None:
-    """Layer ``pre``'s dense unit, or its router, shared expert (with its
-    per-token gate where the checkpoint has one: ``gated_shared``) and held
-    experts (stacked on a leading axis in ``experts`` order), into ``p``:
-    gate and up projections side by side, the products' own layout."""
+              gated_shared: bool = False, shared: bool = True) -> None:
+    """Layer ``pre``'s dense unit, or its router, shared expert (where the
+    model has one: ``shared``; with its per-token gate where the checkpoint
+    has one: ``gated_shared``) and held experts (stacked on a leading axis in
+    ``experts`` order), into ``p``: gate and up projections side by side, the
+    products' own layout."""
     pair = ("gate_proj", "up_proj")
     if dense:
         p["w_gate_up"] = side_by_side(f"{pre}/mlp", pair)
         p["w_down"] = get(f"{pre}/mlp/down_proj")
         return
     p["router"] = get(f"{pre}/router")
-    p["shared_gate_up"] = side_by_side(f"{pre}/shared", pair)
-    p["shared_down"] = get(f"{pre}/shared/down_proj")
+    if shared:
+        p["shared_gate_up"] = side_by_side(f"{pre}/shared", pair)
+        p["shared_down"] = get(f"{pre}/shared/down_proj")
     if gated_shared:
         p["shared_gate"] = get(f"{pre}/shared_gate")
     p["experts_gate_up"] = jnp.stack([side_by_side(f"{pre}/experts/{e}", pair) for e in experts])
@@ -265,7 +271,8 @@ def mlp_leaf_shapes(spec: Dict[str, Tuple[int, ...]], pre: str, hid: int, dense_
                     router_width: int, shared_width: int, expert_width: int,
                     experts: Sequence[int], gated_shared: bool = False) -> None:
     """The leaves :func:`stack_mlp` reads, into ``spec``; ``dense_width`` is
-    None for a sparse layer."""
+    None for a sparse layer, ``shared_width`` 0 where it has no shared
+    expert."""
     def unit(prefix, width):
         spec[f"{prefix}/gate_proj"] = spec[f"{prefix}/up_proj"] = (hid, width)
         spec[f"{prefix}/down_proj"] = (width, hid)
@@ -274,7 +281,8 @@ def mlp_leaf_shapes(spec: Dict[str, Tuple[int, ...]], pre: str, hid: int, dense_
         unit(f"{pre}/mlp", dense_width)
         return
     spec[f"{pre}/router"] = (hid, router_width)
-    unit(f"{pre}/shared", shared_width)
+    if shared_width:
+        unit(f"{pre}/shared", shared_width)
     if gated_shared:
         spec[f"{pre}/shared_gate"] = (hid, 1)
     for e in experts:

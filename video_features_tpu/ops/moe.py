@@ -55,13 +55,15 @@ class Chunk(NamedTuple):
     interpret: bool             # the page's kernels run in the Pallas interpreter (off the TPU)
 
 
-def route(h, w_router, top_k: int, scale: float, scoring: str = "softmax", bias=None):
+def route(h, w_router, top_k: int, scale: float, scoring: str = "softmax", bias=None,
+          eps: float = 0.0):
     """Scores over all experts in float32 by the model's ``scoring`` —
     ``"softmax"`` of the logits, or ``"sigmoid"`` of each (the auxiliary-loss-
-    free scheme) — then the ``top_k`` largest, renormalised to sum 1, times
-    the routed scaling factor → (weights float32, expert ids), both (tokens,
-    top_k). ``bias`` (all experts, float32) is added to the scores for the
-    CHOICE only: the weights come from the unbiased scores."""
+    free scheme) — then the ``top_k`` largest, renormalised to sum 1 (over
+    their sum plus ``eps``), times the routed scaling factor → (weights
+    float32, expert ids), both (tokens, top_k). ``bias`` (all experts,
+    float32) is added to the scores for the CHOICE only: the weights come from
+    the unbiased scores."""
     logits = jnp.dot(h, w_router, preferred_element_type=jnp.float32)
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"route: scoring {scoring!r} is neither softmax nor sigmoid")
@@ -71,7 +73,10 @@ def route(h, w_router, top_k: int, scale: float, scoring: str = "softmax", bias=
     else:
         _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
         top = jnp.take_along_axis(scores, experts, axis=-1)
-    return top / top.sum(-1, keepdims=True) * scale, experts.astype(jnp.int32)
+    total = top.sum(-1, keepdims=True)
+    if eps:  # added only where a model states one: without it the program is the same
+        total = total + eps
+    return top / total * scale, experts.astype(jnp.int32)
 
 
 def dispatch(experts, valid, slot_of, num_held: int, weights=None) -> Dispatch:

@@ -14,13 +14,17 @@ products on the MXU with float32 accumulation.
 Layout is the projections' own: ``q`` is ``(tokens, heads * head_dim)``,
 ``k``/``v`` ``(tokens, kv_heads * head_dim)``, and a grid step holds the
 ``heads / kv_heads`` query heads that share one key/value head, so nothing is
-transposed on the way in or out. ``interpret=True`` runs the same kernel in the
+transposed on the way in or out. Heads narrower than a lane row go as many to
+a step as fill one: at ``head_dim`` 64 a grid step holds two key/value heads
+(128 lanes of ``k`` and ``v``) and the query heads of both, since Mosaic
+refuses a 64-lane block of a wider array. ``interpret=True`` runs the same kernel in the
 Pallas interpreter; the caller says so (off the TPU: ``extractors/token_pages.py``).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -33,6 +37,7 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 # for a pass of the benchmark's corpus at 64 heads, 4 269.1, 16 270.4, 2 342.0
 # (PERF.md section 6, PR 38)
 LATENT_HEADS_PER_STEP = 8
+LANES = 128  # a vector register's lanes: the narrowest block of k and v Mosaic takes
 
 
 def first_key_block(doc: jnp.ndarray, block: int, window: Optional[int]) -> jnp.ndarray:
@@ -140,7 +145,8 @@ def segment_attention(q, k, v, doc, *, kv_heads: int, head_dim: int,
     if tokens % block or width != group * kv_heads * head_dim:
         raise ValueError(f"segment_attention: {tokens} tokens in blocks of {block}, "
                          f"q width {width} over {kv_heads} key/value heads of {head_dim}")
-    shared, kv_step = 0, 1
+    # key/value heads a grid step holds: as many as fill a lane row (`head_dim` 64: two)
+    shared, kv_step = 0, math.gcd(kv_heads, max(1, LANES // head_dim))
     if q_shared is not None:
         shared, kv_step = k_shared.shape[1], min(LATENT_HEADS_PER_STEP, kv_heads)
         if (group != 1 or window is not None or 128 % shared or kv_heads % kv_step
