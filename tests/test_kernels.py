@@ -1,10 +1,14 @@
 """Fast direct tests of the TPU kernel lowerings (default test subset).
 
 Covers the production paths a slow-marked file would hide from the default
-run: the spatially tiled Pallas cost-volume kernel (interpret mode) and the
-TapConv3D lowering every bias-free I3D conv takes, in both dtypes.
+run: the spatially tiled Pallas cost-volume kernel (interpret mode), the
+TapConv3D lowering every bias-free I3D conv takes, in both dtypes, and the text
+stream's attention kernel (its softmax state's layout, and pages against a
+plain softmax).
 """
 # fast-registry: default tier — kernel parity vs torch mirrors
+
+import functools
 
 import numpy as np
 import pytest
@@ -353,3 +357,95 @@ def test_raft_on_demand_matmul_matches_gather():
     out_c = np.asarray(_lookup_on_demand(f1, pyr, coords, "matmul",
                                          chunk_budget=h * w * 7))
     np.testing.assert_allclose(out_c, ref, rtol=1e-4, atol=1e-4)
+
+
+# --- the attention kernel over packed documents ----------------------------------
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            if hasattr(value, "jaxpr"):
+                yield from _pallas_calls(value.jaxpr)
+
+
+# the published attention of each text model: (query heads, key/value heads,
+# head_dim, window, shared key columns) → the query heads a grid step holds
+@pytest.mark.parametrize("heads,kv,d,window,shared,heads_step", [
+    pytest.param(32, 8, 64, None, 0, 8, id="lfm2_32_over_8_x64"),
+    pytest.param(48, 8, 128, None, 0, 6, id="laguna_full_48_over_8_x128"),
+    pytest.param(72, 8, 128, 512, 0, 9, id="laguna_window_72_over_8_x128"),
+    pytest.param(16, 2, 256, None, 0, 8, id="qwen3_next_16_over_2_x256"),
+    pytest.param(20, 1, 128, None, 0, 20, id="jamba_20_over_1_x128"),
+    pytest.param(64, 64, 128, None, 64, 8, id="sarvam_latent_64_x128_shared_64"),
+])
+def test_the_softmax_state_is_lane_replicated_at_every_published_shape(
+        heads, kv, d, window, shared, heads_step):
+    """The running max and sum are ``(heads_step, block, 128)`` float32, each
+    row's value in every lane of its row, beside the ``(block, heads_step *
+    head_dim)`` accumulator: the score tile's lane rows take them by whole
+    copies, with no lane broadcast per row."""
+    from video_features_tpu.ops.segment_attention import segment_attention
+
+    tokens, block = 16384, 512
+    arg = lambda w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (tokens, w) if w else (tokens,), dtype)
+    call = functools.partial(segment_attention, kv_heads=kv, head_dim=d, window=window, block=block)
+    args = [arg(heads * d), arg(kv * d), arg(kv * d), arg(0, jnp.int32)]
+    if shared:
+        args += [arg(heads * shared), arg(shared)]
+    traced = jax.make_jaxpr(lambda q, k, v, doc, *rest: call(q, k, v, doc, **dict(
+        zip(("q_shared", "k_shared"), rest))))(*args)
+    (pallas,) = _pallas_calls(traced.jaxpr)
+    n = pallas.params["grid_mapping"].num_scratch_operands
+    scratch = [(tuple(v.aval.shape), v.aval.dtype) for v in pallas.params["jaxpr"].invars[-n:]]
+    assert scratch == [((heads_step, block, 128), jnp.float32)] * 2 + [
+        ((block, heads_step * d), jnp.float32)]
+
+
+def _plain_attention(q, k, v, doc, heads, kv, d, window):
+    """Softmax within each document (and fewer than ``window`` back), query
+    head ``a`` over key/value head ``a // (heads / kv)``, in float32."""
+    n = q.shape[0]
+    i = np.arange(n)
+    seen = (doc[:, None] == doc[None, :]) & (i[None, :] <= i[:, None])
+    if window is not None:
+        seen &= i[:, None] - i[None, :] < window
+    o = np.zeros((n, heads * d), np.float32)
+    for a in range(heads):
+        b = a // (heads // kv)
+        s = np.where(seen, q[:, a * d:(a + 1) * d] @ k[:, b * d:(b + 1) * d].T, -np.inf)
+        p = np.exp(s - s.max(1, keepdims=True))
+        o[:, a * d:(a + 1) * d] = p / p.sum(1, keepdims=True) @ v[:, b * d:(b + 1) * d]
+    return o
+
+
+@pytest.mark.parametrize("heads,kv,d,block,window,lengths,tokens", [
+    # a document begins at block 0's last query, another ends one short of a block
+    pytest.param(4, 1, 32, 16, None, (15, 33, 15), 64, id="document_at_a_blocks_last_token"),
+    # the page's last block holds nothing but pads
+    pytest.param(4, 2, 64, 16, None, (20, 28), 64, id="last_block_all_pads"),
+    # 256-wide heads over a 128-lane state: `alpha` and the sum tiled twice
+    pytest.param(4, 2, 256, 128, None, (100, 200), 384, id="heads_256_wide_state_tiled"),
+    # 64-wide heads at a lane row's upper half take the state's upper lanes
+    pytest.param(8, 2, 64, 128, None, (130, 90), 256, id="heads_64_wide_at_lane_offset"),
+    pytest.param(6, 2, 128, 32, 40, (100, 60), 192, id="window_40_over_blocks_of_32"),
+])
+def test_segment_attention_against_a_plain_softmax(heads, kv, d, block, window, lengths, tokens,
+                                                   rng):
+    """Pages of several documents and a padded tail in the Pallas interpreter,
+    against the softmax written out in float32; pad rows stay finite."""
+    from video_features_tpu.ops.segment_attention import segment_attention
+
+    doc = np.full(tokens, -1, np.int32)
+    doc[:sum(lengths)] = np.repeat(np.arange(len(lengths)), lengths)
+    q, k, v = (rng.standard_normal((tokens, n * d)).astype(np.float32) * 0.5
+               for n in (heads, kv, kv))
+    got = np.asarray(segment_attention(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                                       jnp.asarray(doc), kv_heads=kv, head_dim=d, window=window,
+                                       block=block, interpret=True))
+    want = _plain_attention(q, k, v, doc, heads, kv, d, window)
+    real = doc >= 0
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[real], want[real], atol=2e-5, rtol=1e-5)

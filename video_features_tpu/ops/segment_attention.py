@@ -11,6 +11,17 @@ its copy are not), and inside the range the document, causal and window
 structure is a mask. Scores never leave VMEM: online softmax in float32,
 products on the MXU with float32 accumulation.
 
+The softmax's running max and sum are held lane-replicated, ``(heads, block,
+128)`` float32 with each row's value in every lane (as JAX's splash attention
+holds them). A score tile is ``block / 128`` lane rows wide and a head's
+accumulator columns lie in one lane row (or two, at ``head_dim`` 256), so the
+state meets both by whole-register copies; a 64-wide head at the upper half of
+its lane row takes the state's upper lanes. A state one lane wide has to be
+broadcast across lanes by permutes on the cross-lane unit for every group of
+rows, a chain that binds the kernel: against it this layout takes 12–35 % fewer
+bundles a grid step at the published widths (`tools/kernel_bundles.py`), with
+the same arithmetic and the same bits.
+
 Layout is the projections' own: ``q`` is ``(tokens, heads * head_dim)``,
 ``k``/``v`` ``(tokens, kv_heads * head_dim)``, and a grid step holds the
 ``heads / kv_heads`` query heads that share one key/value head, so nothing is
@@ -96,20 +107,34 @@ def _kernel(lo_ref, q_ref, k_ref, v_ref, *rest, group: int, kv_step: int, head_d
                     s += lax.dot_general(qs_ref[:, row], ks_ref[:, at], contract_last,
                                          preferred_element_type=jnp.float32)
                 s = jnp.where(mask, s, MASKED)
-                m_prev = m_scr[a]
+                m_prev = m_scr[a]  # (block, lanes): a row's value in every lane
                 m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
+                p = jnp.exp(s - _spread(m_new, 0, block))
                 l_scr[a] = alpha * l_scr[a] + p.sum(axis=1, keepdims=True)
-                acc_scr[:, cols_a] = alpha * acc_scr[:, cols_a] + jnp.dot(
-                    p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+                acc_scr[:, cols_a] = (_spread(alpha, a * head_dim, head_dim) * acc_scr[:, cols_a]
+                                      + jnp.dot(p.astype(v.dtype), v,
+                                                preferred_element_type=jnp.float32))
                 m_scr[a] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
         for a in range(kv_step * group):
             cols_a = slice(a * head_dim, (a + 1) * head_dim)
-            o_ref[:, cols_a] = (acc_scr[:, cols_a] / l_scr[a]).astype(o_ref.dtype)
+            o_ref[:, cols_a] = (acc_scr[:, cols_a]
+                                / _spread(l_scr[a], a * head_dim, head_dim)).astype(o_ref.dtype)
+
+
+def _spread(x, start, width):
+    """(rows, lanes) lane-replicated state → (rows, width), lined up with the
+    columns ``start``..``start + width`` of an array: whole copies of its lane
+    rows, sliced at ``start``'s place inside one, so no lane moves (a 64-wide
+    head at an odd place takes the upper half of the row)."""
+    lanes = x.shape[1]
+    start %= lanes
+    if start == 0 and width == lanes:
+        return x
+    return jnp.tile(x, (1, -(-(start + width) // lanes)))[:, start:start + width]
 
 
 def _lane_rows(ks):
@@ -166,6 +191,7 @@ def segment_attention(q, k, v, doc, *, kv_heads: int, head_dim: int,
         return i, h
 
     heads_step = kv_step * group
+    state_lanes = min(block, LANES)  # the running max and sum, each row's in every lane
     in_specs = [pl.BlockSpec((block, heads_step * head_dim), q_block),
                 pl.BlockSpec((block, kv_step * head_dim), kv_block),
                 pl.BlockSpec((block, kv_step * head_dim), kv_block)]
@@ -185,8 +211,8 @@ def segment_attention(q, k, v, doc, *, kv_heads: int, head_dim: int,
         ],
         out_specs=pl.BlockSpec((block, heads_step * head_dim), q_block),
         scratch_shapes=[
-            pltpu.VMEM((heads_step, block, 1), jnp.float32),
-            pltpu.VMEM((heads_step, block, 1), jnp.float32),
+            pltpu.VMEM((heads_step, block, state_lanes), jnp.float32),
+            pltpu.VMEM((heads_step, block, state_lanes), jnp.float32),
             pltpu.VMEM((block, heads_step * head_dim), jnp.float32),
         ],
     )
